@@ -15,27 +15,21 @@ from .base import MXNetError
 from . import resilience
 from .resilience import CheckpointManager, PreemptionHandler, StepWatchdog
 
-# Persistent XLA compilation cache: MXTPU_COMPILE_CACHE=<dir> makes every
-# relaunch reuse compiled programs from disk instead of recompiling the
-# fused step from scratch (bench.py reports cold vs warm bring-up).
-# Configured BEFORE anything can trigger a compile; thresholds are zeroed
-# so even small CPU-test programs land in the cache.
+# Persistent XLA compilation cache.  JAX_COMPILATION_CACHE_DIR places it
+# from outside: jax reads that variable itself and nothing here touches
+# the setting.  Unset, the cache is ONE fixed directory in the checkout —
+# the path is part of the cache key, so a directory that moves never
+# hits.  Configured BEFORE anything can trigger a compile; jax's own
+# thresholds decide which programs are worth an entry.
 import os as _os
-from .base import ENV_COMPILE_CACHE as _ENV_COMPILE_CACHE
-from .base import get_env as _get_env
-_compile_cache = _get_env(_ENV_COMPILE_CACHE)
-if _compile_cache:
+if "JAX_COMPILATION_CACHE_DIR" not in _os.environ:
     import jax as _jax
-    _jax.config.update("jax_compilation_cache_dir",
-                       _os.path.expanduser(_compile_cache))
-    for _k, _v in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                   ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            _jax.config.update(_k, _v)
-        except Exception:  # noqa: BLE001 — older jax without the knob
-            pass
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
     del _jax
-del _os, _compile_cache, _get_env, _ENV_COMPILE_CACHE
+del _os
 
 # Join the process group BEFORE anything can touch a JAX backend: under
 # tools/launch.py the MXTPU_* envs are set, and jax.distributed.initialize

@@ -155,7 +155,7 @@ def iter_eqns(jaxpr, prune=frozenset()):
     ``prune``: primitive names whose equations are yielded but whose
     sub-jaxprs are NOT descended into (the pallas rule prunes at
     custom-vjp wrappers — their bodies are differentiation-protected)."""
-    import jax
+    from jax.extend import core as jex_core
 
     def _walk(jxp):
         for eqn in jxp.eqns:
@@ -165,9 +165,9 @@ def iter_eqns(jaxpr, prune=frozenset()):
             for v in eqn.params.values():
                 items = v if isinstance(v, (list, tuple)) else (v,)
                 for item in items:
-                    if isinstance(item, jax.core.ClosedJaxpr):
+                    if isinstance(item, jex_core.ClosedJaxpr):
                         yield from _walk(item.jaxpr)
-                    elif isinstance(item, jax.core.Jaxpr):
+                    elif isinstance(item, jex_core.Jaxpr):
                         yield from _walk(item)
     inner = getattr(jaxpr, "jaxpr", jaxpr)
     return _walk(inner)
@@ -644,9 +644,21 @@ def lint_lowered(lowered, closed_jaxpr=None, compute_dtype=None,
             rep.extend(findings)
             rep.stats["compute_eqn_dtypes"] = tally
     if compiled_text is None:
-        compiled_text = lowered.compile().as_text()
+        compiled = lowered.compile()
+        compiled_text = compiled.as_text()
+        mem = compiled.memory_analysis()
+        if mem is not None:
+            # XLA's own accounting of one execution, per device
+            rep.stats["memory"] = {
+                "argument_bytes": int(mem.argument_size_in_bytes),
+                "output_bytes": int(mem.output_size_in_bytes),
+                "temp_bytes": int(mem.temp_size_in_bytes)}
     stats = collective_stats(compiled_text)
     rep.stats["collectives"] = stats
+    # which of the package's Pallas kernels the COMPILED program holds —
+    # the tier a step took is read off the program (chip_smoke.py)
+    from ..kernels import compiled_kernels
+    rep.stats["pallas_kernels"] = compiled_kernels(compiled_text)
     rep.extend(audit_collectives(stats, param_bytes=param_bytes,
                                  expect_allgather=expect_allgather))
     rep.extend(audit_collective_schedule(

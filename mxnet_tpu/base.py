@@ -216,10 +216,6 @@ def check_call(ret):  # pragma: no cover - API-parity shim
 
 # -- knobs owned by the package root / the test harness (modules register
 # their own next to the code that reads them; see ENV_REGISTRY)
-ENV_COMPILE_CACHE = register_env(
-    "MXTPU_COMPILE_CACHE",
-    doc="Directory for XLA's persistent compilation cache (wired to "
-        "jax_compilation_cache_dir at package import)")
 ENV_TEST_PLATFORM = register_env(
     "MXTPU_TEST_PLATFORM", default="cpu", scope="test",
     doc="Test-suite platform: cpu = 8-device virtual mesh, tpu = real "

@@ -65,8 +65,9 @@ class _NativeDecoder(object):
     def __init__(self, native, common, cfg):
         import ctypes
         lib = native.get_lib()
-        if lib is None or not getattr(lib, "_has_imagedec", False):
-            raise RuntimeError("native image pipeline unavailable")
+        if lib is None:
+            raise RuntimeError("native image pipeline disabled "
+                               "(MXNET_NO_NATIVE=1)")
         self._ct = ctypes
         self._lib = lib
         aug = cfg["aug"]

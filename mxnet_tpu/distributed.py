@@ -54,6 +54,8 @@ def is_initialized():
 def _check_backend_untouched():
     """Joining after the first JAX backend touch is unrecoverable user
     error, never retryable — checked once, before the retry ladder."""
+    # jax 0.9 has no public spelling: jax.extend.backend.backends() would
+    # itself initialize the backends this check must find untouched
     from jax._src import xla_bridge
     if xla_bridge.backends_are_initialized():
         raise MXNetError(
@@ -120,8 +122,6 @@ def initialize(coordinator_address=None, num_processes=None, process_id=None,
     import jax
     _check_backend_untouched()
     if platform:
-        # The TPU plugin platform wins over the JAX_PLATFORMS env var, so
-        # the override must go through jax.config (see tests/conftest.py).
         jax.config.update("jax_platforms", platform)
     if platform == "cpu":
         # Cross-process XLA collectives on the CPU backend need an explicit
@@ -275,6 +275,7 @@ HEARTBEAT_INTERVAL = 2.0
 def _kv_client():
     if not _INITIALIZED:
         return None
+    # the coordination-service KV client has no public accessor in jax 0.9
     from jax._src import distributed as _jd
     return _jd.global_state.client
 

@@ -24,7 +24,7 @@ daemons into one serving system:
   SIGTERM drain that fences new work then drains every replica, and
   fleet-level p50/p99/shed aggregation on ``/stats``.
 - :mod:`.warm` — the AOT warm store: pre-compile every (model, bucket)
-  forward into ``MXTPU_COMPILE_CACHE`` so a fresh or respawned replica
+  forward into ``JAX_COMPILATION_CACHE_DIR`` so a fresh or respawned replica
   warms from disk instead of from XLA (``fleet_warm_start_x`` in
   ``bench.py fleet`` measures the win; >= 3x is the bar).
 - :mod:`.view` — the shared fleet view that shards the front end: ONE

@@ -22,7 +22,7 @@ by the same exit-code discipline, extended for serving:
   contract).
 
 Respawned replicas come back WARM: the controller passes the AOT warm
-store as ``MXTPU_COMPILE_CACHE``, so ``--warmup`` loads every (model,
+store as ``JAX_COMPILATION_CACHE_DIR``, so ``--warmup`` loads every (model,
 bucket) program from disk instead of XLA (docs/how_to/fleet.md).
 """
 from __future__ import annotations
@@ -121,7 +121,7 @@ class ReplicaController(object):
         env.update(self.extra_env)
         env.update(self.extra_env_by_rid.get(rid, {}))
         if self.warm_store:
-            env["MXTPU_COMPILE_CACHE"] = self.warm_store
+            env["JAX_COMPILATION_CACHE_DIR"] = self.warm_store
         return Replica(rid, argv, env, port_file, log_path,
                        affinity=affinity)
 

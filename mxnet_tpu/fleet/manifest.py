@@ -68,8 +68,8 @@ def replica_device_env(device_sets, index):
     - ``"tpu:0,1;2,3"`` — ``JAX_PLATFORMS=tpu`` and replica *i* sees
       only chip set ``i % n_sets`` (``TPU_VISIBLE_CHIPS``, plus the
       single-process topology bounds libtpu wants for a 1-chip set) —
-      the one-serving-process-per-chip-subset topology.  More replicas
-      than sets wrap around (co-tenant replicas on one subset).
+      the one-serving-process-per-chip-subset topology.  A chip belongs
+      to one process, so more replicas than sets is an error.
     """
     if not device_sets:
         return {}
@@ -81,7 +81,12 @@ def replica_device_env(device_sets, index):
         raise MXNetError(
             "bad device-sets spec %r (want 'cpu' or 'tpu:0,1;2,3')"
             % (device_sets,))
-    chips = groups[index % len(groups)]
+    if index >= len(groups):
+        raise MXNetError(
+            "device-sets spec %r names %d chip set(s) but replica %d "
+            "needs one of its own — a chip belongs to one process"
+            % (device_sets, len(groups), index))
+    chips = groups[index]
     env = {"JAX_PLATFORMS": "tpu", "TPU_VISIBLE_CHIPS": chips}
     if len(chips.split(",")) == 1:
         # a single-chip replica is its own 1x1x1 topology; without the
@@ -120,6 +125,9 @@ class FleetManifest(object):
             raise MXNetError("replicas must be >= 1, got %d"
                              % self.replicas)
         self.buckets = buckets
+        # validates the spec for the LAST replica: a tpu: spec with fewer
+        # chip sets than replicas fails here, not at spawn time
+        replica_device_env(device_sets, self.replicas - 1)
         self.device_sets = device_sets
         #: router worker processes sharing the public port (the sharded
         #: front end); None = the MXTPU_FLEET_WORKERS default at serve

@@ -8,7 +8,7 @@ bucket shape, platform), so the fleet builds them ONCE, ahead of
 traffic: the builder compiles each one and serializes the COMPILED
 EXECUTABLE into ``<store>/aot/`` (``serving/aot.py`` —
 ``jax.experimental.serialize_executable``, weight-free artifacts), and
-the store directory doubles as every replica's ``MXTPU_COMPILE_CACHE``
+the store directory doubles as every replica's ``JAX_COMPILATION_CACHE_DIR``
 (the PR-2 persistent cache catches any program the AOT layer misses).
 A replica launched with the store warms by DESERIALIZING executables —
 no trace, no lower, no compile.
@@ -79,7 +79,7 @@ def build_warm_store(manifest, store_dir, serve_py=None, python=None,
     # replica 0's device env (all replicas share one platform)
     env.update(replica_device_env(manifest.device_sets, 0))
     env.update(extra_env or {})
-    env["MXTPU_COMPILE_CACHE"] = store_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = store_dir
     log("fleet: building warm store %r (%s)"
         % (store_dir, ", ".join(manifest.names())))
     tic = time.monotonic()

@@ -28,9 +28,6 @@ from . import recordio
 from .base import (ENV_DATA_SERVERS, ENV_DATA_WORKERS, MXNetError,
                    get_env, register_env)
 
-ENV_UPLOAD_THREADS = register_env(
-    "MXNET_UPLOAD_THREADS", default=4,
-    doc="Device-upload thread-pool size for batched host->device copies")
 ENV_JPEG_DECODE_FAST = register_env(
     "MXNET_JPEG_DECODE_FAST", default=1,
     doc="0 switches the native training decode from the fast SIMD IDCT "
@@ -870,12 +867,10 @@ class _NativePipeline(_AsyncPipeline):
     SUPPORTED = frozenset(("resize", "rand_crop", "rand_mirror",
                            "mean", "std"))
 
-    #: device-upload threads: each nd.array() call may BLOCK for a full
-    #: host->device round trip (tunneled/remote devices have ~100 ms
-    #: transfer latency at fine batch sizes even when bandwidth is ample),
-    #: so uploads run on a small pool with order-preserving delivery.
-    #: MXNET_UPLOAD_THREADS overrides (1 = serial uploads on the pool).
-    UPLOAD_THREADS = int(get_env(ENV_UPLOAD_THREADS, "4"))
+    #: uploads (and the device_transform dispatch) run on ONE helper
+    #: thread, so the transfer of a batch overlaps the decode of the
+    #: next; more threads fed the attached chip no faster (PERF.md, PR 21)
+    UPLOAD_THREADS = 1
 
     def __init__(self, it, data_shape, batch_size, label_width, aug_kwargs,
                  num_workers, prefetch, dtype, layout="NCHW", seed=0,
@@ -917,8 +912,9 @@ class _NativePipeline(_AsyncPipeline):
 
         from . import native as _native
         lib = _native.get_lib()
-        if lib is None or not getattr(lib, "_has_imagedec", False):
-            raise MXNetError("native image pipeline unavailable")
+        if lib is None:
+            raise MXNetError("native image pipeline disabled "
+                             "(MXNET_NO_NATIVE=1)")
         unsupported = set(aug_kwargs) - self.SUPPORTED
         if unsupported:
             raise MXNetError("native image pipeline does not implement %s"
@@ -1167,6 +1163,7 @@ class ImageRecordIter(mxio.DataIter):
         aug_kwargs = _translate_cxx_aug_params(aug_kwargs)
         has_custom_augs = "aug_list" in aug_kwargs
         self._layout = layout
+        self._device_transform = device_transform
         if layout not in ("NCHW", "NHWC"):
             raise MXNetError("layout must be NCHW or NHWC")
         # Incompatible-flag checks depend only on constructor args and must
@@ -1250,7 +1247,6 @@ class ImageRecordIter(mxio.DataIter):
             self.label_width = label_width
             self._dtype = dtype
             self._host_batches = bool(host_batches)
-            self._device_transform = device_transform
             self._data_name = data_name
             self._label_name = label_name
             return
@@ -1265,22 +1261,23 @@ class ImageRecordIter(mxio.DataIter):
         # when the requested augmentations are natively implemented AND the
         # first record looks like JPEG (PNG/BMP .rec files take the cv2
         # paths — libjpeg cannot decode them).
+        # The choice is made from what the request says (augmentations,
+        # channels, record format, MXNET_NO_NATIVE) — a native library
+        # that fails to build or load raises, it is not a reason to take
+        # the several-fold slower cv2 path.
+        from . import native as _native
         if (not has_custom_augs
                 and get_env(ENV_RECORDITER_NATIVE, "1") != "0"
                 and set(aug_kwargs) <= _NativePipeline.SUPPORTED
-                and _rec_looks_jpeg(path_imgrec)):
-            try:
-                self._pipeline = _NativePipeline(
-                    self._it, tuple(data_shape), batch_size, label_width,
-                    aug_kwargs, preprocess_threads, prefetch_buffer, dtype,
-                    layout=layout, seed=self._eff_seed,
-                    device_transform=device_transform,
-                    host_batches=host_batches)
-            except (MXNetError, ImportError, OSError):
-                # ImportError: ml_dtypes missing for dtype='bfloat16';
-                # OSError: ctypes load failure — the cv2/process path may
-                # still work on such hosts, so fall through
-                self._pipeline = None
+                and tuple(data_shape)[0] == 3
+                and _rec_looks_jpeg(path_imgrec)
+                and _native.get_lib() is not None):
+            self._pipeline = _NativePipeline(
+                self._it, tuple(data_shape), batch_size, label_width,
+                aug_kwargs, preprocess_threads, prefetch_buffer, dtype,
+                layout=layout, seed=self._eff_seed,
+                device_transform=device_transform,
+                host_batches=host_batches)
         if device_transform is not None and self._pipeline is None:
             raise MXNetError(
                 "device_transform needs the native image pipeline")
@@ -1423,6 +1420,23 @@ class ImageRecordIter(mxio.DataIter):
 
     @property
     def provide_data(self):
+        descs = self._decoded_descs()
+        if self._device_transform is None:
+            return descs
+        # consumers see the transform's product — a module binds on it
+        # (uint8 NHWC off the decoder, normalized NCHW into the net)
+        import jax
+        out = []
+        for d in descs:
+            o = jax.eval_shape(self._device_transform,
+                               jax.ShapeDtypeStruct(d.shape, d.dtype))
+            dt = np.dtype("float32" if o.dtype.name == "bfloat16"
+                          else o.dtype.name)
+            out.append(mxio.DataDesc(d.name, o.shape, dtype=dt))
+        return out
+
+    def _decoded_descs(self):
+        """Shape/dtype of the batches as the decode stage delivers them."""
         dt = np.dtype("float32" if self._dtype == "bfloat16"
                       else self._dtype)
         if self._service is not None:

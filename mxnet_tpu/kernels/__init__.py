@@ -1,10 +1,9 @@
 """mxkern — fused Pallas/lax kernels for the graphs XLA leaves on the table.
 
-The bench trajectory (BENCH_r04) shows the conv/matmul models near the
-machine's ceiling while BatchNorm/concat-heavy (inception-bn) and
-gate-heavy (LSTM) graphs trail badly: those graphs spend their time in
-memory-bound elementwise chains that benefit from being ONE kernel pass
-instead of a dispatch-granularity composition.  Following the
+BatchNorm/concat-heavy (inception-bn) and gate-heavy (LSTM) graphs spend
+their time in memory-bound elementwise chains that benefit from being ONE
+kernel pass instead of a dispatch-granularity composition (whether each
+kernel wins on the chip is not measured yet — PERF.md).  Following the
 FlashAttention discipline (Dao et al., 2022 — materialize nothing you can
 recompute in-tile), every kernel here ships at two tiers:
 
@@ -12,13 +11,16 @@ recompute in-tile), every kernel here ships at two tiers:
   ``jax.custom_vjp`` backward, per the :mod:`~mxnet_tpu.rtc` contract
   (Pallas has no reverse-mode transpose; an unprotected kernel in a
   differentiated step is a trace-time error — mxlint's
-  ``graph-pallas-no-vjp`` rule polices this).
-- **fused-lax reference** (CPU tier, and the numeric oracle): the same
-  math as the unfused op composition, in one traced function, written so
-  the per-element operation sequence is IDENTICAL to the unfused graph —
-  bit-comparable where float reassociation permits (asserted in
-  tests/test_kernels.py), and faster than the op-by-op composition
-  because it compiles to one program instead of a dispatch chain.
+  ``graph-pallas-no-vjp`` rule polices this).  A program lowered for a
+  TPU gets the compiled kernel or fails to compile; interpret mode
+  exists only for tests that ask for it by argument.
+- **fused-lax reference** (every other platform, and the numeric
+  oracle): the same math as the unfused op composition, in one traced
+  function, written so the per-element operation sequence is IDENTICAL
+  to the unfused graph — bit-comparable where float reassociation
+  permits (asserted in tests/test_kernels.py), and faster than the
+  op-by-op composition because it compiles to one program instead of a
+  dispatch chain.
 
 Routing is per-kernel via ``MXTPU_FUSED_KERNELS`` (registered in
 ``base.py``): ``1`` (default) enables everything, ``0`` restores the
@@ -64,14 +66,17 @@ registry routes them exactly like the kernel bodies.
 """
 from __future__ import annotations
 
+import contextlib
 import logging
+import threading
 
 from ..base import ENV_FUSED_KERNELS, get_env, register_env
 
 __all__ = ["KNOWN_KERNELS", "fused_enabled", "enabled_kernels",
-           "use_pallas", "ENV_FLASH_BLOCK", "bn_act", "lstm_cell",
-           "flash_attention", "roofline", "augment", "concat_fuse",
-           "pool_act", "eltwise_chain"]
+           "by_platform", "auto_partitioned", "compiled_kernels",
+           "ENV_FLASH_BLOCK", "bn_act",
+           "lstm_cell", "flash_attention", "roofline", "augment",
+           "concat_fuse", "pool_act", "eltwise_chain"]
 
 _LOG = logging.getLogger(__name__)
 
@@ -125,13 +130,60 @@ def fused_enabled(name):
     return name in enabled_kernels()
 
 
-def use_pallas():
-    """Tier selection: compiled Pallas kernels on TPU backends, the
-    fused-lax reference elsewhere.  Tests force the Pallas tier with
-    ``interpret=True`` explicitly (the rtc.py story: same kernel code
-    runs interpreted on the virtual CPU mesh)."""
-    from ..rtc import on_tpu
-    return on_tpu()
+_auto_partitioned = threading.local()
+
+
+@contextlib.contextmanager
+def auto_partitioned():
+    """Mark what is traced inside as part of a program the SPMD
+    partitioner will split over several devices (``SPMDTrainer``'s GSPMD
+    tiers on a multi-device mesh).  jax cannot partition a Mosaic kernel
+    automatically and refuses to lower one there, so :func:`by_platform`
+    keeps such programs on the fused-lax tier; single-device programs and
+    ``shard_map`` bodies (the zero3 manual tier) take the compiled one."""
+    prev = getattr(_auto_partitioned, "on", False)
+    _auto_partitioned.on = True
+    try:
+        yield
+    finally:
+        _auto_partitioned.on = prev
+
+
+def by_platform(pallas_fn, lax_fn, *args):
+    """Tier selection by the platform the computation is LOWERED for
+    (``lax.platform_dependent``): the compiled Pallas kernel in a TPU
+    program, the fused-lax reference anywhere else — so a CPU-placed
+    program on a TPU host takes the lax tier and a TPU program never
+    quietly drops to it.  The one exception is decided by the caller's
+    placement, not by failure: see :func:`auto_partitioned`.  The Pallas
+    outputs are cast to the lax tier's dtypes: both branches must present
+    one signature."""
+    import jax
+    if getattr(_auto_partitioned, "on", False):
+        return lax_fn(*args)
+    want = jax.eval_shape(lax_fn, *args)
+
+    def tpu(*a):
+        return jax.tree.map(lambda o, w: o.astype(w.dtype),
+                            pallas_fn(*a), want)
+    return jax.lax.platform_dependent(*args, tpu=tpu, default=lax_fn)
+
+
+def compiled_kernels(hlo_text):
+    """{kernel name: count} of this package's compiled Pallas kernels
+    in a compiled program's HLO text — which tier a step actually took
+    is read off the program, not off the routing code.  Every
+    pallas_call here is given a ``name="mxtpu_..."``, which the Mosaic
+    custom call carries."""
+    import re
+    counts = {}
+    for line in hlo_text.splitlines():
+        if "tpu_custom_call" not in line:
+            continue
+        m = re.search(r"mxtpu_[a-z0-9_]+", line)
+        name = m.group(0) if m else "unnamed"
+        counts[name] = counts.get(name, 0) + 1
+    return counts
 
 
 from . import roofline            # noqa: E402  (stdlib-light, analytic)

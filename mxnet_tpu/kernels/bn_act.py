@@ -14,7 +14,7 @@ Two fusions close the gap:
   is bit-identical to the unfused graph; the Pallas tier runs the
   normalize+activate block as a ``pl.pallas_call`` pair behind
   ``jax.custom_vjp`` (backward recomputes the activation in-tile and
-  emits per-block partial sums for the scale/shift gradients).
+  accumulates per-image partial sums for the scale/shift gradients).
 - **Inference** (:func:`fold_bn_into_conv`): with frozen moving stats,
   ``BN(conv(x, W) + b)`` is exactly ``conv(x, W * s) + (b - mean) * s +
   beta`` with ``s = gamma * rsqrt(var + eps)`` — the BN op vanishes from
@@ -30,6 +30,8 @@ registered ``BatchNorm`` op, and the executor writes the trailing
 outputs back to aux storage as before.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -88,65 +90,91 @@ def _act_grad_from_y(y, act_type):
     return jnp.ones_like(y)
 
 
+#: elements of one (C, tile) data block: 128K f32 elements = 512 KiB, so
+#: the backward's three double-buffered data blocks plus its in-kernel
+#: f32 temporaries stay well inside the 16 MiB scoped-VMEM default
+_BLOCK_ELEMS = 128 * 1024
+
+
+def _tile(C, M):
+    """Lane tile of the (C, M) row: the largest multiple-of-128 divisor
+    of M whose (C, tile) block fits ``_BLOCK_ELEMS``; a row that is not
+    lane-aligned is one whole block (block dim == array dim is always a
+    legal Mosaic block, and is what the interpret-mode tests present)."""
+    if M % 128:
+        return M
+    lanes = M // 128
+    fit = max(1, _BLOCK_ELEMS // (C * 128))
+    return 128 * max(d for d in range(1, lanes + 1)
+                     if lanes % d == 0 and d <= fit)
+
+
 def _make_norm_act(act_type, interpret):
-    """custom_vjp'd ``y = act(x * scale + shift)`` over (N, C, M) blocks
-    with per-channel scale/shift shaped (1, C, 1); grid over N."""
+    """custom_vjp'd ``y = act(x * scale + shift)`` over an (N, C, M)
+    array with per-channel f32 scale/shift shaped (1, C, 1).  Grid
+    (N, M / tile): one (C, tile) block per program, math in f32 whatever
+    the data dtype (v5e has no bf16 VPU)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     def specs(x):
-        """(row, chan, part) BlockSpecs for the compiled tier: grid over
-        N, one (1, C, M) data row per program, channel vectors shared."""
-        from jax.experimental.pallas import tpu as pltpu
         _, C, M = x.shape
-        row = pl.BlockSpec((1, C, M), lambda n: (n, 0, 0),
+        t = _tile(C, M)
+        row = pl.BlockSpec((None, C, t), lambda n, m: (n, 0, m),
                            memory_space=pltpu.VMEM)
-        chan = pl.BlockSpec((1, C, 1), lambda n: (0, 0, 0),
+        chan = pl.BlockSpec((None, C, 1), lambda n, m: (0, 0, 0),
                             memory_space=pltpu.VMEM)
-        part = pl.BlockSpec((1, C, 1), lambda n: (n, 0, 0),
+        # per-image partial sums: the block index ignores m, so the
+        # block stays resident and accumulates across the row's tiles
+        part = pl.BlockSpec((None, C, 1), lambda n, m: (n, 0, 0),
                             memory_space=pltpu.VMEM)
-        return row, chan, part
+        return (x.shape[0], M // t), row, chan, part
 
     def fwd_kernel(x_ref, s_ref, b_ref, y_ref):
-        y_ref[...] = _apply_act(x_ref[...] * s_ref[...] + b_ref[...],
-                                act_type)
+        y = _apply_act(x_ref[...].astype(jnp.float32) * s_ref[...]
+                       + b_ref[...], act_type)
+        y_ref[...] = y.astype(y_ref.dtype)
 
     def bwd_kernel(x_ref, s_ref, b_ref, dy_ref, dx_ref, ds_ref, db_ref):
         # recompute y in-tile (nothing saved between passes), then the
-        # pre-activation cotangent and this block's partial reductions
-        y = _apply_act(x_ref[...] * s_ref[...] + b_ref[...], act_type)
-        dpre = dy_ref[...] * _act_grad_from_y(y, act_type)
-        dx_ref[...] = dpre * s_ref[...]
-        ds_ref[...] = jnp.sum(dpre * x_ref[...], axis=-1, keepdims=True)
-        db_ref[...] = jnp.sum(dpre, axis=-1, keepdims=True)
+        # pre-activation cotangent and this tile's partial reductions
+        x = x_ref[...].astype(jnp.float32)
+        y = _apply_act(x * s_ref[...] + b_ref[...], act_type)
+        dpre = dy_ref[...].astype(jnp.float32) * _act_grad_from_y(y, act_type)
+        dx_ref[...] = (dpre * s_ref[...]).astype(dx_ref.dtype)
+
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            ds_ref[...] = jnp.zeros_like(ds_ref)
+            db_ref[...] = jnp.zeros_like(db_ref)
+        ds_ref[...] += jnp.sum(dpre * x, axis=-1, keepdims=True)
+        db_ref[...] += jnp.sum(dpre, axis=-1, keepdims=True)
 
     def fwd_call(x, s, b):
-        kw = {}
-        if not interpret:
-            row, chan, _ = specs(x)
-            kw = {"grid": (x.shape[0],), "in_specs": [row, chan, chan],
-                  "out_specs": row}
+        grid, row, chan, _ = specs(x)
         return pl.pallas_call(
             fwd_kernel, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            interpret=interpret, **kw)(x, s, b)
+            grid=grid, in_specs=[row, chan, chan], out_specs=row,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            name="mxtpu_bn_act_fwd", interpret=interpret)(x, s, b)
 
     def bwd_call(x, s, b, dy):
-        kw = {}
         N, C, _ = x.shape
-        if not interpret:
-            row, chan, part = specs(x)
-            kw = {"grid": (N,),
-                  "in_specs": [row, chan, chan, row],
-                  "out_specs": (row, part, part)}
+        grid, row, chan, part = specs(x)
         dx, ds_p, db_p = pl.pallas_call(
             bwd_kernel,
             out_shape=(jax.ShapeDtypeStruct(x.shape, x.dtype),
-                       jax.ShapeDtypeStruct((N, C, 1), x.dtype),
-                       jax.ShapeDtypeStruct((N, C, 1), x.dtype)),
-            interpret=interpret, **kw)(x, s, b, dy)
-        # fold the per-block partials across the grid dimension in lax
-        ds = jnp.sum(ds_p, axis=0, keepdims=True)
-        db = jnp.sum(db_p, axis=0, keepdims=True)
-        return dx, ds, db
+                       jax.ShapeDtypeStruct((N, C, 1), jnp.float32),
+                       jax.ShapeDtypeStruct((N, C, 1), jnp.float32)),
+            grid=grid, in_specs=[row, chan, chan, row],
+            out_specs=(row, part, part),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            name="mxtpu_bn_act_bwd", interpret=interpret)(x, s, b, dy)
+        # fold the per-image partials in lax
+        return (dx, jnp.sum(ds_p, axis=0, keepdims=True),
+                jnp.sum(db_p, axis=0, keepdims=True))
 
     @jax.custom_vjp
     def norm_act(x, scale, shift):
@@ -176,13 +204,12 @@ def _norm_act(x3, scale3, shift3, act_type, interpret):
 def fused_bn_act_pallas(data, gamma, beta, moving_mean, moving_var,
                         act_type=None, eps=0.001, momentum=0.9,
                         fix_gamma=True, use_global_stats=False,
-                        is_train=False, interpret=None):
+                        is_train=False, interpret=False):
     """Pallas-tier fused BN(+act): lax batch statistics + one
     normalize+activate kernel pass (custom_vjp registered).  Semantics
-    and return shape match the registered BatchNorm op exactly."""
-    if interpret is None:
-        from ..rtc import on_tpu
-        interpret = not on_tpu()
+    and return shape match the registered BatchNorm op exactly.
+    ``interpret=True`` runs the same kernels in the Pallas interpreter
+    (the CPU tests); the default compiles them with Mosaic."""
     if act_type and act_type not in _PALLAS_ACTS:
         return fused_bn_act_lax(
             data, gamma, beta, moving_mean, moving_var, act_type=act_type,
@@ -200,8 +227,8 @@ def fused_bn_act_pallas(data, gamma, beta, moving_mean, moving_var,
         mean, var = moving_mean, moving_var
         new_mm, new_mv = moving_mean, moving_var
     inv = lax.rsqrt(var + eps)
-    scale = (inv * gamma).astype(data.dtype)
-    shift = (beta - mean * inv * gamma).astype(data.dtype)
+    scale = (inv * gamma).astype(jnp.float32)
+    shift = (beta - mean * inv * gamma).astype(jnp.float32)
     n, c = data.shape[0], data.shape[1]
     x3 = data.reshape(n, c, -1)
     out = _norm_act(x3, scale.reshape(1, c, 1), shift.reshape(1, c, 1),
@@ -210,20 +237,21 @@ def fused_bn_act_pallas(data, gamma, beta, moving_mean, moving_var,
 
 
 def fused_bn_act(data, gamma, beta, moving_mean, moving_var, **kw):
-    """Backend-routed fused BN(+activation): compiled Pallas on TPU,
-    fused-lax elsewhere (same signature/returns as the BatchNorm op,
-    plus ``act_type``).  The compiled kernel engages only for
-    (sublane, lane)-aligned (C, H*W) blocks; unaligned shapes take the
-    fused-lax path rather than paying Mosaic relayouts."""
-    from . import use_pallas
+    """Platform-routed fused BN(+activation): the compiled Pallas kernel
+    in a program lowered for a TPU, fused-lax anywhere else (same
+    signature/returns as the BatchNorm op, plus ``act_type``).  The
+    compiled kernel engages only for (sublane, lane)-aligned (C, H*W)
+    rows; unaligned shapes take the fused-lax path rather than paying
+    Mosaic relayouts."""
+    from . import by_platform
     spatial = 1
     for d in data.shape[2:]:
         spatial *= int(d)
-    if use_pallas() and spatial % 128 == 0 and data.shape[1] % 8 == 0:
-        return fused_bn_act_pallas(data, gamma, beta, moving_mean,
-                                   moving_var, interpret=False, **kw)
-    return fused_bn_act_lax(data, gamma, beta, moving_mean, moving_var,
-                            **kw)
+    args = (data, gamma, beta, moving_mean, moving_var)
+    if spatial % 128 == 0 and data.shape[1] % 8 == 0:
+        return by_platform(functools.partial(fused_bn_act_pallas, **kw),
+                           functools.partial(fused_bn_act_lax, **kw), *args)
+    return fused_bn_act_lax(*args, **kw)
 
 
 def fold_bn_into_conv(weight, bias, gamma, beta, moving_mean, moving_var,

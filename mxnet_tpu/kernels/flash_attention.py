@@ -18,8 +18,8 @@ Tiers (package docstring):
   differentiable by jax (the scan transposes to the standard recompute
   backward), O(T) memory.
 - :func:`flash_attention_pallas` — a ``pl.pallas_call`` kernel (grid
-  over batch x heads x query blocks, ``fori_loop`` over key blocks with
-  the running triple in registers/VMEM) behind ``jax.custom_vjp``; the
+  over batch x heads x query blocks x key blocks, the running triple in
+  VMEM scratch across the key axis) behind ``jax.custom_vjp``; the
   registered backward recomputes through the fused-lax tier (O(T)
   memory, the FlashAttention recompute discipline) — Pallas has no
   reverse-mode transpose (rtc.py contract; mxlint ``graph-pallas-no-vjp``
@@ -134,56 +134,62 @@ def flash_attention_lax(q, k, v, causal=False, scale=None, block_k=None):
 # Pallas tier
 # ---------------------------------------------------------------------------
 
-def _flash_kernel(causal, scale, Tq, Tk, bk, q_ref, k_ref, v_ref, o_ref):
-    """One (batch, head, q-block) program: fori_loop over key blocks
-    with the running (acc, m, s) triple held in VMEM values.  ``Tq``/
-    ``Tk`` are the TRUE (unpadded) lengths — causal offsets must not
-    see the block padding."""
+#: score of a masked position: finite, so the running max never needs an
+#: is-finite test (a fully masked row keeps s_run == 0 and outputs 0)
+_MASKED = -1e30
+
+
+def _flash_kernel(causal, scale, Tq, Tk, q_ref, k_ref, v_ref, o_ref,
+                  acc_ref, m_ref, s_ref):
+    """One (batch, head, q-block, k-block) program.  The k-block axis is
+    the innermost grid axis: the running (acc, m, s) triple lives in VMEM
+    scratch across it and the output block is written on its last step.
+    ``Tq``/``Tk`` are the TRUE (unpadded) lengths — causal offsets must
+    not see the block padding."""
     from jax.experimental import pallas as pl
 
-    bq = q_ref.shape[2]
-    D = q_ref.shape[3]
-    qi = pl.program_id(2)
-    q = q_ref[0, 0, :, :].astype(jnp.float32)          # (bq, D)
-    q_pos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0) \
-        + (Tk - Tq)
-    nk = -(-Tk // bk)
+    bq, bk = q_ref.shape[0], k_ref.shape[0]
+    kj = pl.program_id(3)
 
-    def body(j, carry):
-        acc, m_run, s_run = carry
-        kb = k_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        vb = v_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        k_pos = j * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        mask = k_pos < Tk
-        if causal:
-            mask = mask & (q_pos >= k_pos)
-        scores = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) \
-            * scale
-        scores = jnp.where(mask, scores, -jnp.inf)
-        m_blk = jnp.max(scores, axis=-1, keepdims=True)
-        m_safe = jnp.where(jnp.isfinite(m_blk), m_blk, 0.0)
-        p = jnp.where(mask, jnp.exp(scores - m_safe), 0.0)
-        s_blk = jnp.sum(p, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_run, jnp.where(s_blk > 0, m_safe, m_run))
-        alpha = jnp.where(jnp.isfinite(m_run), jnp.exp(m_run - m_new), 0.0)
-        beta = jnp.where(jnp.isfinite(m_blk) & (s_blk > 0),
-                         jnp.exp(m_safe - m_new), 0.0)
-        s_new = s_run * alpha + s_blk * beta
-        acc_new = acc * alpha + \
-            jnp.dot(p, vb, preferred_element_type=jnp.float32) * beta
-        return acc_new, m_new, s_new
+    @pl.when(kj == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        s_ref[...] = jnp.zeros_like(s_ref)
 
-    acc0 = jnp.zeros((bq, D), jnp.float32)
-    m0 = jnp.full((bq, 1), -jnp.inf, jnp.float32)
-    s0 = jnp.zeros((bq, 1), jnp.float32)
-    acc, _, s_run = lax.fori_loop(0, nk, body, (acc0, m0, s0))
-    o_ref[0, 0, :, :] = (acc / jnp.maximum(s_run, 1e-20)) \
-        .astype(o_ref.dtype)
+    q = q_ref[...].astype(jnp.float32)                  # (bq, D)
+    kb = k_ref[...].astype(jnp.float32)                 # (bk, D)
+    vb = v_ref[...].astype(jnp.float32)
+    k_pos = kj * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    mask = k_pos < Tk
+    if causal:
+        q_pos = pl.program_id(2) * bq + (Tk - Tq) \
+            + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        mask = mask & (q_pos >= k_pos)
+    # q @ k^T as a transposed-rhs contraction (no in-kernel transpose)
+    scores = lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(mask, scores, _MASKED)
+    m_run = m_ref[...]
+    m_new = jnp.maximum(m_run, jnp.max(scores, axis=-1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
+    alpha = jnp.exp(m_run - m_new)
+    s_ref[...] = s_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + \
+        jnp.dot(p, vb, preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(s_ref[...], 1e-20)) \
+            .astype(o_ref.dtype)
 
 
 def _flash_pallas_fwd(q, k, v, causal, scale, block, interpret):
-    """pallas_call over a (B, H, nq) grid in (B, H, T, D) layout."""
+    """pallas_call over a (B, H, nq, nk) grid in (B, H, T, D) layout:
+    q/o blocks of ``block`` rows, K/V streamed in ``block``-row blocks."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
@@ -196,25 +202,30 @@ def _flash_pallas_fwd(q, k, v, causal, scale, block, interpret):
     if pad_q:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
     bk = min(block, Tk)
-    pad_k = (-(-Tk // bk)) * bk - Tk
+    nk = -(-Tk // bk)
+    pad_k = nk * bk - Tk
     if pad_k:
         kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
 
-    kernel = functools.partial(_flash_kernel, causal, scale, Tq, Tk, bk)
-    kw = {"grid": (B, H, nq),
-          "in_specs": [
-              pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-              pl.BlockSpec((1, 1, kt.shape[2], D),
-                           lambda b, h, i: (b, h, 0, 0)),
-              pl.BlockSpec((1, 1, vt.shape[2], D),
-                           lambda b, h, i: (b, h, 0, 0))],
-          "out_specs": pl.BlockSpec((1, 1, bq, D),
-                                    lambda b, h, i: (b, h, i, 0))}
+    def spec(rows, index_map):
+        return pl.BlockSpec((None, None, rows, D), index_map,
+                            memory_space=pltpu.VMEM)
+    q_spec = spec(bq, lambda b, h, i, j: (b, h, i, 0))
+    kv_spec = spec(bk, lambda b, h, i, j: (b, h, j, 0))
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_flash_kernel, causal, scale, Tq, Tk),
         out_shape=jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
-        interpret=interpret, **kw)(qt, kt, vt)
+        grid=(B, H, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        name="mxtpu_flash_attention_fwd",
+        interpret=interpret)(qt, kt, vt)
     if pad_q:
         out = out[:, :, :Tq, :]
     return jnp.moveaxis(out, 2, 1)                      # (B, Tq, H, D)
@@ -245,11 +256,10 @@ _flash_pallas.defvjp(_fp_fwd, _fp_bwd)
 
 
 def flash_attention_pallas(q, k, v, causal=False, scale=None, block=None,
-                           interpret=None):
-    """Pallas-tier flash attention (custom_vjp registered)."""
-    if interpret is None:
-        from ..rtc import on_tpu
-        interpret = not on_tpu()
+                           interpret=False):
+    """Pallas-tier flash attention (custom_vjp registered).
+    ``interpret=True`` runs the same kernel in the Pallas interpreter
+    (the CPU tests); the default compiles it with Mosaic."""
     D = q.shape[-1]
     scale = scale or (1.0 / np.sqrt(D))
     return _flash_pallas(q, k, v, bool(causal), float(scale),
@@ -257,12 +267,13 @@ def flash_attention_pallas(q, k, v, causal=False, scale=None, block=None,
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block=None):
-    """Backend-routed flash attention: compiled Pallas on TPU, the lax
-    scan elsewhere.  Same contract as
-    :func:`~mxnet_tpu.parallel.ring_attention.full_attention`."""
-    from . import use_pallas
-    if use_pallas():
-        return flash_attention_pallas(q, k, v, causal=causal, scale=scale,
-                                      block=block, interpret=False)
-    return flash_attention_lax(q, k, v, causal=causal, scale=scale,
-                               block_k=block)
+    """Platform-routed flash attention: the compiled Pallas kernel in a
+    program lowered for a TPU, the lax scan anywhere else.  Same
+    contract as :func:`~mxnet_tpu.parallel.ring_attention.full_attention`."""
+    from . import by_platform
+    return by_platform(
+        functools.partial(flash_attention_pallas, causal=causal,
+                          scale=scale, block=block),
+        functools.partial(flash_attention_lax, causal=causal, scale=scale,
+                          block_k=block),
+        q, k, v)

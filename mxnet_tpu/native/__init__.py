@@ -1,74 +1,88 @@
 """Loader for the native host-runtime library (libmxtpu.so).
 
-The native layer provides the host-side dependency engine and the RecordIO
-codec (see engine.cc / recordio.cc).  It is built on first import if a
-compiler is available; all Python callers degrade gracefully to pure-Python
-fallbacks when it is not (so the framework stays importable on minimal
-systems).
+The native layer provides the host-side dependency engine, the RecordIO
+codec and the libjpeg image pipeline (engine.cc / recordio.cc /
+imagedec.cc / im2rec.cc).  It is built on first use from the sources
+beside this file; the library is git-ignored, so a fresh checkout always
+builds its own.  A build that fails raises with the compiler's output —
+it never degrades to a slower pure-Python or cv2 path behind the
+caller's back.  ``MXNET_NO_NATIVE=1`` is the one way to run without it.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 
+from ..base import MXNetError
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_DIR, "libmxtpu.so")
+#: sidecar naming the sources the library was built from (see _stale)
+_DIGEST_PATH = _LIB_PATH + ".src"
 _SRCS = ("engine.cc", "recordio.cc", "imagedec.cc", "im2rec.cc")
+_CMD = ("g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-pthread")
+_LIBS = ("-ljpeg",)
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build():
-    """Compile libmxtpu.so in-place.  Returns True on success.
+def _src_digest():
+    """Digest of everything the build reads: sources, header, command."""
+    h = hashlib.sha256(" ".join(_CMD + _LIBS).encode())
+    for name in _SRCS + ("mxtpu.h",):
+        with open(os.path.join(_DIR, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return h.hexdigest()
+
+
+def _build(digest):
+    """Compile libmxtpu.so in-place; raises MXNetError with the
+    compiler's stderr on failure.
 
     Compiles to a per-pid temp name then renames atomically so concurrent
-    first-use from multiple processes cannot dlopen a half-written file.
+    first-use from multiple processes cannot dlopen a half-written file;
+    the digest sidecar is published last, so a library without a matching
+    sidecar is rebuilt, never trusted.
     """
-    srcs = [os.path.join(_DIR, s) for s in _SRCS]
     tmp = _LIB_PATH + ".%d.tmp" % os.getpid()
-    base = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-pthread",
-            "-o", tmp]
-    # Preferred build includes the libjpeg image pipeline; hosts without
-    # libjpeg still get the engine + recordio codec (image callers fall
-    # back to the cv2 path).
-    _JPEG_SRCS = ("imagedec.cc", "im2rec.cc")
-    attempts = [base + srcs + ["-ljpeg"],
-                base + [s for s in srcs
-                        if not s.endswith(_JPEG_SRCS)]]
+    cmd = list(_CMD) + ["-o", tmp] + \
+        [os.path.join(_DIR, s) for s in _SRCS] + list(_LIBS)
     try:
-        built = False
-        for cmd in attempts:
-            proc = subprocess.run(cmd, capture_output=True, timeout=300)
-            if proc.returncode == 0 and os.path.exists(tmp):
-                built = True
-                break
-        if not built:
-            return False
+        try:
+            proc = subprocess.run(cmd, capture_output=True, text=True,
+                                  timeout=300)
+        except (OSError, subprocess.TimeoutExpired) as e:
+            raise MXNetError("native build could not run (%s): %s"
+                             % (" ".join(cmd), e))
+        if proc.returncode != 0 or not os.path.exists(tmp):
+            raise MXNetError("native build failed (rc %d): %s\n%s"
+                             % (proc.returncode, " ".join(cmd),
+                                proc.stderr))
         os.replace(tmp, _LIB_PATH)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+        with open(tmp, "w") as f:
+            f.write(digest)
+        os.replace(tmp, _DIGEST_PATH)
     finally:
         if os.path.exists(tmp):
-            try:
-                os.remove(tmp)
-            except OSError:
-                pass
-    return os.path.exists(_LIB_PATH)
+            os.remove(tmp)
 
 
-def _stale():
-    if not os.path.exists(_LIB_PATH):
+def _stale(digest):
+    """True unless the library on disk was built by :func:`_build` from
+    exactly the sources beside it.  Content, not mtimes: a copy or an
+    unpacked archive scrambles mtimes, and a library that came along from
+    another tree must not be loaded."""
+    try:
+        with open(_DIGEST_PATH) as f:
+            return f.read().strip() != digest \
+                or not os.path.exists(_LIB_PATH)
+    except OSError:
         return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    for s in _SRCS + ("mxtpu.h",):
-        p = os.path.join(_DIR, s)
-        if os.path.exists(p) and os.path.getmtime(p) > lib_mtime:
-            return True
-    return False
 
 
 def _configure(lib):
@@ -112,38 +126,28 @@ def _configure(lib):
     lib.MXTPURecordIOReaderClose.argtypes = [p]
     lib.MXTPUFree.argtypes = [p]
 
-    # Image pipeline (absent when the host lacks libjpeg — callers probe
-    # with has_imagedec()).
-    try:
-        fp = ctypes.POINTER(ctypes.c_float)
-        pp = ctypes.POINTER(ctypes.c_void_p)
-        lib.MXTPUImgPipeCreate.restype = p
-        lib.MXTPUImgPipeCreate.argtypes = [
-            ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,
-            ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int, fp, fp,
-            ctypes.c_int]
-        lib.MXTPUImgPipeDecodeBatch.restype = ctypes.c_int
-        lib.MXTPUImgPipeDecodeBatch.argtypes = [
-            p, pp, ctypes.POINTER(u64), ctypes.c_int, p,
-            ctypes.POINTER(ctypes.c_uint8), u64]
-        lib.MXTPUImgPipeDestroy.argtypes = [p]
-        lib.MXTPUImgDecodeDims.restype = ctypes.c_int
-        lib.MXTPUImgDecodeDims.argtypes = [
-            p, u64, ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int)]
-        lib.MXTPUImgDecode.restype = ctypes.c_int
-        lib.MXTPUImgDecode.argtypes = [p, u64, p, ctypes.c_int]
-        lib._has_imagedec = True
-    except AttributeError:
-        lib._has_imagedec = False
-    try:
-        lib.MXTPUIm2Rec.restype = ctypes.c_int
-        lib.MXTPUIm2Rec.argtypes = [
-            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
-            ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
-            ctypes.c_int, ctypes.POINTER(u64), ctypes.POINTER(u64)]
-        lib._has_im2rec = True
-    except AttributeError:
-        lib._has_im2rec = False
+    fp = ctypes.POINTER(ctypes.c_float)
+    pp = ctypes.POINTER(ctypes.c_void_p)
+    lib.MXTPUImgPipeCreate.restype = p
+    lib.MXTPUImgPipeCreate.argtypes = [
+        ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,
+        ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int, fp, fp,
+        ctypes.c_int]
+    lib.MXTPUImgPipeDecodeBatch.restype = ctypes.c_int
+    lib.MXTPUImgPipeDecodeBatch.argtypes = [
+        p, pp, ctypes.POINTER(u64), ctypes.c_int, p,
+        ctypes.POINTER(ctypes.c_uint8), u64]
+    lib.MXTPUImgPipeDestroy.argtypes = [p]
+    lib.MXTPUImgDecodeDims.restype = ctypes.c_int
+    lib.MXTPUImgDecodeDims.argtypes = [
+        p, u64, ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int)]
+    lib.MXTPUImgDecode.restype = ctypes.c_int
+    lib.MXTPUImgDecode.argtypes = [p, u64, p, ctypes.c_int]
+    lib.MXTPUIm2Rec.restype = ctypes.c_int
+    lib.MXTPUIm2Rec.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
+        ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
+        ctypes.c_int, ctypes.POINTER(u64), ctypes.POINTER(u64)]
     return lib
 
 
@@ -157,20 +161,21 @@ ENV_NO_NATIVE = register_env(
 
 
 def get_lib():
-    """Return the configured ctypes library, or None if unavailable."""
+    """Return the configured ctypes library, building it first when the
+    one on disk is missing or stale.  None only under MXNET_NO_NATIVE=1;
+    a failed build or load raises."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
     with _lock:
         if _lib is not None or _tried:
             return _lib
-        _tried = True
         if str(get_env(ENV_NO_NATIVE, "0")) == "1":
+            _tried = True
             return None
-        if _stale() and not _build():
-            return None
-        try:
-            _lib = _configure(ctypes.CDLL(_LIB_PATH))
-        except OSError:
-            _lib = None
+        digest = _src_digest()
+        if _stale(digest):
+            _build(digest)
+        _lib = _configure(ctypes.CDLL(_LIB_PATH))
+        _tried = True
     return _lib

@@ -28,8 +28,7 @@ def _decode_host(buf, flag, to_rgb):
     # the native JPEG path always yields 3 channels; flags other than
     # 0 (gray) and 1 (color) — e.g. IMREAD_UNCHANGED=-1, which must return
     # 2-D for grayscale sources like the reference _cvimdecode — go to cv2
-    if (lib is not None and getattr(lib, "_has_imagedec", False)
-            and int(flag) in (0, 1)):
+    if lib is not None and int(flag) in (0, 1):
         import ctypes as ct
         h = ct.c_int()
         w = ct.c_int()

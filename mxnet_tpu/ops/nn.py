@@ -118,6 +118,11 @@ def convolution(data, weight, bias=None, kernel=(), stride=None, dilate=None,
     dilate = _tuple(dilate or (1,) * nd, nd)
     pad = _tuple(pad if pad is not None else (0,) * nd, nd)
     dn = lax.conv_dimension_numbers(data.shape, weight.shape, _CONV_DIMNUMS[nd])
+    if data.dtype != weight.dtype:
+        # the weights' dtype is the layer's compute dtype: a bf16 serving
+        # pool (serving/pool.py) feeds f32 requests into bf16 weights, and
+        # lax.conv — unlike jnp.dot — refuses mixed operands
+        data = data.astype(weight.dtype)
     out = lax.conv_general_dilated(
         data, weight, window_strides=stride,
         padding=[(p, p) for p in pad], rhs_dilation=dilate,
@@ -185,6 +190,8 @@ def deconvolution(data, weight, bias=None, kernel=(), stride=None, pad=None,
         w = flipped.reshape((num_group, ci // num_group, co_g) + kernel)
         w = jnp.swapaxes(w, 1, 2).reshape((num_group * co_g, ci // num_group) + kernel)
     dn = lax.conv_dimension_numbers(data.shape, w.shape, _CONV_DIMNUMS[nd])
+    if data.dtype != w.dtype:    # as in convolution: the weights decide
+        data = data.astype(w.dtype)
     out = lax.conv_general_dilated(
         data, w, window_strides=(1,) * nd, padding=padding,
         lhs_dilation=stride, rhs_dilation=dilate,

@@ -229,21 +229,6 @@ def _jitted(op_name, attr_items, dyn_names, is_train, with_rng):
     return jax.jit(call)
 
 
-@functools.lru_cache(maxsize=1)
-def _callback_probe():
-    """One-time backend probe: can a pure_callback run under jit here?"""
-    import numpy as np
-    import jax
-    import jax.numpy as jnp
-    try:
-        f = jax.jit(lambda x: jax.pure_callback(
-            lambda a: np.asarray(a), jax.ShapeDtypeStruct((), jnp.float32), x))
-        jax.block_until_ready(f(jnp.float32(0.0)))
-        return True
-    except Exception:
-        return False
-
-
 def callbacks_under_jit_supported():
     """Whether graphs containing host-callback ops (Custom) may be
     whole-graph jitted.  Default: NO — callbacks then run inside the
@@ -255,13 +240,12 @@ def callbacks_under_jit_supported():
     host-side engine callback between kernel launches
     (src/operator/custom/custom-inl.h), and makes stateful callback RNG
     deterministic (pure_callback gives no execution-count guarantee).
-    Set MXNET_CUSTOM_UNDER_JIT=1 to opt into fused custom-op graphs.
-    The env var is read per call (only the backend probe is cached), so
-    toggling it mid-process takes effect at the next bind."""
+    Set MXNET_CUSTOM_UNDER_JIT=1 to opt into fused custom-op graphs
+    (host callbacks run under jit on the CPU and TPU backends alike).
+    The env var is read per call, so toggling it mid-process takes
+    effect at the next bind."""
     from ..base import get_env
-    if str(get_env(ENV_CUSTOM_UNDER_JIT, "0")) != "1":
-        return False
-    return _callback_probe()
+    return str(get_env(ENV_CUSTOM_UNDER_JIT, "0")) == "1"
 
 
 def _hashable(v):

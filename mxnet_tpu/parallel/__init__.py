@@ -24,9 +24,7 @@ Components:
   (shard_map + ppermute neighbor exchange)
 - moe.py: GShard-style top-2 mixture-of-experts over the 'ep' axis
   (dispatch/combine einsums -> all_to_all under GSPMD)
-- compat.py: JAX version shims (the shard_map spelling/kwarg drift)
 """
-from .compat import HAS_SHARD_MAP
 from .mesh import build_mesh, default_mesh, local_mesh
 from .trainer import SPMDTrainer
 from . import zero3  # noqa: F401 — EAGER env registration (MXTPU_ZERO3_*)

@@ -39,7 +39,6 @@ def pipeline_apply(fn, stage_params, x, mesh, axis_name="pp",
     returns: (B, ...) replicated result of stage S-1 ∘ ... ∘ stage 0
     """
     from jax.sharding import PartitionSpec as P
-    from .compat import shard_map
 
     n_stages = mesh.shape[axis_name]
     n_given = jax.tree_util.tree_leaves(stage_params)[0].shape[0]
@@ -95,7 +94,7 @@ def pipeline_apply(fn, stage_params, x, mesh, axis_name="pp",
                                  jnp.zeros_like(out)), axis_name)
         return out
 
-    fn_sharded = shard_map(
+    fn_sharded = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(), check_vma=False)

@@ -96,7 +96,6 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", causal=False,
     """Ring attention with q/k/v sharded on the sequence axis (axis 1) over
     ``axis_name`` of ``mesh``.  q,k,v: (B, T, H, D) global shapes."""
     from jax.sharding import PartitionSpec as P
-    from .compat import shard_map
 
     n_blocks = mesh.shape[axis_name]
     D = q.shape[-1]
@@ -108,8 +107,8 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", causal=False,
         return _ring_body(axis_name, n_blocks, causal, scale, q_blk, k_blk,
                           v_blk, my_idx)
 
-    fn = shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_vma=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 
@@ -120,4 +119,10 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
     if mesh is not None and axis_name in mesh.shape and \
             mesh.shape[axis_name] > 1:
         return ring_attention_sharded(q, k, v, mesh, axis_name, causal, scale)
+    if mesh is not None and mesh.size > 1:
+        # the caller's program is partitioned over the mesh's other axes:
+        # no Mosaic kernel there (kernels.auto_partitioned)
+        from ..kernels import auto_partitioned
+        with auto_partitioned():
+            return full_attention(q, k, v, causal=causal, scale=scale)
     return full_attention(q, k, v, causal=causal, scale=scale)

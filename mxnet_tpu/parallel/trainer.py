@@ -319,14 +319,13 @@ class SPMDTrainer(object):
         with plain psum gradients — correct either way).
 
         Tier: 'manual' (shard_map body, guaranteed all-gather/
-        reduce-scatter schedule) needs a pure-dp mesh, a shard_map
-        spelling, batch-leading outputs and at least one shardable
+        reduce-scatter schedule) needs a pure-dp mesh,
+        batch-leading outputs and at least one shardable
         param; anything else composes through the 'gspmd' tier.
         """
         from ..base import get_env
         from . import zero3 as z3
         from .zero3 import ENV_ZERO3_GATHER_GROUP
-        from .compat import HAS_SHARD_MAP
         shardable = {}
         for name in self.param_names:
             spec = self._param_spec(name, self.arg_shapes[name])
@@ -344,7 +343,7 @@ class SPMDTrainer(object):
         batch_leading = all(s and s[0] == self.batch_size
                             for s in self.out_shapes)
         self.zero3_tier = "manual" if (
-            pure_dp and HAS_SHARD_MAP and batch_leading and shardable
+            pure_dp and batch_leading and shardable
         ) else "gspmd"
 
     def _choose_gather_groups(self, shardable):
@@ -544,8 +543,25 @@ class SPMDTrainer(object):
         raise MXNetError("SPMDTrainer: in-graph rule for optimizer %r not "
                          "implemented (sgd/adam/rmsprop supported)" % kind)
 
-    def _build_step(self):
+    def _placed_eval(self, manual=False):
+        """``self._eval`` as one of this trainer's programs traces it.
+        A program the SPMD partitioner splits over several devices cannot
+        hold a Mosaic kernel (jax refuses to lower one there), so its
+        trace runs under ``kernels.auto_partitioned`` and the kernel
+        router keeps to the fused-lax tier; a single-device mesh and a
+        ``shard_map`` body (``manual``) keep the compiled tier."""
         eval_fn = self._eval
+        if manual or self.mesh is None or self.mesh.size == 1:
+            return eval_fn
+        from ..kernels import auto_partitioned
+
+        def traced(*args, **kw):
+            with auto_partitioned():
+                return eval_fn(*args, **kw)
+        return traced
+
+    def _build_step(self):
+        eval_fn = self._placed_eval()
         compute_dtype = self.compute_dtype
         transforms = dict(self.input_transforms)
 
@@ -773,9 +789,9 @@ class SPMDTrainer(object):
         """
         import jax
         from . import zero3 as z3
-        eval_fn = self._eval
         param_names = tuple(self.param_names)
         manual = self.zero3_tier == "manual"
+        eval_fn = self._placed_eval(manual)
         axis = self.data_axis
         dp = self.mesh.shape[axis]
         policy = z3.remat_policy()
@@ -849,7 +865,6 @@ class SPMDTrainer(object):
         # manual tier: the body above runs per-device under shard_map —
         # every collective is explicit, so the schedule cannot depend on
         # backend partitioner heuristics
-        from .compat import shard_map
         pspec = {n: (P(*[axis if i == shard_dim[n] else None
                          for i in range(len(self.arg_shapes[n]))])
                      if n in grouped else P())
@@ -862,8 +877,8 @@ class SPMDTrainer(object):
         out_specs = (pspec, P(), pspec, P(),
                      [P(axis, *([None] * (len(s) - 1)))
                       for s in self.out_shapes])
-        return shard_map(step, self.mesh, in_specs, out_specs,
-                         check_vma=False)
+        return jax.shard_map(step, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     # -- public API --------------------------------------------------------
     def stage_batch(self, *batch_arrays):
@@ -1647,7 +1662,7 @@ class SPMDTrainer(object):
         count+bytes even when nothing flags — bench.py's ``analyze``
         metric reads it), and dtype drift under ``compute_dtype``.
         Traces and compiles the step once; with a warm persistent
-        compile cache (MXTPU_COMPILE_CACHE) the XLA work is reused."""
+        compile cache (JAX_COMPILATION_CACHE_DIR) the XLA work is reused."""
         args = self._example_args(*batch_arrays)
         return self._lint_args(args, min_donate_bytes=min_donate_bytes)
 

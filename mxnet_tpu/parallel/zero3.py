@@ -10,7 +10,7 @@ parameter residency is ~1/world (``bench.py zero3`` proves it).
 
 Two tiers, the kernels-package discipline (Pallas/lax):
 
-- **manual** (pure-dp mesh, shard_map available): the whole step body
+- **manual** (pure-dp mesh): the whole step body
   runs under ``shard_map`` over the dp axis.  Gathers are explicit
   ``lax.all_gather`` calls — several same-group shards flatten into ONE
   bucketed collective — and their autodiff transpose IS

@@ -13,8 +13,8 @@ kernels first-class framework ops:
   ``vjp``), and the Module stack.
 - :func:`elementwise_pallas_kernel` — wrap a Pallas kernel *body*
   (``kernel(in_ref, out_ref)``) into a callable with sane VMEM block specs,
-  falling back to interpreter mode off-TPU so kernels are testable on the
-  virtual CPU mesh.
+  interpreted when lowered for anything but a TPU so kernels are testable
+  on the virtual CPU mesh.
 - :class:`MXRtc` — the reference's class shape (name/inputs/outputs +
   ``push``); the kernel is a Python/Pallas function instead of a CUDA
   source string (documented divergence: there is no NVRTC on TPU).
@@ -108,38 +108,38 @@ def register_kernel(name, fn=None, *, input_names=("data",), num_outputs=1,
 
 
 def on_tpu():
-    """Whether a real TPU backend is available — the tier selector for
-    two-tier kernels (mxnet_tpu/kernels/): compiled Pallas on TPU, the
-    fused-lax reference (or ``interpret=True``) elsewhere."""
-    try:
-        return jax.default_backend() == "tpu" or any(
-            d.platform == "tpu" for d in jax.devices())
-    except Exception:  # noqa: BLE001
-        return False
-
-
-_on_tpu = on_tpu  # historical private alias
+    """Whether the default JAX backend is a TPU.  A backend that fails
+    to initialize raises here — it is never read as "no TPU".  Kernels
+    do not route on this: they pick their tier from the platform the
+    program is lowered for (``kernels.by_platform``)."""
+    return jax.default_backend() == "tpu"
 
 
 def elementwise_pallas_kernel(kernel_body, interpret=None):
     """Wrap an elementwise Pallas kernel body ``kernel(in_ref, out_ref)``
     into ``fn(x) -> y`` with whole-array VMEM blocks.
 
-    ``interpret=None`` auto-selects: compiled on TPU backends, interpreter
-    elsewhere (so the same kernel runs on the virtual CPU mesh in tests —
-    the MXRtc story never had that).
+    ``interpret=None`` picks by the platform the call is lowered for:
+    compiled in a TPU program, the Pallas interpreter anywhere else (so
+    the same kernel runs on the virtual CPU mesh in tests — the MXRtc
+    story never had that).
     """
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = not _on_tpu()
-
-    def fn(x):
+    def call(interp, x):
         return pl.pallas_call(
             kernel_body,
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            interpret=interpret,
+            interpret=interp,
         )(x)
+
+    def fn(x):
+        if interpret is not None:
+            return call(interpret, x)
+        import functools
+        return jax.lax.platform_dependent(
+            x, tpu=functools.partial(call, False),
+            default=functools.partial(call, True))
     return fn
 
 

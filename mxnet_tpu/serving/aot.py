@@ -1,6 +1,6 @@
 """AOT executable store: serialized COMPILED (model, bucket) forwards.
 
-The persistent compile cache (``MXTPU_COMPILE_CACHE``) removes XLA
+The persistent compile cache (``JAX_COMPILATION_CACHE_DIR``) removes XLA
 compilation from a replica's bring-up — but the dominant remaining cost
 on re-trace is Python: binding the symbol graph and tracing one jitted
 forward per bucket shape (seconds for a deep net, per process).  This
@@ -16,7 +16,7 @@ call arguments (the pool keeps the single device-resident copy), so a
 store is a few hundred KB per program regardless of model size, and
 reloading never duplicates weights.
 
-Store layout (``<MXTPU_COMPILE_CACHE>/aot/``)::
+Store layout (``<jax_compilation_cache_dir>/aot/``)::
 
     <model>.json            meta: sample shapes, dtype, param/aux names,
                             platform, buckets — verified before loading
@@ -47,14 +47,17 @@ import numpy as np
 
 from ..base import MXNetError
 
-__all__ = ["AotStore", "aot_dir_for_cache"]
+__all__ = ["AotStore", "aot_dir"]
 
 _META_VERSION = 1
 
 
-def aot_dir_for_cache(cache_dir):
-    """The store's location inside a compile-cache directory."""
-    return os.path.join(cache_dir, "aot")
+def aot_dir():
+    """The store's location: inside the compile-cache directory this
+    process runs with (``JAX_COMPILATION_CACHE_DIR``, or the fixed
+    in-checkout default the package sets at import)."""
+    import jax
+    return os.path.join(jax.config.jax_compilation_cache_dir, "aot")
 
 
 def _log():
@@ -200,7 +203,13 @@ class AotStore(object):
                 payload = f.read()
             with open(base + ".tree", "rb") as f:
                 in_tree, out_tree = pickle.load(f)
-            return se.deserialize_and_load(payload, in_tree, out_tree)
+            # the store holds single-device forwards; without the
+            # device list jax loads onto EVERY local device and the call
+            # then wants one shard per device
+            import jax
+            return se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=jax.local_devices()[:1])
         except Exception as e:  # noqa: BLE001 — stale/foreign artifact
             _log().warning("AOT store %s: cannot load %s-b%d (%s: %s) — "
                            "falling back to trace warmup",

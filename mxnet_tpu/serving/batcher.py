@@ -4,7 +4,7 @@ The serving analog of Orca's iteration-level batching / Clipper's
 adaptive batching, shaped for XLA: every dispatched batch has one of a
 fixed set of power-of-two **bucket** sizes, so each bucket hits exactly
 ONE cached AOT-compiled forward (``predict.Predictor``'s per-shape jit
-cache, persisted across relaunches by ``MXTPU_COMPILE_CACHE``) instead
+cache, persisted across relaunches by ``JAX_COMPILATION_CACHE_DIR``) instead
 of recompiling per arrival count.
 
 Dispatch policy (continuous batching): the dispatcher takes everything

@@ -14,7 +14,7 @@ to draining (new predicts get 503, ``/healthz`` reports ``draining``),
 every ACCEPTED request finishes and gets its 200, then the process
 exits 0.  A wedged forward is the StepWatchdog's job — armed around
 each batch dispatch, it dumps stacks and aborts with exit 87 so the
-supervisor relaunches the daemon (warm via ``MXTPU_COMPILE_CACHE``).
+supervisor relaunches the daemon (warm via ``JAX_COMPILATION_CACHE_DIR``).
 """
 from __future__ import annotations
 

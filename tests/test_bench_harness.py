@@ -44,11 +44,9 @@ def test_collect_timeout_returns_partial_record_fast():
                           "kill regressed" % elapsed)
 
 
-def test_collect_extra_env_none_strips_variable(monkeypatch):
-    """``extra_env={VAR: None}`` must REMOVE the variable from the
-    child env (the resume drill strips a global MXTPU_COMPILE_CACHE —
-    jax's persistent cache segfaults that mode's save/restore/second-
-    trainer sequence on this backend), while plain values overlay."""
+def test_collect_extra_env_overlays_child_env(monkeypatch):
+    """``extra_env`` overlays the child's environment — how main()
+    points both compile-probe runs at ONE fixed cache directory."""
     import subprocess
 
     seen = {}
@@ -68,18 +66,15 @@ def test_collect_extra_env_none_strips_variable(monkeypatch):
         seen.update(env or {})
         return _Proc()
 
-    monkeypatch.setenv("MXTPU_COMPILE_CACHE", "/tmp/somewhere")
     monkeypatch.setattr(subprocess, "Popen", fake_popen)
-    bench._collect("resume", timeout=5,
-                   extra_env={"MXTPU_COMPILE_CACHE": None,
-                              "BENCH_X": "1"})
-    assert "MXTPU_COMPILE_CACHE" not in seen
-    assert seen["BENCH_X"] == "1"
-    assert seen["BENCH_MODE"] == "resume"
-    # the full round actually wires the strip at the resume call site
+    bench._collect("compile-probe", timeout=5,
+                   extra_env={"JAX_COMPILATION_CACHE_DIR": "/x/cache"})
+    assert seen["JAX_COMPILATION_CACHE_DIR"] == "/x/cache"
+    assert seen["BENCH_MODE"] == "compile-probe"
+    # the cache directory the full round hands its probes never moves
     import inspect
     src = inspect.getsource(bench.main)
-    assert '"MXTPU_COMPILE_CACHE": None' in src
+    assert "mkdtemp" not in src and '".jax_cache"' in src
 
 
 def test_collect_failed_mode_returns_status_record():

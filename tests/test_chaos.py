@@ -1038,7 +1038,7 @@ def test_serving_drill_wedged_forward_watchdog_supervise_relaunch(
                MXTPU_FAULTS="hang_serve_forward:1",
                MXTPU_STEP_TIMEOUT="4",
                MXTPU_DEBUG_DIR=str(debug_dir),
-               MXTPU_COMPILE_CACHE=str(tmp_path / "xla_cache"))
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla_cache"))
     proc = subprocess.Popen(
         [sys.executable, SUPERVISE, "--max-restarts", "1", "--backoff",
          "0", "--", sys.executable, str(script)],

@@ -102,7 +102,7 @@ def _kw(path, idx, **over):
 def _native_decoder_available():
     from mxnet_tpu import native
     lib = native.get_lib()
-    return lib is not None and getattr(lib, "_has_imagedec", False)
+    return lib is not None
 
 
 # ---------------------------------------------------------------------------

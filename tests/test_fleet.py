@@ -85,8 +85,14 @@ def test_replica_device_env_specs():
     assert env0["TPU_VISIBLE_CHIPS"] == "0,1"
     assert env1["TPU_VISIBLE_CHIPS"] == "2,3"
     assert env0["JAX_PLATFORMS"] == "tpu"
-    # wrap-around: more replicas than chip sets co-tenant
-    assert replica_device_env("tpu:0;1", 2)["TPU_VISIBLE_CHIPS"] == "0"
+    # a chip belongs to one process: more replicas than chip sets is an
+    # error, at the replica and already at the manifest
+    with pytest.raises(MXNetError, match="one process"):
+        replica_device_env("tpu:0;1", 2)
+    with pytest.raises(MXNetError, match="one process"):
+        FleetManifest({"m": "/x:1"}, replicas=3, device_sets="tpu:0;1")
+    assert FleetManifest({"m": "/x:1"}, replicas=2,
+                         device_sets="tpu:0;1").device_sets == "tpu:0;1"
     # single-chip sets pin the 1x1x1 process topology too
     single = replica_device_env("tpu:0;1", 1)
     assert single["TPU_PROCESS_BOUNDS"] == "1,1,1"
@@ -758,8 +764,8 @@ def test_controller_affinity_partitions_cores():
 _STUB_SERVE = r"""
 import os, sys
 assert "--warmup-only" in sys.argv
-cache = os.environ.get("MXTPU_COMPILE_CACHE")
-assert cache, "warm store build must set MXTPU_COMPILE_CACHE"
+cache = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+assert cache, "warm store build must set JAX_COMPILATION_CACHE_DIR"
 with open(os.path.join(cache, "compiled.bin"), "w") as f:
     f.write("programs")
 sys.stderr.write("mxserve: warmup_s=1.234\n")

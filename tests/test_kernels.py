@@ -502,3 +502,141 @@ def test_trainer_guard_counters_are_one_stacked_carry():
         assert tr.consecutive_bad_steps == 0
     finally:
         tr.close()
+
+
+# ---------------------------------------------------------------------------
+# compiled tier: the router picks it by the platform a program is LOWERED
+# for; on a chip (MXTPU_TEST_PLATFORM=tpu) Mosaic compiles each kernel at
+# the shapes the supported models present and it must match its lax tier
+# ---------------------------------------------------------------------------
+
+needs_chip = pytest.mark.skipif(
+    jax.default_backend() != "tpu",
+    reason="compiles the Pallas kernels with Mosaic: send through the "
+           "chip tool under MXTPU_TEST_PLATFORM=tpu")
+
+
+def _sq(x):
+    return (x.astype(jnp.float32) ** 2).sum()
+
+
+def _compiled_case(name):
+    """(routed fn, lax fn, argument shapes, kernel names) per kernel,
+    both fns returning ``(loss, outputs)`` for value_and_grad."""
+    if name == "bn_act":        # ResNet-50 / Inception-BN bn0
+        args = ((256, 64, 112, 112), (64,), (64,))
+        mm, mv = jnp.zeros(64, jnp.float32), jnp.ones(64, jnp.float32)
+
+        def wrap(fn):
+            def f(x, g, b):
+                o, m, v = fn(x, g, b, mm, mv, act_type="relu",
+                             fix_gamma=False, is_train=True)
+                return (o.astype(jnp.float32) ** 2).mean(), (o, m, v)
+            return f
+        return wrap(BA.fused_bn_act), wrap(BA.fused_bn_act_lax), args, \
+            ("mxtpu_bn_act_fwd", "mxtpu_bn_act_bwd")
+    if name == "lstm_cell":     # an aligned cell: H=256, rows % 8 == 0
+        args = ((64, 1024), (64, 256))
+
+        def wrap(fn):
+            def f(g, c):
+                h, c2 = fn(g, c)
+                return _sq(h) + c2.astype(jnp.float32).sum(), (h, c2)
+            return f
+        return wrap(LC.lstm_cell), wrap(LC.lstm_cell_lax), args, \
+            ("mxtpu_lstm_cell_fwd", "mxtpu_lstm_cell_bwd")
+    # example/long-context's attention: B=4, T=512, 4 heads of 16
+    args = ((4, 512, 4, 16),) * 3
+
+    def wrap(fn):
+        def f(q, k, v):
+            o = fn(q, k, v, causal=True)
+            return _sq(o), o
+        return f
+    return wrap(FA.flash_attention), wrap(FA.flash_attention_lax), args, \
+        ("mxtpu_flash_attention_fwd",)
+
+
+_KERNELS = ["bn_act", "lstm_cell", "flash_attention"]
+
+
+@pytest.mark.parametrize("name", _KERNELS)
+def test_router_picks_tier_by_lowering_platform(name):
+    """The same routed call lowers to the Mosaic kernels in a TPU
+    program and to plain lax in a CPU one — decided by where the
+    computation is placed, not by which backends the host happens to
+    have (``kernels.by_platform``)."""
+    from mxnet_tpu.kernels import compiled_kernels
+    routed, _, shapes, names = _compiled_case(name)
+    specs = [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
+    traced = jax.jit(jax.value_and_grad(routed, has_aux=True)).trace(*specs)
+    tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+    cpu = traced.lower(lowering_platforms=("cpu",)).as_text()
+    assert set(compiled_kernels(tpu)) == set(names)
+    assert compiled_kernels(cpu) == {}
+
+
+@needs_chip
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", _KERNELS)
+def test_compiled_tier_matches_lax_on_the_chip(name, dtype):
+    """Forward and backward of the Mosaic-compiled kernel against the
+    fused-lax tier, at the model's shape, within the documented
+    tolerances.  (flash attention's bf16 gradient is compared in f32
+    only: the lax tier's own bf16 gradient is not finite on the chip.)"""
+    from mxnet_tpu.kernels import compiled_kernels
+    routed, lax_fn, shapes, names = _compiled_case(name)
+    rs = np.random.RandomState(9)
+    args = [jnp.asarray(rs.randn(*s).astype("f")).astype(dtype)
+            for s in shapes]
+    argnums = tuple(range(len(args)))
+    fp = jax.jit(jax.value_and_grad(routed, argnums, has_aux=True))
+    fl = jax.jit(jax.value_and_grad(lax_fn, argnums, has_aux=True))
+    assert set(compiled_kernels(
+        fp.lower(*args).compile().as_text())) == set(names)
+    (_, outs_p), grads_p = fp(*args)
+    (_, outs_l), grads_l = fl(*args)
+    for a, b in zip(jax.tree.leaves(outs_p), jax.tree.leaves(outs_l)):
+        _close(a, b, dtype)
+    if name == "flash_attention" and dtype == "bfloat16":
+        return
+    for a, b in zip(grads_p, grads_l):
+        _close(a, b, dtype, grad=True)
+
+
+@pytest.mark.parametrize("sync,ndev,want", [
+    ("allreduce", 1, True), ("allreduce", 4, False), ("zero3", 4, True)])
+def test_trainer_step_lowers_for_tpu_at_every_placement(sync, ndev, want):
+    """jax refuses to lower a Mosaic kernel in a program the SPMD
+    partitioner splits over devices — the first ResNet-50 step on four
+    real chips died of it.  A GSPMD-tier step on a multi-device mesh must
+    keep to the lax tier (``kernels.auto_partitioned``); a single-device
+    step and the zero3 ``shard_map`` body keep the compiled kernel.
+    Lowering for the TPU platform from the CPU mesh shows all three."""
+    from mxnet_tpu.kernels import compiled_kernels
+    from mxnet_tpu.parallel import SPMDTrainer, default_mesh
+    if len(jax.devices()) < ndev:
+        pytest.skip("needs %d devices" % ndev)
+    data = mx.sym.Variable("data")
+    net = mx.sym.Convolution(data, num_filter=8, kernel=(3, 3), pad=(1, 1),
+                             name="c1")
+    net = mx.sym.Activation(mx.sym.BatchNorm(net, name="bn1",
+                                             fix_gamma=False),
+                            act_type="relu")
+    net = mx.sym.SoftmaxOutput(mx.sym.FullyConnected(
+        mx.sym.Flatten(net), num_hidden=4, name="fc"), name="softmax")
+    tr = SPMDTrainer(net, "sgd", {"learning_rate": 0.1,
+                                  "rescale_grad": 0.125},
+                     mesh=default_mesh(devices=jax.devices()[:ndev]),
+                     grad_sync=sync)
+    try:
+        # bn1 sees (8, 8, 16, 8): C % 8 == 0 and H*W == 128 -> aligned
+        tr.bind([("data", (8, 3, 16, 8))], [("softmax_label", (8,))])
+        tr.init_params(mx.initializer.Xavier())
+        args = tr._example_args(np.zeros((8, 3, 16, 8), "f"),
+                                np.zeros(8, "f"))
+        text = tr._step_fn.trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+    finally:
+        tr.close()
+    assert bool(compiled_kernels(text)) is want

@@ -212,9 +212,6 @@ def test_spmd_matches_single_device():
     np.testing.assert_allclose(w_single, w_spmd, rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.skipif(not __import__("mxnet_tpu").parallel.HAS_SHARD_MAP,
-                    reason="this JAX has no shard_map spelling "
-                           "(parallel/compat.py)")
 def test_ring_attention_matches_full():
     """Ring attention over sp=4 == full attention, causal and not."""
     import jax
@@ -497,9 +494,6 @@ def test_zero3_gather_groups_follow_plan_order(monkeypatch):
     t.close()
 
 
-@pytest.mark.skipif(not __import__("mxnet_tpu").parallel.HAS_SHARD_MAP,
-                    reason="zero3 manual tier needs shard_map "
-                           "(parallel/compat.py)")
 def test_zero3_composes_with_tp():
     """One trainer config expresses dp x tp: explicit tp rules keep
     their sharding (GSPMD tier engages on the multi-axis mesh), the

@@ -8,15 +8,9 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu.parallel import (HAS_SHARD_MAP, build_mesh, moe_ffn,
-                                moe_init, moe_shardings, pipeline_apply,
+from mxnet_tpu.parallel import (build_mesh, moe_ffn, moe_init,
+                                moe_shardings, pipeline_apply,
                                 stack_stage_params)
-
-# pipeline_apply rides shard_map (resolved across JAX spellings by
-# parallel/compat.py); skip cleanly on a JAX that ships neither
-needs_shard_map = pytest.mark.skipif(
-    not HAS_SHARD_MAP,
-    reason="this JAX has no shard_map spelling (parallel/compat.py)")
 
 
 def _devices(n):
@@ -26,7 +20,6 @@ def _devices(n):
     return devs[:n]
 
 
-@needs_shard_map
 def test_pipeline_matches_sequential():
     S = 4
     devs = _devices(S)
@@ -51,7 +44,6 @@ def test_pipeline_matches_sequential():
                                rtol=1e-5, atol=1e-6)
 
 
-@needs_shard_map
 def test_pipeline_microbatch_counts():
     S = 2
     devs = _devices(S)
@@ -137,7 +129,6 @@ def test_moe_capacity_drops_tokens():
     assert not np.allclose(np.asarray(out), dense)
 
 
-@needs_shard_map
 def test_pipeline_rejects_stage_count_mismatch():
     devs = _devices(2)
     mesh = build_mesh({"pp": 2}, devs)

@@ -1199,7 +1199,7 @@ def test_serve_daemon_warms_from_aot_store(tmp_path):
     sym, args, prefix = _save_mlp(tmp_path)
     store = str(tmp_path / "cache")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               MXTPU_COMPILE_CACHE=store)
+               JAX_COMPILATION_CACHE_DIR=store)
     res = subprocess.run(
         [sys.executable, SERVE, "--model", "mlp=%s:1" % prefix,
          "--input-shape", "data=32", "--port", "0",
@@ -1210,7 +1210,8 @@ def test_serve_daemon_warms_from_aot_store(tmp_path):
     assert os.path.isdir(os.path.join(store, "aot"))
     proc, port = _spawn_daemon(tmp_path, prefix, "--warmup",
                                "--buckets", "1,2,4",
-                               env_extra={"MXTPU_COMPILE_CACHE": store})
+                               env_extra={
+                                   "JAX_COMPILATION_CACHE_DIR": store})
     try:
         # the daemon's stderr says it warmed from the store
         x = np.random.RandomState(6).rand(32).astype("f")

@@ -105,7 +105,7 @@ def _add_manifest_flags(p):
                    help="device placement: 'cpu' or 'tpu:0,1;2,3' "
                         "(replica i -> chip set i)")
     p.add_argument("--warm-store", default=None,
-                   help="AOT warm store directory (MXTPU_COMPILE_CACHE "
+                   help="AOT warm store directory (JAX_COMPILATION_CACHE_DIR "
                         "for every replica; `serve` builds it when "
                         "missing)")
 

@@ -126,7 +126,7 @@ def make_record_native(args):
     options aren't covered (the Python path then serves)."""
     from mxnet_tpu import native as _native
     lib = _native.get_lib()
-    if lib is None or not getattr(lib, "_has_im2rec", False):
+    if lib is None:
         return False
     if args.center_crop or args.encoding != ".jpg" or args.color != 1:
         return False   # cv2-only options

@@ -9,14 +9,18 @@ N JAX processes that join a global device topology through
 launcher spawns them with the ``MXTPU_*`` envs the workers read.
 
 Local mode (default) runs all N workers on this host — the exact analog of
-the reference's ``--launcher local`` used by its nightly dist tests.  For
+the reference's ``--launcher local`` used by its nightly dist tests — and
+only on the CPU platform (``--platform cpu``, the virtual cluster): a TPU
+chip belongs to one process, and N ranks started here would each open
+every chip of the host, so the launcher refuses.  On a TPU host ONE
+process drives all its chips (``SPMDTrainer`` / ``kvstore='tpu'``); for
 real multi-host pods, use the cluster scheduler (GKE/slurm) to start one
 process per host with the same envs; there is no ssh fan-out here by
 design (pods are provisioned, not ssh'd into).
 
 Usage::
 
-    python tools/launch.py -n 4 python train.py --kv-store dist_sync
+    python tools/launch.py -n 4 --platform cpu python train.py --kv-store dist_sync
     python tools/launch.py -n 2 --platform cpu python tests/dist/dist_sync_kvstore.py
 """
 import argparse
@@ -49,8 +53,16 @@ def launch(num_workers, command, platform=None, port=None, env=None,
     worker triggers termination of the rest, like the reference tracker's
     local mode killing the job on a dead role.
     """
-    port = port or _free_port()
     base = dict(os.environ if env is None else env)
+    plat = platform or base.get("MXTPU_PLATFORM") or base.get("JAX_PLATFORMS")
+    if num_workers > 1 and plat != "cpu":
+        raise ValueError(
+            "local mode would start %d processes on this host with "
+            "platform %r: each would open every accelerator chip of the "
+            "host, and a chip belongs to one process.  Pass --platform cpu "
+            "for the virtual cluster; on a TPU host run ONE process over "
+            "all its chips" % (num_workers, plat))
+    port = port or _free_port()
     base["MXTPU_COORDINATOR"] = "127.0.0.1:%d" % port
     base["MXTPU_NUM_WORKERS"] = str(num_workers)
     if platform:

@@ -17,9 +17,9 @@ checksum verification.
 
 Lifecycle: SIGTERM/SIGINT drain (finish accepted requests, then exit 0);
 a wedged forward is killed by the StepWatchdog (``MXTPU_STEP_TIMEOUT``,
-exit 87) so ``tools/supervise.py`` can relaunch the daemon — warm, when
-``MXTPU_COMPILE_CACHE`` is set (compiled bucket programs reload from
-disk).  Serving knobs: ``MXTPU_SERVE_*`` (docs/env_vars.md) or the
+exit 87) so ``tools/supervise.py`` can relaunch the daemon — warm:
+compiled bucket programs reload from the persistent compile cache
+(``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``).  Serving knobs: ``MXTPU_SERVE_*`` (docs/env_vars.md) or the
 equivalent flags below.
 """
 import argparse
@@ -119,7 +119,7 @@ def main(argv=None):
                         help="BUILD the AOT executable store: compile "
                              "every (model, bucket) forward and "
                              "serialize the executables under "
-                             "MXTPU_COMPILE_CACHE/aot (pair with "
+                             "JAX_COMPILATION_CACHE_DIR/aot (pair with "
                              "--warmup-only; replicas launched with "
                              "the same cache dir then warm by LOADING "
                              "instead of compiling)")
@@ -155,12 +155,9 @@ def main(argv=None):
     if args.warmup or args.warmup_only or args.export_aot:
         import time as _time
 
-        from mxnet_tpu.base import get_env as _get_env
-        from mxnet_tpu.base import ENV_COMPILE_CACHE as _ENV_CC
-        from mxnet_tpu.serving.aot import aot_dir_for_cache
+        from mxnet_tpu.serving import aot
 
-        cache_dir = _get_env(_ENV_CC)
-        aot_dir = aot_dir_for_cache(cache_dir) if cache_dir else None
+        aot_dir = aot.aot_dir()
         tic = _time.monotonic()
         buckets = parse_buckets(args.buckets)
         for name in pool.names():
@@ -175,15 +172,12 @@ def main(argv=None):
                 # the store BUILDER: compile + serialize each bucket's
                 # executable (no Predictor warmup — this process never
                 # serves)
-                if aot_dir is None:
-                    raise SystemExit("--export-aot needs "
-                                     "MXTPU_COMPILE_CACHE set")
                 entry.export_aot(buckets, aot_dir)
                 sys.stderr.write("mxserve: exported AOT executables "
                                  "for %r over buckets %s\n"
                                  % (name, list(buckets)))
                 continue
-            loaded = entry.load_aot(aot_dir, buckets) if aot_dir else 0
+            loaded = entry.load_aot(aot_dir, buckets)
             if loaded:
                 sys.stderr.write("mxserve: warmed %r from the AOT "
                                  "store (%d/%d buckets)\n"
