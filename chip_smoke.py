@@ -64,49 +64,29 @@ def log(msg):
     print(msg, flush=True)
 
 
-class Meter(object):
-    """Process-wide compile seconds and persistent-cache hits/misses, read
-    off jax's own monitoring events; phases report the delta they caused."""
+def mark():
+    """(perf-counter seconds, the program's counters) now, for
+    :func:`since`."""
+    from mxnet_tpu import profiler
+    return time.perf_counter(), profiler.counters()
 
-    _COMPILE = ("/jax/core/compile/jaxpr_trace_duration",
-                "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                "/jax/core/compile/backend_compile_duration")
 
-    def __init__(self):
-        import jax
-        self._lock = threading.Lock()
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event in self._COMPILE:
-            with self._lock:
-                self.compile_s += secs
-
-    def _event(self, event, **_):
-        with self._lock:
-            if event == "/jax/compilation_cache/cache_hits":
-                self.hits += 1
-            elif event == "/jax/compilation_cache/cache_misses":
-                self.misses += 1
-
-    def snapshot(self):
-        with self._lock:
-            return (time.perf_counter(), self.compile_s, self.hits,
-                    self.misses)
-
-    def since(self, snap):
-        """{wall_s, compile_s, run_s, cache_hits, cache_misses} since
-        ``snap``.  compile_s sums trace + lowering + backend compile (a
-        cache hit's retrieval included); run_s is the rest of the wall."""
-        t, c, h, m = self.snapshot()
-        wall, comp = t - snap[0], c - snap[1]
-        return {"wall_s": round(wall, 2), "compile_s": round(comp, 2),
-                "run_s": round(max(0.0, wall - comp), 2),
-                "cache_hits": h - snap[2], "cache_misses": m - snap[3]}
+def since(snap):
+    """{wall_s, compile_s, run_s, cache_hits, cache_misses} since the
+    :func:`mark` ``snap``, from the program's own recorder: ``compile.*``
+    events (trace + lowering + backend compile, a cache hit's retrieval
+    included) and the ``compile.cache_*`` counters; run_s is the rest of
+    the wall."""
+    from mxnet_tpu import profiler
+    now, counted = mark()
+    wall = now - snap[0]
+    comp = sum(r["end"] - r["start"] for r in profiler.spans(since=snap[0])
+               if r["name"].startswith("compile."))
+    return {"wall_s": round(wall, 2), "compile_s": round(comp, 2),
+            "run_s": round(max(0.0, wall - comp), 2),
+            **{key: counted.get("compile." + key, 0)
+               - snap[1].get("compile." + key, 0)
+               for key in ("cache_hits", "cache_misses")}}
 
 
 def device_report():
@@ -523,7 +503,7 @@ def main():
         return 2
 
     from mxnet_tpu import models
-    meter = Meter()
+    start = mark()
     cache_dir, entries_before = cache_entries()
     log("compile cache: %s (JAX_COMPILATION_CACHE_DIR %s), %d entries"
         % (cache_dir,
@@ -531,24 +511,24 @@ def main():
            entries_before))
     work = tempfile.mkdtemp(prefix="chip_smoke_")
     try:
-        snap = meter.snapshot()
+        snap = mark()
         phase_calibration()
-        log("calibration: %r" % meter.since(snap))
+        log("calibration: %r" % since(snap))
 
-        snap = meter.snapshot()
+        snap = mark()
         from bench import _make_dataset
         rec = _make_dataset(STEPS_PER_EPOCH * BATCH, 256, LABEL_CLASSES,
                             directory=work)
         log("dataset: %d JPEGs in %.1fs"
-            % (STEPS_PER_EPOCH * BATCH, meter.since(snap)["wall_s"]))
+            % (STEPS_PER_EPOCH * BATCH, since(snap)["wall_s"]))
 
         sym = models.get_symbol(MODEL, num_classes=NUM_CLASSES)
         ckpt_dir = os.path.join(work, "ckpt")
         trace_dir = os.path.join(work, "trace")
-        snap = meter.snapshot()
+        snap = mark()
         train = phase_train(sym, rec, IMAGE, BATCH, EPOCHS,
                             ckpt_dir=ckpt_dir, trace_dir=trace_dir)
-        log(_fmt_train("train", train, meter.since(snap)))
+        log(_fmt_train("train", train, since(snap)))
         assert train["last_loss"] < train["first_loss"], (
             "the loss did not fall over %d steps" % train["steps"])
         assert train["pallas_kernels"], (
@@ -558,22 +538,22 @@ def main():
         planes = check_trace(trace_dir, "/device:TPU")
         log("trace: planes with events %r" % planes)
 
-        snap = meter.snapshot()
+        snap = mark()
         second = phase_second_trainer(sym, IMAGE, BATCH)
         log("second trainer (built after the first was closed): staged "
             "step %.1f ms against the first trainer's %.1f ms | %r"
             % (second["staged_step_ms"], train["staged_step_ms"],
-               meter.since(snap)))
+               since(snap)))
 
-        snap = meter.snapshot()
+        snap = mark()
         serve = phase_serve(ckpt_dir, sample_inputs(rec, 9, IMAGE))
-        log("serve: %r | %r" % (serve, meter.since(snap)))
+        log("serve: %r | %r" % (serve, since(snap)))
 
         if dev["count"] >= 4:
-            snap = meter.snapshot()
+            snap = mark()
             phase_multichip(sym, rec, IMAGE, MULTICHIP_BATCH,
                             MULTICHIP_EPOCHS)
-            log("multichip: %r" % meter.since(snap))
+            log("multichip: %r" % since(snap))
         else:
             log("multichip: %d device, phase not run" % dev["count"])
     finally:
@@ -581,10 +561,12 @@ def main():
 
     _, entries_after = cache_entries()
     peak = peak_bytes()
+    whole = since(start)
     log("compile cache: %d entries before, %d after; %d hits, %d misses, "
         "%.1fs compiling in all" % (entries_before, entries_after,
-                                    meter.hits, meter.misses,
-                                    meter.compile_s))
+                                    whole["cache_hits"],
+                                    whole["cache_misses"],
+                                    whole["compile_s"]))
     log("device 0 peak_bytes_in_use: %s"
         % ("not reported" if peak is None else "%.2f GiB" % (peak / 2 ** 30)))
     log("losses: %s" % json.dumps(train["losses"]))

@@ -26,6 +26,7 @@ import logging
 import queue
 import threading
 
+from . import profiler
 from .base import MXNetError
 from .io import DataBatch, DataIter, StagedBatch
 
@@ -90,6 +91,7 @@ class DevicePrefetchIter(DataIter):
         self._stage = _resolve_stage(stage)
         self.depth = max(0, int(depth))
         self._gen = 0
+        self._staged = 0    # batches numbered so far: the spans' `batch`
         self._done = False
         self._stop = threading.Event()
         self._thread = None
@@ -112,33 +114,41 @@ class DevicePrefetchIter(DataIter):
             name="DevicePrefetchIter", daemon=True)
         self._thread.start()
 
-    def _worker(self, gen, stop):
+    def _next_staged(self):
+        """Pull the source's next batch and stage it, under the feed's
+        number for it (``batch_no``), which its spans carry as ``batch``."""
         from .resilience import retrying_next
+        n, self._staged = self._staged, self._staged + 1
+        with profiler.span("feed.source_next", batch=n):
+            batch = retrying_next(self._iter, name="device_prefetch.next")
+        with profiler.span("feed.stage", batch=n):
+            item = self._stage_one(batch)
+        item.batch_no = n
+        return item
+
+    def _worker(self, gen, stop):
         while not stop.is_set():
             try:
-                batch = retrying_next(self._iter, name="device_prefetch.next")
+                item = self._next_staged()
             except StopIteration:
-                self._put(gen, _END, stop)
-                return
+                item = _END
             except Exception as e:  # noqa: BLE001 — surfaced to consumer
-                self._put(gen, _WorkerError(e), stop)
-                return
-            try:
-                item = self._stage_one(batch)
-            except Exception as e:  # noqa: BLE001 — surfaced to consumer
-                self._put(gen, _WorkerError(e), stop)
-                return
+                item = _WorkerError(e)
             self._put(gen, item, stop)
+            if item is _END or isinstance(item, _WorkerError):
+                return
 
     def _put(self, gen, item, stop):
         """Bounded put that aborts promptly on shutdown (a plain blocking
         put would deadlock close() when the consumer is gone)."""
-        while not stop.is_set():
-            try:
-                self._queue.put((gen, item), timeout=0.05)
-                return
-            except queue.Full:
-                continue
+        with profiler.span("feed.put_wait",
+                           batch=getattr(item, "batch_no", None)):
+            while not stop.is_set():
+                try:
+                    self._queue.put((gen, item), timeout=0.05)
+                    return
+                except queue.Full:
+                    continue
 
     def _stage_one(self, batch):
         # deterministic fault points for the staging path: "stage_batch"
@@ -202,15 +212,30 @@ class DevicePrefetchIter(DataIter):
         if self._done:
             raise StopIteration
         if self.depth == 0:
-            from .resilience import retrying_next
             try:
-                batch = retrying_next(self._iter,
-                                      name="device_prefetch.next")
+                self.current_batch = self._next_staged()
             except StopIteration:
                 self._done = True
                 raise
-            self.current_batch = self._stage_one(batch)
             return self.current_batch
+        profiler.count("feed.gets")
+        if self._queue.empty():
+            profiler.count("feed.empty_gets")
+        with profiler.span("feed.get_wait") as waited:
+            item = self._get()
+            waited.note(batch=getattr(item, "batch_no", None))
+        if item is _END:
+            self._done = True
+            raise StopIteration
+        if isinstance(item, _WorkerError):
+            # the worker stopped after the error; reset() restarts it
+            self._done = True
+            raise item.exc
+        self.current_batch = item
+        return item
+
+    def _get(self):
+        """The worker's next item of this generation (blocks)."""
         while True:
             try:
                 gen, item = self._queue.get(timeout=1.0)
@@ -220,18 +245,8 @@ class DevicePrefetchIter(DataIter):
                         "DevicePrefetchIter: worker thread died without "
                         "reporting a result")
                 continue
-            if gen != self._gen:
-                continue  # stale item from before a reset()
-            if item is _END:
-                self._done = True
-                raise StopIteration
-            if isinstance(item, _WorkerError):
-                # the worker stopped after the error; reset() restarts it
-                self._done = True
-                raise item.exc
-            self.current_batch = item
-            return item
-
+            if gen == self._gen:    # else stale, from before a reset()
+                return item
     # NOTE: no `__next__ = next` here — DataIter.__next__ dispatches to
     # self.next() dynamically, so subclass overrides stay reachable from
     # for-loops (the io.py DataIter contract)

@@ -273,14 +273,15 @@ class _PythonEngine(object):
 
     def _execute(self, op):
         import time
-        start = time.time() if self._profiling else 0
+        # the native engine's clock (steady_clock), and the spans'
+        start = time.perf_counter() if self._profiling else 0
         try:
             op.fn()
         except BaseException:
             with self._lock:
                 self._errors.append(traceback.format_exc())
         if self._profiling:
-            end = time.time()
+            end = time.perf_counter()
             with self._lock:
                 self._events.append((op.name or "op", int(start * 1e6),
                                      int(end * 1e6),

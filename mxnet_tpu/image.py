@@ -25,6 +25,7 @@ import numpy as np
 from . import io as mxio
 from . import ndarray as nd
 from . import recordio
+from .profiler import count as _count, span as _span
 from .base import (ENV_DATA_SERVERS, ENV_DATA_WORKERS, MXNetError,
                    get_env, register_env)
 
@@ -988,10 +989,12 @@ class _NativePipeline(_AsyncPipeline):
             return mxio.DataBatch(
                 [out], [lab_arr[:, 0] if self._lw == 1 else lab_arr],
                 pad=pad)
-        data = nd.array(out, dtype=out.dtype)
-        if self._device_transform is not None:
-            data = nd.NDArray._from_jax(self._device_transform(data._data))
-        labels = nd.array(lab_arr[:, 0] if self._lw == 1 else lab_arr)
+        with _span("decode.upload"):
+            data = nd.array(out, dtype=out.dtype)
+            if self._device_transform is not None:
+                data = nd.NDArray._from_jax(
+                    self._device_transform(data._data))
+            labels = nd.array(lab_arr[:, 0] if self._lw == 1 else lab_arr)
         return mxio.DataBatch([data], [labels], pad=pad)
 
     def _one_epoch(self):
@@ -1008,20 +1011,27 @@ class _NativePipeline(_AsyncPipeline):
         exhausted = False
         inflight = deque()   # ordered upload futures
 
+        def hand_on():
+            # blocks on the oldest upload, then on a full queue: the
+            # consumer is behind, the decoder ahead
+            with _span("decode.put_wait"):
+                self._put(inflight.popleft().result())
+
         def drain(block):
             while inflight and (block or inflight[0].done()):
-                self._put(inflight.popleft().result())
+                hand_on()
 
         while not exhausted and not self._stopping and not self._abandon:
             raws, labs = [], []
-            for _ in range(bs):
-                try:
-                    lab, raw = it.next_raw()
-                except StopIteration:
-                    exhausted = True
-                    break
-                raws.append(raw)
-                labs.append(lab)
+            with _span("decode.read"):
+                for _ in range(bs):
+                    try:
+                        lab, raw = it.next_raw()
+                    except StopIteration:
+                        exhausted = True
+                        break
+                    raws.append(raw)
+                    labs.append(lab)
             n = len(raws)
             if n == 0:
                 break
@@ -1036,9 +1046,14 @@ class _NativePipeline(_AsyncPipeline):
                 *[ct.cast(ct.c_char_p(r), ct.c_void_p) for r in raws])
             lens = (ct.c_uint64 * n)(*[len(r) for r in raws])
             valid[:] = 0
-            nv = self._lib.MXTPUImgPipeDecodeBatch(
-                self._pipe, bufs, lens, n, out.ctypes.data_as(ct.c_void_p),
-                valid.ctypes.data_as(u8p), cseed)
+            with _span("decode.batch") as decoded:
+                nv = self._lib.MXTPUImgPipeDecodeBatch(
+                    self._pipe, bufs, lens, n,
+                    out.ctypes.data_as(ct.c_void_p),
+                    valid.ctypes.data_as(u8p), cseed)
+                decoded.note(images=nv)
+            if nv < n:
+                _count("decode.failed", n - nv)
             if nv == 0:
                 # an entire batch of undecodable records is a dataset-level
                 # problem (e.g. non-JPEG payloads), not per-image noise —
@@ -1058,7 +1073,7 @@ class _NativePipeline(_AsyncPipeline):
                 self._uploader.submit(self._upload, out, lab_arr, bs - nv))
             drain(block=False)
             while len(inflight) > self.UPLOAD_THREADS + 2:  # backpressure
-                self._put(inflight.popleft().result())
+                hand_on()
         drain(block=True)
 
 

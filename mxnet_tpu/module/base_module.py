@@ -307,6 +307,8 @@ class BaseModule(object):
             # 10-15 of the first epoch (None when the env is unset)
             from .. import profiler as _profiler
             trace = _profiler.StepTraceCapture.from_env()
+            _span = _profiler.span
+            nstep = 0   # batches this fit() has taken: the spans' `step`
 
             ############################################################
             # training loop
@@ -335,26 +337,36 @@ class BaseModule(object):
                     with wd.armed("epoch %d batch %d"
                                   % (epoch, nbatch + 1)) \
                             if wd is not None else nullcontext():
-                        try:
-                            data_batch = next(data_stream)
-                        except StopIteration:
-                            break
+                        with _span("fit.next", step=nstep) as sp:
+                            try:
+                                data_batch = next(data_stream)
+                            except StopIteration:
+                                break
+                            # the feed's number of this batch, which its
+                            # worker's spans carry too
+                            sp.note(batch=getattr(data_batch, "batch_no",
+                                                  None))
                         nbatch += 1
                         if trace is not None:
                             trace.on_batch(nbatch)
                         if monitor is not None:
                             monitor.tic()
-                        self.forward_backward(data_batch)
-                        self.update()
-                        self.update_metric(eval_metric, data_batch.label)
+                        with _span("fit.step", step=nstep):
+                            self.forward_backward(data_batch)
+                            self.update()
+                        with _span("fit.metric", step=nstep):
+                            self.update_metric(eval_metric, data_batch.label)
                         if monitor is not None:
                             monitor.toc_print()
                         if batch_end_callback is not None:
-                            batch_end_params = BatchEndParam(
-                                epoch=epoch, nbatch=nbatch,
-                                eval_metric=eval_metric, locals=locals())
-                            for callback in _as_list(batch_end_callback):
-                                callback(batch_end_params)
+                            with _span("fit.callback", step=nstep):
+                                batch_end_params = BatchEndParam(
+                                    epoch=epoch, nbatch=nbatch,
+                                    eval_metric=eval_metric,
+                                    locals=locals())
+                                for callback in _as_list(batch_end_callback):
+                                    callback(batch_end_params)
+                        nstep += 1
                     # step boundary: consume a pending preemption —
                     # checkpoint mid-epoch and exit cleanly for the
                     # supervisor to relaunch with resume

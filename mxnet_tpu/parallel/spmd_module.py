@@ -14,6 +14,7 @@ from ..base import MXNetError
 from ..initializer import Uniform
 from ..module.base_module import BaseModule
 from ..ndarray import NDArray
+from ..profiler import span
 from .trainer import SPMDTrainer
 from .mesh import local_mesh
 
@@ -76,16 +77,20 @@ class SPMDModule(BaseModule):
         batch = self._data_shapes[0][1][0] if not hasattr(
             self._data_shapes[0], "shape") else self._data_shapes[0].shape[0]
         optimizer_params.setdefault("rescale_grad", 1.0 / batch)
-        self._trainer = SPMDTrainer(
-            self._symbol, optimizer, optimizer_params,
-            mesh=self._mesh if self._mesh is not None else None,
-            data_axis=self._data_axis,
-            param_shardings=self._param_shardings,
-            compute_dtype=self._compute_dtype,
-            grad_sync=self._grad_sync, plan=self._plan)
-        self._trainer.bind(self._data_shapes, self._label_shapes)
-        initializer, arg_params, aux_params = self._init_args
-        self._trainer.init_params(initializer, arg_params, aux_params)
+        # the module's bind and init_params only take notes (no span):
+        # the trainer is built, bound and given its weights here, under
+        # its own setup.bind / setup.build_step / setup.init_params
+        with span("setup.init_optimizer"):
+            self._trainer = SPMDTrainer(
+                self._symbol, optimizer, optimizer_params,
+                mesh=self._mesh if self._mesh is not None else None,
+                data_axis=self._data_axis,
+                param_shardings=self._param_shardings,
+                compute_dtype=self._compute_dtype,
+                grad_sync=self._grad_sync, plan=self._plan)
+            self._trainer.bind(self._data_shapes, self._label_shapes)
+            initializer, arg_params, aux_params = self._init_args
+            self._trainer.init_params(initializer, arg_params, aux_params)
         self.optimizer_initialized = True
 
     # fused: forward_backward does the whole step; update is a no-op

@@ -29,6 +29,7 @@ from ..base import MXNetError, register_env
 from ..executor import _build_eval
 from ..ndarray import NDArray
 from ..io import DataDesc
+from ..profiler import span
 
 __all__ = ["SPMDTrainer", "SUPPORTED_OPTIMIZERS",
            "DEFAULT_GUARD_FLUSH_INTERVAL"]
@@ -264,6 +265,10 @@ class SPMDTrainer(object):
 
     # -- setup ------------------------------------------------------------
     def bind(self, data_shapes, label_shapes=None):
+        with span("setup.bind"):
+            return self._bind(data_shapes, label_shapes)
+
+    def _bind(self, data_shapes, label_shapes):
         data_shapes = [d if isinstance(d, DataDesc) else DataDesc(d[0], d[1])
                        for d in data_shapes]
         label_shapes = [l if isinstance(l, DataDesc) else DataDesc(l[0], l[1])
@@ -400,6 +405,10 @@ class SPMDTrainer(object):
         return manual
 
     def init_params(self, initializer, arg_params=None, aux_params=None):
+        with span("setup.init_params"):
+            self._init_params(initializer, arg_params, aux_params)
+
+    def _init_params(self, initializer, arg_params, aux_params):
         from ..ndarray import zeros as nd_zeros
         params, aux = {}, {}
         for name in self.param_names:
@@ -561,6 +570,10 @@ class SPMDTrainer(object):
         return traced
 
     def _build_step(self):
+        with span("setup.build_step"):
+            self._build_step_fns()
+
+    def _build_step_fns(self):
         eval_fn = self._placed_eval()
         compute_dtype = self.compute_dtype
         transforms = dict(self.input_transforms)
@@ -982,7 +995,6 @@ class SPMDTrainer(object):
             return self._step_impl(batch_arrays, key)
 
     def _step_impl(self, batch_arrays, key):
-        from .. import random as _random
         # consume the PREVIOUS steps' guard counters before dispatching
         # this one: a one-deep pipeline by default (the device runs step N
         # while the host preps N+1); with flush_interval > 1 (deferred
@@ -1018,6 +1030,25 @@ class SPMDTrainer(object):
                         "axis (%d) — pad the final batch (iterator "
                         "default) or use grad_sync='zero'"
                         % (n, need, dp))
+        with span("step.prepare"):
+            args = self._step_args(batch_arrays, key)
+        with span("step.dispatch"):
+            self.params, self.aux, self.opt_state, extras, outs = \
+                self._step_fn(*args)
+        if self.step_guard:
+            self._guard_acc = extras["guard"]
+            self._guard_pending = True
+        if self._metric_fn is not None:
+            self._metric_acc = extras["metric"]
+        with span("step.localize"):
+            outs = self._localize(outs)
+        self._outputs = outs
+        return outs
+
+    def _step_args(self, batch_arrays, key):
+        """The arguments of one call of the compiled step: the batch on
+        the mesh, the key, the schedule's scalars, the accumulators."""
+        from .. import random as _random
         data = self._resolve_batch(batch_arrays)
         self._num_update += 1
         lr = self.optimizer.lr if self.optimizer.lr_scheduler is None else \
@@ -1051,16 +1082,7 @@ class SPMDTrainer(object):
             if sig not in self._analyzed_keys:
                 self._analyzed_keys.add(sig)
                 self._maybe_env_analyze(args)
-        self.params, self.aux, self.opt_state, extras, outs = \
-            self._step_fn(*args)
-        if self.step_guard:
-            self._guard_acc = extras["guard"]
-            self._guard_pending = True
-        if self._metric_fn is not None:
-            self._metric_acc = extras["metric"]
-        outs = self._localize(outs)
-        self._outputs = outs
-        return outs
+        return args
 
     def _poison_batch(self, batch_arrays):
         """Fault-injection hook: NaN out the first floating input so the
@@ -1109,7 +1131,8 @@ class SPMDTrainer(object):
             return
         self._guard_pending = False
         # ONE device->host fetch for all three counters (stacked i32[3])
-        acc = np.asarray(self._read_scalar(self._guard_acc))
+        with span("step.guard_wait"):
+            acc = np.asarray(self._read_scalar(self._guard_acc))
         total = int(acc[0]) + self._skip_base
         consec = int(acc[1])
         trips = int(acc[2])
@@ -1183,8 +1206,9 @@ class SPMDTrainer(object):
         re-zeroed — bounded windows keep f32 exact for integer sums."""
         if self._metric_acc is None:
             return 0.0, 0.0
-        s = float(self._read_scalar(self._metric_acc[0]))
-        c = float(self._read_scalar(self._metric_acc[1]))
+        with span("step.metric_wait"):
+            s = float(self._read_scalar(self._metric_acc[0]))
+            c = float(self._read_scalar(self._metric_acc[1]))
         self._metric_acc = None  # fresh zeros at the next step
         return s, c
 

@@ -1,26 +1,54 @@
 """Profiler control (reference python/mxnet/profiler.py over MXSetProfilerConfig/
 MXSetProfilerState/MXDumpProfile, src/engine/profiler.{h,cc}).
 
-Two collectors feed one Chrome ``traceEvents`` dump, matching the reference's
-format (profiler.cc:134-216):
+Three collectors, one Chrome ``traceEvents`` dump in the reference's format
+(profiler.cc:134-216):
 - the host dependency engine's per-op timings (data pipeline, engine ops) via
   the native profiler (mxnet_tpu/native/engine.cc);
+- the program's own spans and counters (:func:`span`, :func:`count`,
+  :func:`event`): where the host's time goes in the training path — ``fit``,
+  the fused step, the feed, the decoder, compilation, set-up;
 - XLA device traces via ``jax.profiler`` when a trace_dir is configured
-  (mode='all_xla') — viewable in TensorBoard/Perfetto, the TPU analog of the
-  reference's per-kernel GPU stats.
+  (mode='all_xla', or ``MXTPU_PROFILE_DIR`` under ``fit``) — viewable in
+  TensorBoard/Perfetto, the TPU analog of the reference's per-kernel GPU
+  stats.  :func:`idle_gaps` lays the spans over such a trace and says what
+  the host was doing while the device sat idle.
+
+The span recorder is always on and bounded: the last ``RING`` spans stay in
+memory, so a live job can be asked for its last minute.  A step of ``fit``
+over a ``DevicePrefetchIter`` over the native image pipeline makes 17
+records: 9 spans on the loop's thread (``fit.next``, ``feed.get_wait``,
+``fit.step``, ``step.prepare``, ``step.dispatch``, ``step.localize``,
+``fit.metric``, one ``step.guard_wait``, ``fit.callback``), 3 on the feed's
+worker, 4 on the decoder's threads, and one ``compile.trace`` event (the
+image iterator re-traces its device transform's shape once a batch);
+in-memory batches make 12.  ``RING`` holds over 3,800 such steps: about
+eight minutes of ResNet-50 at 121 ms a step.  Every stamp
+is ``time.perf_counter_ns()``; :func:`spans` adds the Unix time of each
+start, from an anchor pair of both clocks taken at the read, and a
+``jax.profiler`` trace's events count from its ``profile_start_time`` (Unix
+ns, plane ``Task Environment``), so the two share a timeline.
 
 Env parity: MXNET_PROFILER_AUTOSTART=1 starts profiling at import
 (docs/how_to/env_var.md:66-73).
 """
 from __future__ import annotations
 
+import collections
+import itertools
+import json
 import os
+import threading
+import time
+
+import jax
 
 from .base import MXNetError, get_env, register_env
 
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
            "dumps", "get_op_stats", "State", "Mode", "StepTraceCapture",
-           "ENV_PROFILE_DIR"]
+           "ENV_PROFILE_DIR", "span", "count", "event", "spans", "counters",
+           "idle_gaps", "RING"]
 
 #: when set, fit() captures a jax.profiler trace of steps 10-15 of the
 #: first epoch into this directory (viewable in TensorBoard/Perfetto)
@@ -33,6 +61,156 @@ ENV_PROFILER_AUTOSTART = register_env(
     doc="1 starts the host profiler at import (reference parity)")
 
 
+# -- the program's spans and counters ---------------------------------------
+
+#: how many finished spans stay in memory (the oldest fall out)
+RING = 65536
+
+_ring = collections.deque(maxlen=RING)
+_serial = itertools.count()
+_counters = collections.Counter()
+_counters_lock = threading.Lock()
+_local = threading.local()
+_now = time.perf_counter_ns
+
+
+def _thread():
+    """(open spans of this thread, innermost last; ident; name)."""
+    try:
+        return _local.state
+    except AttributeError:
+        t = threading.current_thread()
+        _local.state = ([], t.ident, t.name)
+        return _local.state
+
+
+class span(object):
+    """``with span("feed.stage", batch=7):`` — times the block and, at its
+    end, appends itself to the ring: name, start, end (perf-counter ns),
+    thread, the span of this thread that was open around it, and ``ids``.
+    A span inherits its parent's ids (a step's spans all carry ``step``);
+    :meth:`note` adds what is only known inside the block."""
+
+    __slots__ = ("name", "ids", "serial", "parent", "thread", "start", "end")
+
+    def __init__(self, name, **ids):
+        self.name = name
+        self.ids = ids
+
+    def _adopt(self):
+        """Take a serial, this thread and, from the span open on it, the
+        parent's serial and ids; returns the thread's open spans."""
+        stack, ident, tname = _thread()
+        self.serial = next(_serial)
+        self.thread = (ident, tname)
+        self.parent = None
+        if stack:
+            parent = stack[-1]
+            self.parent = parent.serial
+            if parent.ids:
+                self.ids = dict(parent.ids, **self.ids) if self.ids \
+                    else parent.ids
+        return stack
+
+    def __enter__(self):
+        self._adopt().append(self)
+        self.start = _now()
+        return self
+
+    def __exit__(self, *exc):
+        self.end = _now()
+        _local.state[0].pop()
+        _ring.append(self)
+        return False
+
+    def note(self, **ids):
+        self.ids = dict(self.ids, **ids)
+
+
+def event(name, start, end, **ids):
+    """Append a finished span whose times (perf-counter ns) someone else
+    measured; its parent is the span open on the calling thread."""
+    s = span(name, **ids)
+    s._adopt()
+    s.start, s.end = int(start), int(end)
+    _ring.append(s)
+
+
+def count(name, n=1):
+    """Add ``n`` to the cumulative counter ``name``."""
+    with _counters_lock:
+        _counters[name] += n
+
+
+def counters():
+    """{name: value} of every counter, cumulative since import."""
+    with _counters_lock:
+        return dict(_counters)
+
+
+def spans(since=None, until=None):
+    """The ring's spans that start in [since, until] (perf-counter
+    seconds, what ``time.perf_counter()`` gives; None is open), sorted by
+    start.  Each is a dict: ``name``, ``start`` and ``end`` (perf-counter
+    seconds), ``unix_ns`` (its start in Unix nanoseconds), ``thread``
+    (ident), ``thread_name``, ``serial``, ``parent`` (the enclosing span's
+    serial, or None) and ``ids``."""
+    anchor = time.time_ns() - _now()
+    lo = -float("inf") if since is None else since * 1e9
+    hi = float("inf") if until is None else until * 1e9
+    # list(deque) is one C call: no append of another thread lands inside
+    held = sorted((s for s in list(_ring) if lo <= s.start <= hi),
+                  key=lambda s: s.start)
+    return [{"name": s.name, "start": s.start / 1e9, "end": s.end / 1e9,
+             "unix_ns": s.start + anchor, "thread": s.thread[0],
+             "thread_name": s.thread[1], "serial": s.serial,
+             "parent": s.parent, "ids": dict(s.ids)} for s in held]
+
+
+def _chrome_events(records, clock):
+    """Chrome ``X`` events of span records, one ``tid`` per thread;
+    ``clock`` gives a record's start in microseconds."""
+    out = []
+    for tid, name in sorted({(r["thread"], r["thread_name"])
+                             for r in records}):
+        out.append({"name": "thread_name", "ph": "M", "pid": 0, "tid": tid,
+                    "args": {"name": name}})
+    for r in records:
+        out.append({"name": r["name"], "cat": "span", "ph": "X",
+                    "ts": clock(r), "dur": (r["end"] - r["start"]) * 1e6,
+                    "pid": 0, "tid": r["thread"], "args": r["ids"]})
+    return out
+
+
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
+
+
+def _on_duration(name, secs, **_):
+    if name in _COMPILE_EVENTS:
+        end = _now()
+        event(_COMPILE_EVENTS[name], end - int(secs * 1e9), end)
+
+
+def _on_event(name, **_):
+    if name in _CACHE_EVENTS:
+        count(_CACHE_EVENTS[name])
+
+
+# JAX reports each trace, lowering and backend compile (a cache hit's load
+# included) as it ends, on the thread that asked for it: inside the span
+# that caused it (a first ``step.dispatch``, a ``setup.*``)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
+
+
 class StepTraceCapture(object):
     """Window-bounded ``jax.profiler`` trace for a training loop.
 
@@ -42,12 +220,16 @@ class StepTraceCapture(object):
     end (closing a window the epoch cut short).  A steady-state window —
     not step 0 — so the trace shows the pipeline, not compilation."""
 
+    #: the spans of the traced window, written beside the trace by stop()
+    SPANS_FILE = "mxnet_tpu_spans.trace.json"
+
     def __init__(self, directory, start_step=10, stop_step=15):
         self.directory = os.fspath(directory)
         self.start_step = int(start_step)
         self.stop_step = int(stop_step)
         self._active = False
         self._done = False
+        self._since = None
 
     @classmethod
     def from_env(cls):
@@ -59,9 +241,18 @@ class StepTraceCapture(object):
         if self._done:
             return
         if not self._active and nbatch >= self.start_step:
-            import jax
             os.makedirs(self.directory, exist_ok=True)
-            jax.profiler.start_trace(self.directory)
+            # the device's planes only.  Host TraceMe events make the
+            # transfer threads write ~0.9 M events for one batch and stall
+            # the feed they record (PERF.md, PR 23), and the Python tracer
+            # slows the loop it watches; what the host did is in the
+            # program's own spans, written beside the trace on stop()
+            options = jax.profiler.ProfileOptions()
+            options.host_tracer_level = 0
+            options.python_tracer_level = 0
+            self._since = time.perf_counter()
+            jax.profiler.start_trace(self.directory,
+                                     profiler_options=options)
             self._active = True
         elif self._active and nbatch > self.stop_step:
             self.stop()
@@ -69,10 +260,15 @@ class StepTraceCapture(object):
     def stop(self):
         if not self._active:
             return
-        import jax
         jax.profiler.stop_trace()
         self._active = False
         self._done = True
+        # Unix microseconds: the trace's own events count from its
+        # profile_start_time, which is Unix time too
+        events = _chrome_events(spans(since=self._since),
+                                lambda r: r["unix_ns"] / 1e3)
+        with open(os.path.join(self.directory, self.SPANS_FILE), "w") as f:
+            json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
         import logging
         logging.getLogger(__name__).info(
             "StepTraceCapture: wrote steps %d-%d trace to %s",
@@ -115,7 +311,6 @@ def profiler_set_state(state="stop"):
     running = state == State.RUN
     engine.get().set_profiler_state(running)
     if _config["mode"] == Mode.ALL_XLA:
-        import jax
         trace_dir = _config["trace_dir"] or \
             os.path.splitext(_config["filename"])[0] + "_xla"
         if running and not _xla_tracing[0]:
@@ -128,29 +323,133 @@ def profiler_set_state(state="stop"):
 
 
 def dump_profile(finished=True):
-    """Write the collected host-engine trace as Chrome traceEvents JSON to
-    the configured filename (reference profiler.py:dump_profile /
-    MXDumpProfile)."""
+    """Write the host engine's operations and the program's spans as
+    Chrome traceEvents JSON to the configured filename (reference
+    profiler.py:dump_profile / MXDumpProfile).  Both are stamped in
+    microseconds of the monotonic clock; a span's ``tid`` is its thread."""
     from . import engine
-    data = engine.get().dump_profile()
+    data = json.loads(engine.get().dump_profile())
+    data["traceEvents"] = list(data.get("traceEvents") or []) + \
+        _chrome_events(spans(), lambda r: r["start"] * 1e6)
     with open(_config["filename"], "w") as f:
-        f.write(data)
+        json.dump(data, f)
     return _config["filename"]
 
 
-def _latest_device_trace(trace_dir=None):
-    """Newest <trace_dir>/plugins/profile/*/*.trace.json.gz written by
-    jax.profiler (already Chrome traceEvents format)."""
+def _newest_profile_file(trace_dir, suffix):
+    """Newest <trace_dir>/plugins/profile/*/*<suffix> that jax.profiler
+    wrote."""
     import glob
-    trace_dir = trace_dir or _config["trace_dir"] or \
-        os.path.splitext(_config["filename"])[0] + "_xla"
-    cands = glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*", "*.trace.json.gz"))
+    cands = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*" + suffix))
     if not cands:
-        raise MXNetError(
-            "no XLA device trace under %r — profile with "
-            "mode='all_xla' first" % (trace_dir,))
+        raise MXNetError("no *%s of a jax.profiler trace under %r"
+                         % (suffix, trace_dir))
     return max(cands, key=os.path.getmtime)
+
+
+def _latest_device_trace(trace_dir=None):
+    """Newest ``.trace.json.gz`` (already Chrome traceEvents format) under
+    the configured trace directory: profile with mode='all_xla' first."""
+    return _newest_profile_file(
+        trace_dir or _config["trace_dir"]
+        or os.path.splitext(_config["filename"])[0] + "_xla",
+        ".trace.json.gz")
+
+
+def _device_lines(trace_dir):
+    """(profile_start_time in Unix ns, {device plane: {line name:
+    [(start_ns, duration_ns, name)]}}) of the newest ``.xplane.pb`` under
+    ``trace_dir``; event starts count from profile_start_time."""
+    data = jax.profiler.ProfileData.from_file(
+        _newest_profile_file(trace_dir, ".xplane.pb"))
+    start, devices = None, {}
+    for plane in data.planes:
+        if plane.name == "Task Environment":
+            start = dict(plane.stats).get("profile_start_time")
+        elif plane.name.startswith("/device:TPU:"):
+            devices[plane.name] = {
+                line.name: [(e.start_ns, e.duration_ns, e.name)
+                            for e in line.events] for line in plane.lines}
+    return start, devices
+
+
+def _attribute_gaps(start_ns, devices, records, top=10):
+    """:func:`idle_gaps` on plain data: ``devices`` as
+    :func:`_device_lines` gives them, ``records`` as :func:`spans`."""
+    if start_ns is None:
+        raise MXNetError("the trace has no profile_start_time: its events "
+                         "cannot be put on the spans' clock")
+    busy = {}
+    for name, lines in devices.items():
+        merged = []
+        for s, d, _ in sorted(lines.get("XLA Ops", ())):
+            if merged and s <= merged[-1][1]:
+                merged[-1][1] = max(merged[-1][1], s + d)
+            else:
+                merged.append([s, s + d])
+        if merged:
+            busy[name] = merged
+    if not busy:
+        raise MXNetError("the trace holds no device plane with XLA Ops: %r"
+                         % sorted(devices))
+    device, merged = max(busy.items(),
+                         key=lambda kv: sum(e - s for s, e in kv[1]))
+    # whole nanoseconds: a float holds Unix nanoseconds to 256 of them
+    gaps = [(start_ns + round(a[1]), start_ns + round(b[0]))
+            for a, b in zip(merged, merged[1:])]
+    # the thread that hands the device its work says why the device waits
+    feeders = {r["thread"] for r in records if r["name"] == "step.dispatch"}
+    mine = [(r["unix_ns"], r["unix_ns"] + round((r["end"] - r["start"]) * 1e9),
+             r["name"]) for r in records
+            if not feeders or r["thread"] in feeders]
+    by_span, rows = collections.Counter(), []
+    for g0, g1 in gaps:
+        over = [(max(s, g0), min(e, g1), s, n) for s, e, n in mine
+                if s < g1 and e > g0]
+        cuts = sorted({g0, g1} | {t for o in over for t in o[:2]})
+        row = collections.Counter()
+        for a, b in zip(cuts, cuts[1:]):
+            # spans of one thread nest: the one that started last is innermost
+            inner = max((o for o in over if o[0] <= a and o[1] >= b),
+                        key=lambda o: o[2], default=None)
+            row[inner[3] if inner else "unattributed"] += (b - a) / 1e9
+        by_span.update(row)
+        rows.append({"start_unix_ns": g0, "seconds": (g1 - g0) / 1e9,
+                     "by_span": dict(row.most_common())})
+    rows.sort(key=lambda r: -r["seconds"])
+    return {"device": device,
+            "window_s": (merged[-1][1] - merged[0][0]) / 1e9,
+            "idle_s": sum(r["seconds"] for r in rows),
+            "by_span": dict(by_span.most_common()), "gaps": rows[:top]}
+
+
+def idle_gaps(trace_dir, top=10):
+    """Why the device sat idle: the gaps between the ``XLA Ops`` of the
+    busiest device in the newest ``jax.profiler`` trace under
+    ``trace_dir``, each put down to the program's spans that overlap it.
+
+    Returns ``{"device", "window_s" (first operation to last), "idle_s",
+    "by_span": {span name: idle seconds under it, over all gaps}, "gaps":
+    the ``top`` longest, each {"start_unix_ns", "seconds", "by_span"}}``.
+    A gap's time goes to the innermost span open over it on the thread
+    that dispatches the steps (every thread where nothing dispatched), and
+    to ``unattributed`` where none was.  The spans are those
+    ``StepTraceCapture`` wrote beside the trace, else this process's ring.
+    Raises if the trace has no ``profile_start_time``: without it the two
+    clocks cannot be laid over each other."""
+    start_ns, devices = _device_lines(trace_dir)
+    path = os.path.join(trace_dir, StepTraceCapture.SPANS_FILE)
+    if os.path.exists(path):
+        with open(path) as f:
+            records = [{"name": e["name"], "thread": e["tid"],
+                        "unix_ns": int(e["ts"] * 1e3), "start": 0.0,
+                        "end": e["dur"] / 1e6}
+                       for e in json.load(f)["traceEvents"]
+                       if e["ph"] == "X"]
+    else:
+        records = spans()
+    return _attribute_gaps(start_ns, devices, records, top)
 
 
 def _scope_of(event):

@@ -10,6 +10,7 @@
 - the compile cache is placed from outside by ``JAX_COMPILATION_CACHE_DIR``
   and is one fixed in-checkout directory otherwise.
 """
+import json
 import os
 import re
 import subprocess
@@ -64,9 +65,13 @@ def test_train_serve_trace_phases_at_toy_size(toy):
     assert len(res["shard_devices"]) == 1
     # a program lowered for the CPU holds no Mosaic kernel
     assert res["pallas_kernels"] == {}
-    assert chip_smoke.check_trace(trace, "/host:CPU")
+    # the capture records the device's planes only (none on the CPU) and
+    # writes the program's spans of its window beside the trace
     with pytest.raises(AssertionError, match="no /device:TPU"):
         chip_smoke.check_trace(trace, "/device:TPU")
+    with open(os.path.join(trace, "mxnet_tpu_spans.trace.json")) as f:
+        spans = json.load(f)["traceEvents"]
+    assert sum(e["name"] == "step.dispatch" for e in spans) == 3
 
     serve = chip_smoke.phase_serve(
         ckpt, chip_smoke.sample_inputs(rec, 4, 32), buckets=(1, 4))
@@ -85,6 +90,20 @@ def test_multichip_phase_on_the_cpu_mesh(toy):
     assert set(out) == {"allreduce", "zero3"}
     assert out["zero3"]["grad_sync"] == "zero3"
     assert len(set(out["allreduce"]["shard_devices"])) == 4
+
+
+def test_phases_read_their_compile_seconds_from_the_programs_recorder():
+    """``since(mark())``: the compile.* events and the compile.cache_*
+    counters of ``mxnet_tpu.profiler`` (the smoke keeps no meter)."""
+    import jax.numpy as jnp
+    snap = chip_smoke.mark()
+    jax.jit(lambda x: sum(jnp.tanh(x * k) for k in range(40)))(
+        jnp.ones(7)).block_until_ready()
+    took = chip_smoke.since(snap)
+    assert set(took) == {"wall_s", "compile_s", "run_s", "cache_hits",
+                         "cache_misses"}
+    assert 0 < took["compile_s"] <= took["wall_s"] + 0.01
+    assert took["cache_hits"] == 0
 
 
 def test_calibration_fails_on_a_rate_above_the_peak():
