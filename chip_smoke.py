@@ -261,8 +261,11 @@ def phase_train(symbol, rec_prefix, image, batch, epochs, ckpt_dir=None,
 
     def on_batch(param):
         # the metric accumulates over the epoch; the step's own loss is
-        # the difference to what it held a step ago
+        # the difference to what it held a step ago.  get() first: fit
+        # settles a step's metric one step behind the device unless the
+        # metric is read, and the fields alone are no read
         m = param.eval_metric
+        m.get()
         if param.nbatch == 0:
             seen[0], seen[1] = 0.0, 0
         losses.append(float(m.sum_metric - seen[0])

@@ -69,10 +69,13 @@ class EvalMetric(object):
         return None
 
     def attach_deferred_source(self, fetch, reset):
-        """Fold device-side accumulators into this metric lazily:
+        """Fold a source that lags into this metric lazily:
         ``fetch() -> (sum_delta, count_delta)`` is drained on every
-        ``get``/explicit fold; ``reset()`` zeroes the device side when the
-        metric resets."""
+        ``get``/explicit fold; ``reset()`` zeroes the source when the
+        metric resets.  Two sources use it: a trainer's in-graph
+        accumulators, and ``fit``'s one owed step of a host-side metric
+        (``BaseModule._lag_step_metric``; its fetch calls ``update``
+        itself and returns nothing to add)."""
         self._deferred_fetch = fetch
         self._deferred_reset = reset
 

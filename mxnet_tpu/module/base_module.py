@@ -174,7 +174,9 @@ class BaseModule(object):
         ``dataflow.DevicePrefetchIter`` after ``init_optimizer``) to
         overlap the host->device transfer with the running step; on fused
         modules the train metric is accumulated in-graph (deferred — see
-        MXTPU_METRIC_INTERVAL / MXTPU_METRIC_BLOCKING) and
+        MXTPU_METRIC_INTERVAL / MXTPU_METRIC_BLOCKING) or, where it stays
+        on the host, updated for step N once step N+1 is dispatched
+        (reads through ``get()`` stay exact; its fields alone lag), and
         MXTPU_PROFILE_DIR captures a ``jax.profiler`` trace of steps
         10-15 of the first epoch.  See docs/how_to/performance.md."""
         assert num_epoch is not None, "please specify number of epochs"
@@ -278,6 +280,10 @@ class BaseModule(object):
         fused_trainer = self._deferred_metric_trainer()
         trace = None
         try:
+            # a metric that stays on the host is settled one step behind
+            # the device, which then never waits for it
+            # (_update_step_metric)
+            self._lag_step_metric(eval_metric, True)
             if preemption_safe:
                 # flag set by SIGTERM/SIGINT, consumed at the step
                 # boundaries below.  Multi-process runs AGREE on the flag
@@ -393,6 +399,7 @@ class BaseModule(object):
                     trace = None  # first epoch only
 
                 # one epoch of training is finished
+                self._settle_metric()
                 for name, val in eval_metric.get_name_value():
                     self.logger.info("Epoch[%d] Train-%s=%f", epoch, name,
                                      val)
@@ -446,6 +453,7 @@ class BaseModule(object):
             from ..resilience import wait_checkpoints
             wait_checkpoints()
         finally:
+            self._lag_step_metric(eval_metric, False)
             if trace is not None:
                 trace.stop()
             if preempt is not None:
@@ -583,6 +591,76 @@ class BaseModule(object):
                 self._deferred_calls % self._deferred_interval == 0:
             eval_metric.fold_deferred()
         return True
+
+    # -- a fused train step's host-side metric, one step behind -----------
+    _metric_lags = False    # True inside fit()'s loop on a fused module
+    _owed_metric = None     # (eval_metric, labels, outputs, guard copy)
+
+    def _update_step_metric(self, eval_metric, labels):
+        """``eval_metric.update`` from the outputs of the fused train step
+        just dispatched, unless the guard skipped it (its outputs are
+        non-finite: one NaN into a summing metric would poison the whole
+        epoch's Train-* rows).
+
+        Called by hand this waits for the step: the metric is current
+        when the call returns.  Inside ``fit`` the step's labels, outputs
+        and guard counters are only noted, and settled once the NEXT step
+        is in the device's queue (or when something reads or resets the
+        metric), so neither the guard's answer nor an ``asnumpy`` in
+        ``update`` makes the device wait for the host.  The iterator's
+        label arrays must stay as they are until then, which a batch
+        staged ahead by ``DevicePrefetchIter`` needs of them anyway.
+        Step N's record goes before step N+2 is dispatched, so two
+        steps' outputs are alive at a dispatch, as when the metric was
+        updated at once; an ``update`` that launches device work on the
+        outputs (and fetches nothing) keeps them until that work has
+        run, behind step N+1: a third step's outputs are then alive."""
+        outputs = self.get_outputs()
+        trainer = self._deferred_metric_trainer()
+        if self._metric_lags:
+            self._settle_metric()
+            self._owed_metric = (eval_metric, labels, outputs,
+                                 trainer.guard_snapshot())
+            return
+        trainer.flush_step_guard()
+        if not trainer.last_step_skipped:
+            eval_metric.update(labels, outputs)
+
+    def _settle_metric(self):
+        """Pay the metric the step that is owed to it; returns the
+        nothing a metric's deferred source has left to add."""
+        owed, self._owed_metric = self._owed_metric, None
+        if owed is not None:
+            eval_metric, labels, outputs, snap = owed
+            if not self._deferred_metric_trainer().step_skipped(snap):
+                eval_metric.update(labels, outputs)
+        return 0.0, 0.0
+
+    def _drop_owed_metric(self):
+        self._owed_metric = None
+
+    def _lag_step_metric(self, eval_metric, on):
+        """fit()'s switch, on for its loop and off in its ``finally``.
+        While on, every read of ``eval_metric`` (``get``,
+        ``get_name_value``, a child of a composite) settles the owed step
+        first and ``reset`` drops it, through the hook a metric has for a
+        source that lags (``attach_deferred_source``): a callback sees
+        what it saw when the metric was updated at once.  Off, nothing is
+        owed any more: no step's outputs outlive ``fit`` here."""
+        self._owed_metric = None
+        if self._deferred_metric_trainer() is None:
+            return   # the executor path: nothing is fused, nothing lags
+        self._metric_lags = on
+        if getattr(self, "_deferred_metric", None) is eval_metric:
+            return   # accumulated in-graph: the hook is the trainer's
+        nodes = [eval_metric]
+        for m in nodes:
+            nodes.extend(getattr(m, "metrics", ()))
+            if on:
+                m.attach_deferred_source(self._settle_metric,
+                                         self._drop_owed_metric)
+            else:
+                m.detach_deferred_source()
 
     def get_optimizer_states(self):
         """Serialized optimizer state (bytes), for managed checkpointing.

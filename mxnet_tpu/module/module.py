@@ -648,23 +648,14 @@ class Module(BaseModule):
         return self._fused  # None on the executor path
 
     def update_metric(self, eval_metric, labels):
-        if self._fused is not None:
-            if self._fused_outputs_from_update and \
-                    self._deferred_metric_update(eval_metric):
-                # the step itself accumulated (sum, count) in-graph —
-                # nothing to fetch per step
-                return
-            if self._fused_outputs_from_update and self._fused.step_guard:
-                # a guard-skipped step's outputs are non-finite by
-                # definition; one NaN into a summing metric would poison
-                # the whole epoch's Train-* rows (the flush costs nothing
-                # extra: reading the outputs below syncs the same program)
-                self._fused.flush_step_guard()
-                if self._fused.last_step_skipped:
-                    return
+        if self._fused is None:
+            self._exec_group.update_metric(eval_metric, labels)
+        elif not self._fused_outputs_from_update:
             eval_metric.update(list(labels or []), self.get_outputs())
-            return
-        self._exec_group.update_metric(eval_metric, labels)
+        elif not self._deferred_metric_update(eval_metric):
+            # (else: the step itself accumulated (sum, count) in-graph —
+            # nothing to fetch per step)
+            self._update_step_metric(eval_metric, list(labels or []))
 
     def _sync_params_from_devices(self):
         if self._fused is not None:

@@ -138,19 +138,12 @@ class SPMDModule(BaseModule):
         return self._trainer  # None before init_optimizer
 
     def update_metric(self, eval_metric, labels):
-        if getattr(self, "_eval_outputs", None) is None and \
-                self._deferred_metric_update(eval_metric):
-            # train-step path with in-graph accumulation: the step already
-            # counted this batch (guard-skipped steps excluded in-graph)
-            return
-        if getattr(self, "_eval_outputs", None) is None and \
-                self._trainer.step_guard:
-            # train-step outputs: a guard-skipped step's outputs are
-            # non-finite — keep them out of summing metrics
-            self._trainer.flush_step_guard()
-            if self._trainer.last_step_skipped:
-                return
-        eval_metric.update(labels, self.get_outputs())
+        if getattr(self, "_eval_outputs", None) is not None:
+            eval_metric.update(labels, self.get_outputs())
+        elif not self._deferred_metric_update(eval_metric):
+            # (else: the train step accumulated this batch in-graph,
+            # guard-skipped steps excluded there)
+            self._update_step_metric(eval_metric, labels)
 
     def get_params(self):
         return self._trainer.get_params()

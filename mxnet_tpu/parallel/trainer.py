@@ -29,7 +29,7 @@ from ..base import MXNetError, register_env
 from ..executor import _build_eval
 from ..ndarray import NDArray
 from ..io import DataDesc
-from ..profiler import span
+from ..profiler import count, span
 
 __all__ = ["SPMDTrainer", "SUPPORTED_OPTIMIZERS",
            "DEFAULT_GUARD_FLUSH_INTERVAL"]
@@ -210,17 +210,20 @@ class SPMDTrainer(object):
         # gradients; a non-finite step applies NO update (params, aux and
         # optimizer state pass through unchanged inside the same fused
         # program).  Skip accounting is ALSO in-graph: the step carries a
-        # donated (total_skips, consecutive_bad) i32 pair, so the host
-        # never needs a per-step device sync to know how many updates were
-        # dropped.  The counters are read ONE STEP LATE by default (at the
-        # next step()'s entry, or at flush_step_guard/get_params/counter
-        # reads) — a one-deep pipeline — and when deferred metrics raise
-        # ``flush_interval`` above 1, only every that-many steps (at most
-        # ``flush_interval`` steps of staleness; counter-property reads
-        # always flush and are exact).  After ``max_consecutive_bad_steps``
-        # bad steps in a row the flush aborts with MXNetError — persistent
-        # NaNs mean a diverged model, and silently skipping forever would
-        # burn a pod doing nothing.
+        # donated (total_skips, consecutive_bad, trips) i32[3] and returns
+        # a second, non-donated copy of it (``_guard_snap``) that stays
+        # readable after the next step has consumed the carry.  The host
+        # reads step N's copy only AFTER it has dispatched step N+1, so it
+        # blocks on N while N+1 is already in the device's queue: the
+        # device goes from step to step and the host's own work per step
+        # costs it nothing.  When deferred metrics raise
+        # ``flush_interval`` above 1 the same read happens only every
+        # that-many steps.  Counter-property reads, get_params/get_states
+        # and flush_step_guard() read the NEWEST copy (they wait for the
+        # device to drain) and are exact.  After
+        # ``max_consecutive_bad_steps`` bad steps in a row the read aborts
+        # with MXNetError — persistent NaNs mean a diverged model, and
+        # silently skipping forever would burn a pod doing nothing.
         from ..resilience import ENV_STEP_GUARD, ENV_MAX_BAD_STEPS
         if step_guard is None:
             step_guard = str(get_env(ENV_STEP_GUARD, "1")) != "0"
@@ -233,9 +236,12 @@ class SPMDTrainer(object):
         self._consecutive_bad_steps = 0   # current bad-step run length
         self._skip_base = 0               # host total when counters placed
         self._guard_acc = None            # device (total, consec, trips) i32
-        self._guard_pending = False       # unread counters in flight
+        self._guard_snap = None           # the last step's readable copy
+        self._guard_pending = False       # _guard_snap not yet folded
+        self._guard_read = None           # (copy, host value) read last
+        self._in_flight = False           # a step dispatched, not waited for
         self._trips_seen = 0              # abort events already raised
-        self.last_step_skipped = False    # most recently FLUSHED step
+        self.last_step_skipped = False    # most recently FOLDED step
         # deferred in-graph metrics: optional (sum, count) f32 accumulators
         # carried through the donated step (install_metric); fetch_metric
         # reads them and re-zeroes, so each accumulation window spans at
@@ -243,9 +249,10 @@ class SPMDTrainer(object):
         self._metric_fn = None
         self._metric_key = None
         self._metric_acc = None
-        # host<->device sync cadence for the guard counters: 1 = flush at
-        # every step entry (classic one-deep pipeline); >1 = flush every
-        # N steps (set by install_metric for deferred-metric runs)
+        # how often step() folds the guard counters into host state: 1 =
+        # every step (the previous step's, once this one is dispatched);
+        # >1 = every N steps (set by install_metric for deferred-metric
+        # runs)
         self.flush_interval = 1
         self._steps_since_flush = 0
 
@@ -753,9 +760,9 @@ class SPMDTrainer(object):
             # counts runs REACHING the abort threshold — so a bad run
             # that ends between two deferred flushes still aborts at
             # the next flush (the peak would otherwise be lost when
-            # consec resets).  The host reads the counters lazily
-            # (flush_step_guard), never per-step — and they travel
-            # as ONE stacked i32[3] carry so each flush costs a
+            # consec resets).  The host reads the counters behind the
+            # device (_step_impl), never ahead of a dispatch — and they
+            # travel as ONE stacked i32[3] so each read costs a
             # single device->host transfer, not three (three scalar
             # fetches were measurable per-step host work on the
             # dispatch-bound LSTM path over a high-RTT device link).
@@ -769,6 +776,10 @@ class SPMDTrainer(object):
             new_extras["guard"] = jnp.stack(
                 [jnp.where(finite, total, total + 1), new_consec,
                  trips])
+            # the same 12 bytes once more, as an output that is no
+            # carry: the next step donates "guard", this one the host
+            # can still read after it has dispatched that step
+            new_extras["guard_snap"] = new_extras["guard"]
         if metric_fn is not None:
             # in-graph metric accumulation from this step's own
             # outputs and (pre-transform) labels; a guard-skipped
@@ -995,13 +1006,6 @@ class SPMDTrainer(object):
             return self._step_impl(batch_arrays, key)
 
     def _step_impl(self, batch_arrays, key):
-        # consume the PREVIOUS steps' guard counters before dispatching
-        # this one: a one-deep pipeline by default (the device runs step N
-        # while the host preps N+1); with flush_interval > 1 (deferred
-        # metrics) the read happens only every that-many steps
-        self._steps_since_flush += 1
-        if self._steps_since_flush >= max(1, self.flush_interval):
-            self.flush_step_guard()
         if self._zero3:
             # the manual tier shard_maps the step and every tier
             # dp-shards the batch: an indivisible (unpadded final)
@@ -1032,17 +1036,34 @@ class SPMDTrainer(object):
                         % (n, need, dp))
         with span("step.prepare"):
             args = self._step_args(batch_arrays, key)
-        with span("step.dispatch"):
+        # whether the trainer has waited for the step before this one:
+        # if not, this one joins it in the device's queue
+        queued = self._in_flight
+        with span("step.dispatch", queued=int(queued)):
             self.params, self.aux, self.opt_state, extras, outs = \
                 self._step_fn(*args)
+        count("step.overlapped" if queued else "step.drained")
+        self._in_flight = True
+        owed = self._guard_snap if self._guard_pending else None
         if self.step_guard:
             self._guard_acc = extras["guard"]
+            self._guard_snap = extras["guard_snap"]
             self._guard_pending = True
         if self._metric_fn is not None:
             self._metric_acc = extras["metric"]
         with span("step.localize"):
             outs = self._localize(outs)
         self._outputs = outs
+        # a one-deep pipeline: only now, with this step in the device's
+        # queue, wait for the PREVIOUS step's counters (every step, or
+        # every flush_interval steps under deferred metrics).  The cadence
+        # is a function of the step count alone, so every rank of a
+        # multi-process run reads, and aborts, at the same step.
+        self._steps_since_flush += 1
+        if owed is not None and \
+                self._steps_since_flush >= max(1, self.flush_interval):
+            self._steps_since_flush = 0
+            self._fold_guard(owed)
         return outs
 
     def _step_args(self, batch_arrays, key):
@@ -1117,22 +1138,53 @@ class SPMDTrainer(object):
             return np.asarray(v.addressable_shards[0].data)
         return np.asarray(v)
 
+    def guard_snapshot(self):
+        """The guard counters as the step dispatched last left them (a
+        device i32[3] no later step consumes; None with the guard off or
+        before the first step): what a caller keeps to ask LATER, once
+        more steps are queued, whether that step was skipped
+        (:meth:`step_skipped`)."""
+        return self._guard_snap
+
+    def step_skipped(self, snap):
+        """Whether the step that returned ``snap`` applied no update.
+        Blocks until that step has finished; folds nothing into the
+        host's counters."""
+        return snap is not None and int(self._read_guard(snap)[1]) > 0
+
+    def _read_guard(self, snap):
+        """Host value of one step's counters.  Blocks until that step
+        has finished.  The last reading is kept, so the module's metric
+        asks about the step the trainer has just read for free."""
+        if self._guard_read is None or self._guard_read[0] is not snap:
+            # ONE device->host fetch for all three counters (i32[3])
+            with span("step.guard_wait"):
+                self._guard_read = (
+                    snap, np.asarray(self._read_scalar(snap)))
+            if snap is self._guard_snap:
+                self._in_flight = False   # the device has drained
+        return self._guard_read[1]
+
     def flush_step_guard(self):
-        """Fold the in-graph skip counters into host state (blocks until
-        the last dispatched step's program finished).  Called
-        automatically at step() entry every ``flush_interval`` steps, at
-        get_params/get_states, and by the counter properties — so counter
-        reads are always exact; between reads the host may lag the device
-        by at most ``flush_interval`` steps (deferred-metric mode).
-        Raises the consecutive-bad-steps abort when the flushed run
-        crosses the limit."""
+        """Fold the in-graph skip counters of every step dispatched so
+        far into host state (blocks until the last dispatched step's
+        program finished).  Called by get_params/get_states and the
+        counter properties, so those reads are always exact; step()
+        itself folds one step behind (see ``_step_impl``), or every
+        ``flush_interval`` steps in deferred-metric mode.  Raises the
+        consecutive-bad-steps abort when the folded run crosses the
+        limit."""
         self._steps_since_flush = 0
         if not self._guard_pending:
             return
         self._guard_pending = False
-        # ONE device->host fetch for all three counters (stacked i32[3])
-        with span("step.guard_wait"):
-            acc = np.asarray(self._read_scalar(self._guard_acc))
+        self._fold_guard(self._guard_snap)
+
+    def _fold_guard(self, snap):
+        """Bring the host's counters up to the step that returned
+        ``snap``: the totals are cumulative, so a copy read late misses
+        nothing."""
+        acc = self._read_guard(snap)
         total = int(acc[0]) + self._skip_base
         consec = int(acc[1])
         trips = int(acc[2])
@@ -1142,8 +1194,9 @@ class SPMDTrainer(object):
         if delta > 0:
             # those programs applied no update — roll the update counter
             # back so lr schedules and adam bias correction see only
-            # applied steps (late by at most flush_interval steps under
-            # the pipelined read; self-corrects here)
+            # applied steps (one step late inside step(), at most
+            # flush_interval steps under deferred metrics; self-corrects
+            # here)
             self._num_update -= delta
             self._skipped_steps = total
             import logging
@@ -1210,6 +1263,7 @@ class SPMDTrainer(object):
             s = float(self._read_scalar(self._metric_acc[0]))
             c = float(self._read_scalar(self._metric_acc[1]))
         self._metric_acc = None  # fresh zeros at the next step
+        self._in_flight = False  # the last step has been waited for
         return s, c
 
     def reset_metric(self):
@@ -1369,7 +1423,7 @@ class SPMDTrainer(object):
         # abort gets the full MXTPU_MAX_BAD_STEPS budget again; the
         # lifetime skip total survives via the host base
         self._guard_pending = False
-        self._guard_acc = None
+        self._guard_acc = self._guard_snap = self._guard_read = None
         self._skip_base = self._skipped_steps
         self._consecutive_bad_steps = 0
         import pickle
@@ -1778,10 +1832,11 @@ class SPMDTrainer(object):
                         pass
 
         for attr in ("params", "aux", "opt_state", "_outputs",
-                     "_guard_acc", "_metric_acc"):
+                     "_guard_acc", "_guard_snap", "_metric_acc"):
             _delete_tree(getattr(self, attr, None))
             setattr(self, attr, None)
-        self._guard_pending = False
+        self._guard_pending = self._in_flight = False
+        self._guard_read = None
         # drop the jitted callables (each owns its executable + caches)
         self._step_raw = None
         for attr in ("_step_fn", "_eval_fn", "_rep_fn"):

@@ -19,7 +19,8 @@ memory, so a live job can be asked for its last minute.  A step of ``fit``
 over a ``DevicePrefetchIter`` over the native image pipeline makes 17
 records: 9 spans on the loop's thread (``fit.next``, ``feed.get_wait``,
 ``fit.step``, ``step.prepare``, ``step.dispatch``, ``step.localize``,
-``fit.metric``, one ``step.guard_wait``, ``fit.callback``), 3 on the feed's
+``fit.metric``, one ``step.guard_wait`` (of the step before, inside
+``fit.step`` after ``step.localize``), ``fit.callback``), 3 on the feed's
 worker, 4 on the decoder's threads, and one ``compile.trace`` event (the
 image iterator re-traces its device transform's shape once a batch);
 in-memory batches make 12.  ``RING`` holds over 3,800 such steps: about
