@@ -22,6 +22,8 @@ compiled function with those heads.
 """
 from __future__ import annotations
 
+from contextlib import nullcontext
+
 import numpy as np
 
 import jax
@@ -139,7 +141,8 @@ def _build_eval(symbol, placement=None, mirror_segments=0):
             from . import mxfuse
             const_env, infer_plan = mxfuse.fold_constants(
                 mxfuse.live_entries(fused_plan, out_refs))
-    if mirror_segments and mirror_segments > 1:
+    staged = any(_mirror_stage(entry[0]) for entry in fused_plan)
+    if staged or (mirror_segments and mirror_segments > 1):
         if placement:
             import logging
             logging.warning(
@@ -147,7 +150,8 @@ def _build_eval(symbol, placement=None, mirror_segments=0):
                 "runs per-op eagerly, which jax.checkpoint cannot wrap")
         else:
             return _build_eval_segmented(plan, fused_plan, out_refs,
-                                         int(mirror_segments))
+                                         0 if staged
+                                         else int(mirror_segments))
 
     if not placement:
         def eval_fn(args, aux, rng, is_train, monitor=None):
@@ -282,17 +286,49 @@ def _run_plan_nodes(chunk, env, args, aux, rng, is_train, aux_updates,
             monitor(node, env[id(node)])
 
 
+def _mirror_stage(node):
+    """The ``mirror_stage`` a graph node was built under (``with
+    mx.AttrScope(mirror_stage="l0_attn")``), or None."""
+    return None if node.op is None else node.attrs.get("mirror_stage")
+
+
+def _stage_chunks(fused_plan):
+    """[(stage or None, entries)]: maximal runs of plan entries built under
+    one ``mirror_stage``.  A variable belongs to the run it falls in."""
+    chunks, current = [], object()
+    for entry in fused_plan:
+        node = entry[0]
+        stage = current if node.op is None and chunks \
+            else _mirror_stage(node)
+        if not chunks or stage != current:
+            chunks.append((stage, []))
+            current = stage
+        chunks[-1][1].append(entry)
+    return chunks
+
+
 def _build_eval_segmented(plan, fused_plan, out_refs, n_segments):
-    """Segmented-remat eval: the plan is split into ~n_segments chunks,
-    each wrapped in jax.checkpoint.  Residuals between segments are only
-    the live boundary values, so activation memory scales with the segment
-    size while the backward recomputes within each segment.  Monitored
-    (per-op tap) runs interpret the plain ``plan``; everything else runs
-    the (possibly BN-fused) ``fused_plan`` — same node positions, so the
-    liveness analysis below serves both."""
-    n = len(fused_plan)
-    seg_size = max(1, -(-n // n_segments))
-    chunks = [fused_plan[i:i + seg_size] for i in range(0, n, seg_size)]
+    """Segmented-remat eval: the plan is split into chunks, each wrapped
+    in jax.checkpoint.  Residuals between segments are only the live
+    boundary values, so activation memory scales with the segment size
+    while the backward recomputes within each segment.  With
+    ``n_segments`` the chunks are that many equal runs of entries
+    (MXNET_BACKWARD_DO_MIRROR); with 0 they are the graph's own
+    ``mirror_stage`` runs: each stage is one checkpoint under a
+    ``jax.named_scope`` of the stage's name — so a device trace still
+    tells the stages apart, forward and backward, which a bare
+    checkpoint's op names do not — and entries built under no stage (an
+    embedding, a head) are not rematerialised.  Monitored (per-op tap)
+    runs interpret the plain ``plan``; everything else runs the (possibly
+    BN-fused) ``fused_plan`` — same node positions, so the liveness
+    analysis below serves both."""
+    if n_segments:
+        n = len(fused_plan)
+        seg_size = max(1, -(-n // n_segments))
+        stages = [""] * -(-n // seg_size)
+        chunks = [fused_plan[i:i + seg_size] for i in range(0, n, seg_size)]
+    else:
+        stages, chunks = zip(*_stage_chunks(fused_plan))
 
     # liveness: which node outputs cross each boundary
     produced_in = {}
@@ -341,8 +377,10 @@ def _build_eval_segmented(plan, fused_plan, out_refs, n_segments):
                                 seg_aux)
                 return tuple(env[i] for i in _out), seg_aux
 
-            out_vals, seg_aux = jax.checkpoint(seg)(carry_vals, args, aux,
-                                                    rng)
+            stage = stages[ci]
+            run = seg if stage is None else jax.checkpoint(seg)
+            with jax.named_scope(stage) if stage else nullcontext():
+                out_vals, seg_aux = run(carry_vals, args, aux, rng)
             aux_updates.update(seg_aux)
             carry_ids, carry_vals = ids_out, out_vals
 

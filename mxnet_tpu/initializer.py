@@ -222,6 +222,20 @@ class Normal(Initializer):
 
 
 @register
+class LogUniform(Initializer):
+    """log(U(low, high)): the decay-rate leaves (``A_log``) of gated
+    linear-attention mixers, as their families' code draws them."""
+
+    def __init__(self, low=1e-3, high=16.0):
+        super().__init__(low=low, high=high)
+        self.low, self.high = low, high
+
+    def _init_weight(self, _, arr):
+        _random.uniform(self.low, self.high, out=arr, shape=arr.shape)
+        arr[:] = np.log(arr.asnumpy())
+
+
+@register
 class Orthogonal(Initializer):
     """Orthogonal matrix init (reference initializer.py:Orthogonal)."""
 

@@ -161,6 +161,16 @@ def _init_data(data, allow_empty, default_name):
     return out
 
 
+def _batch_array(v):
+    """One batch of ``v`` as an NDArray: float32, as the reference hands
+    everything on, except 32- and 64-bit integers, which go on as int32 —
+    a token id or a class id above 256 does not survive the cast to a
+    bfloat16 compute dtype that a float goes through on its way to the
+    step.  (uint8 pixels and one-byte labels stay floats, as before.)"""
+    wide_int = np.issubdtype(v.dtype, np.integer) and v.dtype.itemsize >= 4
+    return nd_array(v, dtype=np.int32 if wide_int else None)
+
+
 class NDArrayIter(DataIter):
     """Iterate over in-memory arrays (reference io.py NDArrayIter):
     shuffle, last_batch_handle in {'pad', 'discard', 'roll_over'}."""
@@ -211,11 +221,12 @@ class NDArrayIter(DataIter):
 
     def _getdata(self, source):
         if self.cursor + self.batch_size <= self.num_data:
-            return [nd_array(v[self.cursor:self.cursor + self.batch_size])
+            return [_batch_array(v[self.cursor:self.cursor + self.batch_size])
                     for _, v in source]
         # pad with wrapped-around samples
         pad = self.batch_size - (self.num_data - self.cursor)
-        return [nd_array(np.concatenate([v[self.cursor:], v[:pad]], axis=0))
+        return [_batch_array(np.concatenate([v[self.cursor:], v[:pad]],
+                                            axis=0))
                 for _, v in source]
 
     def getdata(self):

@@ -17,6 +17,14 @@ Tiers (package docstring):
 - :func:`flash_attention_lax` — ``lax.scan`` over key blocks; pure lax,
   differentiable by jax (the scan transposes to the standard recompute
   backward), O(T) memory.
+- :func:`gqa_attention` — causal grouped-query attention (each
+  key/value head serves ``Hq // Hkv`` query heads, any head size) in
+  pure lax with its OWN backward (``jax.custom_vjp``: the saved
+  residuals are q, k, v, the output and one log-sum-exp a row; the
+  probabilities are recomputed a query block at a time), so neither
+  pass ever holds more than one (block x prefix) tile of scores and the
+  bf16 gradient is finite (masked scores are a large finite negative,
+  never ``-inf``).  The language models route here.
 - :func:`flash_attention_pallas` — a ``pl.pallas_call`` kernel (grid
   over batch x heads x query blocks x key blocks, the running triple in
   VMEM scratch across the key axis) behind ``jax.custom_vjp``; the
@@ -41,7 +49,8 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["flash_attention", "flash_attention_lax",
-           "flash_attention_pallas", "online_update", "default_block"]
+           "flash_attention_pallas", "online_update", "default_block",
+           "gqa_attention"]
 
 
 def default_block():
@@ -277,3 +286,108 @@ def flash_attention(q, k, v, causal=False, scale=None, block=None):
         functools.partial(flash_attention_lax, causal=causal, scale=scale,
                           block_k=block),
         q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# causal grouped-query attention, lax tier with its own backward
+# ---------------------------------------------------------------------------
+
+def _gqa_blocks(T, block_q):
+    bq = min(int(block_q), T)
+    return bq, -(-T // bq)
+
+
+def _gqa_scores(qi, k, i, bq, scale):
+    """Scores of query block ``i`` against its causal key prefix, f32:
+    qi (B, bq, Hkv, G, D), k (B, Lk, Hkv, D) -> (B, Hkv, G, bq, Lk)."""
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qi, k,
+                   preferred_element_type=jnp.float32) * scale
+    q_pos = i * bq + lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
+    k_pos = lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
+    return jnp.where(q_pos >= k_pos, s, _MASKED)
+
+
+def _gqa_fwd_blocks(q, k, v, scale, block_q):
+    B, T, Hq, D = q.shape
+    Hkv = k.shape[2]
+    bq, nq = _gqa_blocks(T, block_q)
+    q5 = q.reshape(B, T, Hkv, Hq // Hkv, D)
+    outs, lses = [], []
+    for i in range(nq):
+        lo, hi = i * bq, min((i + 1) * bq, T)
+        s = _gqa_scores(q5[:, lo:hi], k[:, :hi], i, bq, scale)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v[:, :hi],
+                       preferred_element_type=jnp.float32)
+        outs.append(o / jnp.moveaxis(l, (1, 2, 3), (2, 3, 1)))
+        lses.append((m + jnp.log(l))[..., 0])           # (B, Hkv, G, bq)
+    out = jnp.concatenate(outs, axis=1).reshape(B, T, Hq, D)
+    return out.astype(q.dtype), jnp.concatenate(lses, axis=-1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gqa(q, k, v, scale, block_q):
+    return _gqa_fwd_blocks(q, k, v, scale, block_q)[0]
+
+
+def _gqa_fwd(q, k, v, scale, block_q):
+    out, lse = _gqa_fwd_blocks(q, k, v, scale, block_q)
+    return out, (q, k, v, out, lse)
+
+
+def _gqa_bwd(scale, block_q, res, g):
+    q, k, v, out, lse = res
+    B, T, Hq, D = q.shape
+    Hkv = k.shape[2]
+    G = Hq // Hkv
+    bq, nq = _gqa_blocks(T, block_q)
+    q5 = q.reshape(B, T, Hkv, G, D)
+    g5 = g.reshape(B, T, Hkv, G, D)
+    # rowsum(dO * O): what the softmax's backward subtracts in each row
+    delta = jnp.sum(g5.astype(jnp.float32) * out.reshape(q5.shape)
+                    .astype(jnp.float32), axis=-1)      # (B, T, Hkv, G)
+    delta = jnp.moveaxis(delta, 1, 3)                   # (B, Hkv, G, T)
+    dk = jnp.zeros(k.shape, jnp.float32)
+    dv = jnp.zeros(v.shape, jnp.float32)
+    dqs = []
+    for i in range(nq):
+        lo, hi = i * bq, min((i + 1) * bq, T)
+        qi, gi = q5[:, lo:hi], g5[:, lo:hi]
+        s = _gqa_scores(qi, k[:, :hi], i, bq, scale)
+        p = jnp.exp(s - lse[..., lo:hi, None])
+        dp = jnp.einsum("bqhgd,bkhd->bhgqk", gi, v[:, :hi],
+                        preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta[..., lo:hi, None]) * scale).astype(q.dtype)
+        pb = p.astype(q.dtype)
+        dv = dv.at[:, :hi].add(jnp.einsum(
+            "bhgqk,bqhgd->bkhd", pb, gi,
+            preferred_element_type=jnp.float32))
+        dk = dk.at[:, :hi].add(jnp.einsum(
+            "bhgqk,bqhgd->bkhd", ds, qi,
+            preferred_element_type=jnp.float32))
+        dqs.append(jnp.einsum("bhgqk,bkhd->bqhgd", ds, k[:, :hi],
+                              preferred_element_type=jnp.float32))
+    dq = jnp.concatenate(dqs, axis=1).reshape(q.shape)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_gqa.defvjp(_gqa_fwd, _gqa_bwd)
+
+
+def gqa_attention(q, k, v, scale=None, block_q=512):
+    """Causal grouped-query attention.  q (B, T, Hq, D); k, v
+    (B, T, Hkv, D) with ``Hq % Hkv == 0``: key/value head ``h`` serves
+    query heads ``h*G .. h*G+G-1``.  Returns (B, T, Hq, D) in q's dtype.
+
+    Query rows go in blocks of ``block_q``; block ``i`` meets only its
+    causal prefix of keys (a static slice), so the work is the lower
+    triangle plus half a block, and the largest tile either pass holds
+    is (B, Hq, block_q, T) scores.  Contractions take the operands'
+    dtype with float32 accumulation; the softmax is float32."""
+    D = q.shape[-1]
+    if q.shape[2] % k.shape[2]:
+        raise ValueError("gqa_attention: %d query heads over %d key/value "
+                         "heads" % (q.shape[2], k.shape[2]))
+    return _gqa(q, k, v, float(scale or 1.0 / np.sqrt(D)), int(block_q))

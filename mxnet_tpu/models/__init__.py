@@ -7,6 +7,7 @@ reference's fit.py does (example/image-classification/common/fit.py).
 from . import lenet, mlp, alexnet, vgg, resnet, inception_bn, mobilenet
 from . import googlenet, inception_v3, resnext
 from . import lstm_lm
+from . import qwen3_next
 
 _BUILDERS = {
     "lenet": lenet.get_symbol,

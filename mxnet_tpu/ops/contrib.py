@@ -532,3 +532,201 @@ def contrib_attention(query, key, value, num_heads=1, causal=False,
     s = None if float(scale) <= 0 else float(scale)
     out = full_attention(q, k, v, causal=bool(causal), scale=s)
     return out.reshape(B, T, D)
+
+
+# ---------------------------------------------------------------------------
+# Sequence mixers and the routed-expert layer of today's hybrid language
+# models (no 2017 counterpart).  The arithmetic is pure lax: kernels/
+# flash_attention.gqa_attention, kernels/delta_rule.gated_delta_rule,
+# lax.ragged_dot.
+# ---------------------------------------------------------------------------
+
+def _first_input_infer(attrs, in_shapes):
+    """The output has the first input's shape; every input's shape is the
+    caller's to give (the model builder declares its variables' shapes)."""
+    first = in_shapes[0]
+    return list(in_shapes), [None if first is None else tuple(first)], []
+
+
+@register("_contrib_GQAttention", aliases=("GQAttention",),
+          input_names=lambda attrs: ("query", "key", "value", "gate")
+          if attrs.get("gated", False) else ("query", "key", "value"),
+          infer_shape=_first_input_infer)
+def gq_attention(query, key, value, gate=None, scale=-1.0, block_q=512,
+                 gated=False):
+    """Causal grouped-query softmax attention that never holds a
+    (positions x positions) matrix.  query (batch, positions, query_heads,
+    head_dim); key, value (batch, positions, kv_heads, head_dim), each
+    key/value head serving ``query_heads // kv_heads`` consecutive query
+    heads.  ``scale`` <= 0 means head_dim^-0.5.  With ``gated`` the result
+    is multiplied by ``sigmoid(gate)`` (gate shaped like query)."""
+    from ..kernels.flash_attention import gqa_attention
+    out = gqa_attention(query, key, value,
+                        scale=None if float(scale) <= 0 else float(scale),
+                        block_q=int(block_q))
+    if gate is not None:
+        out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+    return out
+
+
+def _delta_rule_infer(attrs, in_shapes):
+    value = in_shapes[2]
+    return list(in_shapes), [None if value is None else tuple(value)], []
+
+
+@register("_contrib_GatedDeltaRule", aliases=("GatedDeltaRule",),
+          input_names=("query", "key", "value", "a", "b", "A_log",
+                       "dt_bias"),
+          infer_shape=_delta_rule_infer)
+def gated_delta_rule_op(query, key, value, a, b, A_log, dt_bias, chunk=64,
+                        eps=1e-6):
+    """Gated delta rule (Gated DeltaNet), computed in chunks of ``chunk``
+    positions.  query, key (batch, positions, key_heads, dk); value (batch,
+    positions, value_heads, dv); a, b (batch, positions, value_heads);
+    A_log, dt_bias (value_heads,).  Each key head serves ``value_heads //
+    key_heads`` consecutive value heads.  In float32: query and key are
+    L2-normalised per head (``eps``), query scaled by dk^-0.5,
+    ``beta = sigmoid(b)``, ``g = -exp(A_log) * softplus(a + dt_bias)``; per
+    value head, from a zero state, position t does ``S = exp(g_t) S;
+    u = (v_t - S^T k_t) beta_t; S = S + k_t u^T; o_t = S^T q_t``.  Returns
+    o shaped like value."""
+    from ..kernels.delta_rule import gated_delta_rule
+    f32 = jnp.float32
+    rep = value.shape[2] // query.shape[2]
+
+    def unit(x):
+        x = x.astype(f32)
+        x = x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                          + float(eps))
+        return jnp.repeat(x, rep, axis=2) if rep > 1 else x
+
+    q = unit(query) * (query.shape[-1] ** -0.5)
+    k = unit(key)
+    beta = jax.nn.sigmoid(b.astype(f32))
+    g = -jnp.exp(A_log.astype(f32)) * jax.nn.softplus(
+        a.astype(f32) + dt_bias.astype(f32))
+    out = gated_delta_rule(q, k, value, g, beta, chunk=int(chunk))
+    return out.astype(value.dtype)
+
+
+@jax.custom_vjp
+def _rows_by_pair(x, order, inverse, k):
+    """``x[order // k]``: the rows of ``x`` (tokens, width), one a (token,
+    expert) pair, in sorted order.  ``inverse`` is the permutation that
+    takes sorted pairs back to token-major order, ``k`` pairs a token, so
+    the backward is a gather and a sum, not a scatter."""
+    return jnp.take(x, order // k, axis=0)
+
+
+def _rows_fwd(x, order, inverse, k):
+    return jnp.take(x, order // k, axis=0), (inverse, x.shape[0])
+
+
+def _rows_bwd(res, g):
+    inverse, tokens = res
+    back = jnp.take(g, inverse, axis=0).astype(jnp.float32)
+    return (back.reshape(tokens, -1, g.shape[-1]).sum(axis=1)
+            .astype(g.dtype), None, None, None)
+
+
+_rows_by_pair.defvjp(_rows_fwd, _rows_bwd)
+
+
+@jax.custom_vjp
+def _permuted(x, perm, inverse):
+    """``x[perm]`` for a permutation and its inverse: gathers both ways."""
+    return jnp.take(x, perm, axis=0)
+
+
+_permuted.defvjp(
+    lambda x, perm, inverse: (jnp.take(x, perm, axis=0), inverse),
+    lambda inverse, g: (jnp.take(g, inverse, axis=0), None, None))
+
+
+def _grouped_dot(rows, weight, sizes, valid):
+    """``lax.ragged_dot`` over the groups, with the rows past the last
+    group — which it neither reads nor writes, going forward or backward —
+    held at zero on both sides of it."""
+    rows = jnp.where(valid, rows, 0)
+    return jnp.where(valid, lax.ragged_dot(rows, weight, sizes), 0)
+
+
+def _routed_infer(attrs, in_shapes):
+    data = in_shapes[0]
+    return list(in_shapes), \
+        [None if data is None else tuple(data), (4,)], []
+
+
+@register("_contrib_RoutedExperts", aliases=("RoutedExperts",),
+          input_names=("data", "router_weight", "gate_up_weight",
+                       "down_weight"),
+          num_outputs=2, output_names=("output", "stats"),
+          infer_shape=_routed_infer)
+def routed_experts(data, router_weight, gate_up_weight, down_weight,
+                   top_k=1, expert_offset=0, norm_topk_prob=True):
+    """Dropless top-``top_k`` routed experts, told which experts it holds.
+    data (tokens, hidden); router_weight (num_experts, hidden) over ALL the
+    experts of the layer; gate_up_weight (held, hidden, 2 x width) and
+    down_weight (held, width, hidden) of the experts ``expert_offset ..
+    expert_offset + held - 1`` that live here.  In float32
+    ``p = softmax(data @ router_weight.T)`` over all experts; each token
+    takes its ``top_k`` largest (divided by their sum with
+    ``norm_topk_prob``); expert e gives ``(silu(x @ gate_e) * (x @ up_e)) @
+    down_e``.  Output 0 is the part of the weighted sum that the held
+    experts give — a token none of whose experts is held gets zeros, no
+    token is dropped and there is no capacity; what the other experts
+    would add is their chips' to compute.  Output 1 (no gradient) is four
+    float32 counts of this call: token-expert pairs routed, those that
+    landed on held experts, the fullest held expert's pairs, the mean over
+    held experts.
+
+    Pairs are sorted by expert, so each held expert's tokens are one
+    contiguous group of rows and the expert products are two
+    ``lax.ragged_dot`` calls over those groups; rows past the last group
+    (pairs of absent experts) are never multiplied."""
+    f32 = jnp.float32
+    tokens = data.shape[0]
+    held = gate_up_weight.shape[0]
+    k = int(top_k)
+    logits = jnp.dot(data, router_weight.T, preferred_element_type=f32)
+    weight, expert = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if norm_topk_prob:
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    local = expert.reshape(-1) - int(expert_offset)      # (tokens * k,)
+    here = (local >= 0) & (local < held)
+    # held pairs first, grouped by expert; absent ones behind them
+    order = jnp.argsort(jnp.where(here, local, held), stable=True)
+    inverse = jnp.argsort(order)
+    sizes = jnp.sum(jax.nn.one_hot(jnp.where(here, local, held), held,
+                                   dtype=jnp.int32), axis=0)
+    valid = (jnp.arange(tokens * k) < jnp.sum(sizes))[:, None]
+    rows = _rows_by_pair(data, order, inverse, k)        # (tokens * k, H)
+    gate, up = jnp.split(_grouped_dot(rows, gate_up_weight, sizes, valid),
+                         2, axis=-1)
+    out = _grouped_dot(jax.nn.silu(gate) * up, down_weight, sizes, valid)
+    # back to token-major pairs, weighted, summed over a token's k
+    out = _permuted(out, inverse, order).reshape(tokens, k, -1)
+    scale = jnp.where(here.reshape(tokens, k), weight, 0.0)[..., None]
+    out = jnp.sum(out.astype(f32) * scale, axis=1).astype(data.dtype)
+    load = sizes.astype(f32)
+    stats = lax.stop_gradient(jnp.stack([
+        jnp.asarray(tokens * k, f32), jnp.sum(load), jnp.max(load),
+        jnp.mean(load)]))
+    return out, stats
+
+
+@register("_contrib_RoutedExpertsStats", aliases=("RoutedExpertsStats",),
+          variable_inputs=True,
+          input_names=lambda attrs: tuple(
+              "arg%d" % i for i in range(int(attrs.get("num_args", 1)))),
+          infer_shape=lambda attrs, in_shapes: (list(in_shapes), [(4,)], []))
+def routed_experts_stats(*stats, num_args=1):
+    """One step's counts over several routed-expert layers, from each
+    layer's ``stats`` output: pairs routed and pairs that landed here
+    summed over the layers; the fullest held expert's pairs and the mean
+    over held experts, both of the layer whose fullest expert is
+    fullest."""
+    s = jnp.stack(stats)                                 # (layers, 4)
+    worst = jnp.argmax(s[:, 2])
+    return jnp.stack([jnp.sum(s[:, 0]), jnp.sum(s[:, 1]), s[worst, 2],
+                      s[worst, 3]])

@@ -287,6 +287,8 @@ def activation(data, act_type="relu"):
         return jax.nn.softplus(data)
     if act_type == "softsign":
         return jax.nn.soft_sign(data)
+    if act_type == "silu":
+        return jax.nn.silu(data)
     raise MXNetError("unknown act_type %r" % act_type)
 
 
@@ -426,6 +428,100 @@ def lrn(data, nsize=5, alpha=1e-4, beta=0.75, knorm=2.0):
         for i in range(nsize))
     norm = jnp.power(knorm + (alpha / nsize) * windows, -beta)
     return data * norm
+
+
+# ---------------------------------------------------------------------------
+# Layers of today's language models (no 2017 counterpart): RMSNorm, the
+# gated feed-forward's SwiGLU, partial rotary embedding, the causal
+# depthwise short convolution of linear-attention mixers
+# ---------------------------------------------------------------------------
+
+def _rmsnorm_inputs(attrs):
+    return ("data", "gamma", "gate") if attrs.get("gated", False) \
+        else ("data", "gamma")
+
+
+def _rmsnorm_infer(attrs, in_shapes):
+    data = in_shapes[0]
+    if data is None:
+        return in_shapes, [None], []
+    shapes = [tuple(data), (data[-1],)]
+    if attrs.get("gated", False):
+        shapes.append(tuple(data))
+    return shapes, [tuple(data)], []
+
+
+@register("RMSNorm", input_names=_rmsnorm_inputs, infer_shape=_rmsnorm_infer)
+def rms_norm(data, gamma, gate=None, eps=1e-6, zero_centered=False,
+             gated=False):
+    """Root-mean-square norm over the last axis, computed in float32:
+    ``x * rsqrt(mean(x^2) + eps) * w``, with ``w = 1 + gamma`` when
+    ``zero_centered`` (gamma initialised 0) and ``w = gamma`` otherwise.
+    With ``gated`` the result is multiplied by ``silu(gate)`` (the output
+    norm of a gated linear-attention mixer)."""
+    x = data.astype(jnp.float32)
+    w = gamma.astype(jnp.float32)
+    y = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + float(eps))
+    y = y * (1.0 + w if zero_centered else w)
+    if gate is not None:
+        y = y * jax.nn.silu(gate.astype(jnp.float32))
+    return y.astype(data.dtype)
+
+
+@register("_contrib_SwiGLU", aliases=("SwiGLU",))
+def swiglu(data):
+    """``silu(a) * b`` for ``data = [a, b]`` along the last axis: the gate
+    and up halves of a gated feed-forward's first projection."""
+    a, b = jnp.split(data, 2, axis=-1)
+    return jax.nn.silu(a) * b
+
+
+@register("_contrib_RotaryEmbedding", aliases=("RotaryEmbedding",))
+def rotary_embedding(data, rotary_dim=0, base=10000.0):
+    """Rotary position embedding, rotate-half form, on the first
+    ``rotary_dim`` features of every head (all of them when 0); the rest
+    pass through.  data (batch, positions, heads, head_dim); position p
+    turns the pair (x[i], x[i + rotary_dim/2]) by p * base^(-2i/rotary_dim).
+    Angles are float32."""
+    d = int(rotary_dim) or data.shape[-1]
+    half = d // 2
+    inv_freq = float(base) ** (-jnp.arange(half, dtype=jnp.float32)
+                               * 2.0 / d)
+    angle = jnp.arange(data.shape[1], dtype=jnp.float32)[:, None] \
+        * inv_freq[None, :]                             # (T, half)
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    x1 = data[..., :half].astype(jnp.float32)
+    x2 = data[..., half:d].astype(jnp.float32)
+    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                             axis=-1).astype(data.dtype)
+    return jnp.concatenate([turned, data[..., d:]], axis=-1)
+
+
+def _causal_conv_infer(attrs, in_shapes):
+    data = in_shapes[0]
+    if data is None:
+        return in_shapes, [None], []
+    return [tuple(data), (data[-1], int(attrs["kernel"]))], \
+        [tuple(data)], []
+
+
+@register("_contrib_CausalConv1D", aliases=("CausalConv1D",),
+          input_names=("data", "weight"), infer_shape=_causal_conv_infer)
+def causal_conv1d(data, weight, kernel=4, act_type=None):
+    """Causal depthwise convolution along positions, no bias: data (batch,
+    positions, channels), weight (channels, kernel);
+    ``y[t] = sum_j weight[:, j] * x[t - (kernel-1) + j]`` with zeros before
+    a row's start.  ``act_type`` as in ``Activation``.  The taps are summed
+    (and the activation taken) in float32."""
+    k = int(kernel)
+    x = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+    w = weight.astype(jnp.float32)
+    t = data.shape[1]
+    y = sum(x[:, j:j + t] * w[:, j] for j in range(k))
+    if act_type:
+        y = activation(y, act_type)
+    return y.astype(data.dtype)
 
 
 # ---------------------------------------------------------------------------

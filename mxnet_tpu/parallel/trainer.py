@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import math
 import re
+import time
 
 import numpy as np
 
@@ -29,7 +30,7 @@ from ..base import MXNetError, register_env
 from ..executor import _build_eval
 from ..ndarray import NDArray
 from ..io import DataDesc
-from ..profiler import count, span
+from ..profiler import count, event, span
 
 __all__ = ["SPMDTrainer", "SUPPORTED_OPTIMIZERS",
            "DEFAULT_GUARD_FLUSH_INTERVAL"]
@@ -205,6 +206,19 @@ class SPMDTrainer(object):
             mirror_segments=mirror_segments_for(symbol, force=self.remat))
         self.arg_names = symbol.list_arguments()
         self.aux_names = symbol.list_auxiliary_states()
+        # heads that are counters, not outputs: a head whose node carries
+        # ``__step_counters__="name,name,..."`` is a vector the graph
+        # computed about this step (a routed-expert layer's load).  It
+        # leaves the compiled step beside the guard's readable copy and
+        # is added to ``profiler.count`` under those names once the NEXT
+        # step is dispatched, like the guard's counters; the module, the
+        # metric and ``outputs`` never see it.
+        self._counter_heads = {
+            i: [n.strip() for n in
+                str(node.attrs["__step_counters__"]).split(",")]
+            for i, (node, _) in enumerate(symbol._outputs)
+            if "__step_counters__" in node.attrs}
+        self._counters_pending = []       # steps' vectors not yet counted
 
         # NaN/Inf step guard: an in-graph all-finite check over the raw
         # gradients; a non-finite step applies NO update (params, aux and
@@ -292,7 +306,7 @@ class SPMDTrainer(object):
         arg_shapes, out_shapes, aux_shapes = self.symbol.infer_shape(**shapes)
         self.arg_shapes = dict(zip(self.arg_names, arg_shapes))
         self.aux_shapes = dict(zip(self.aux_names, aux_shapes))
-        self.out_shapes = out_shapes
+        self.out_shapes = self._outputs_only(out_shapes)
         self.param_names = [n for n in self.arg_names
                             if n not in self.input_names]
         self.batch_size = data_shapes[0].shape[0]
@@ -416,14 +430,17 @@ class SPMDTrainer(object):
             self._init_params(initializer, arg_params, aux_params)
 
     def _init_params(self, initializer, arg_params, aux_params):
+        from ..initializer import InitDesc
         from ..ndarray import zeros as nd_zeros
         params, aux = {}, {}
+        attrs = self.symbol.attr_dict()
         for name in self.param_names:
             arr = nd_zeros(self.arg_shapes[name])
             if arg_params and name in arg_params:
                 arr[:] = arg_params[name]
             elif initializer is not None:
-                initializer(name, arr)
+                # a variable's own ``init`` wins over the name's pattern
+                initializer(InitDesc(name, attrs.get(name)), arr)
             params[name] = arr._data
         for name in self.aux_names:
             arr = nd_zeros(self.aux_shapes[name])
@@ -674,7 +691,7 @@ class SPMDTrainer(object):
             merged = xform(data)
             merged.update(params)
             outs, _ = eval_fn(merged, aux, rng, is_train)
-            return outs
+            return self._outputs_only(outs)
 
         # input shardings propagate from the placed arguments (params were
         # device_put with their NamedShardings, batches are sharded in
@@ -720,6 +737,8 @@ class SPMDTrainer(object):
         metric_fn = self._metric_fn
         maxbad = self.max_consecutive_bad_steps
         finite = None
+        counters = [outs[i] for i in self._counter_heads]
+        outs = self._outputs_only(outs)
         if guard:
             # all-finite over every gradient, folded into the same XLA
             # program (one fused reduction tree) — the in-graph analog
@@ -794,7 +813,16 @@ class SPMDTrainer(object):
                 ds = jnp.where(finite, ds, jnp.zeros_like(ds))
                 dc = jnp.where(finite, dc, jnp.zeros_like(dc))
             new_extras["metric"] = (msum + ds, mcnt + dc)
+        if counters:
+            new_extras["counters"] = counters
         return new_params, new_aux, new_state, new_extras, list(outs)
+
+    def _outputs_only(self, heads):
+        """``heads`` (one entry a symbol head) without the counter heads."""
+        if not self._counter_heads:
+            return heads
+        return [h for i, h in enumerate(heads)
+                if i not in self._counter_heads]
 
     def _make_zero3_step(self, xform, cast):
         """The grad_sync='zero3' fused step (both tiers).
@@ -1051,6 +1079,8 @@ class SPMDTrainer(object):
             self._guard_pending = True
         if self._metric_fn is not None:
             self._metric_acc = extras["metric"]
+        if self._counter_heads:
+            self._counters_pending.append(extras["counters"])
         with span("step.localize"):
             outs = self._localize(outs)
         self._outputs = outs
@@ -1060,11 +1090,31 @@ class SPMDTrainer(object):
         # is a function of the step count alone, so every rank of a
         # multi-process run reads, and aborts, at the same step.
         self._steps_since_flush += 1
-        if owed is not None and \
-                self._steps_since_flush >= max(1, self.flush_interval):
-            self._steps_since_flush = 0
-            self._fold_guard(owed)
+        if self._steps_since_flush >= max(1, self.flush_interval):
+            if owed is not None:
+                self._steps_since_flush = 0
+                self._fold_guard(owed)
+            self._count_step_counters(keep=1)
         return outs
+
+    def _count_step_counters(self, keep=0):
+        """Add the graph's counter vectors of all but the newest ``keep``
+        dispatched steps to the recorder.  With ``keep=1`` the fetch waits
+        only for steps before the one just dispatched."""
+        due = self._counters_pending[:len(self._counters_pending) - keep]
+        if not due:
+            return
+        del self._counters_pending[:len(due)]
+        for vectors in jax.device_get(due):
+            step = {}
+            for names, vector in zip(self._counter_heads.values(), vectors):
+                step.update(zip(names, map(float, np.asarray(vector).ravel())))
+            for name, value in step.items():
+                count(name, value)
+            # the same numbers once more as one record a step, so that a
+            # reader can tell a window's steps from the ones before it
+            now = time.perf_counter_ns()
+            event("step.counters", now, now, **step)
 
     def _step_args(self, batch_arrays, key):
         """The arguments of one call of the compiled step: the batch on
@@ -1175,6 +1225,7 @@ class SPMDTrainer(object):
         consecutive-bad-steps abort when the folded run crosses the
         limit."""
         self._steps_since_flush = 0
+        self._count_step_counters()
         if not self._guard_pending:
             return
         self._guard_pending = False
@@ -1837,6 +1888,7 @@ class SPMDTrainer(object):
             setattr(self, attr, None)
         self._guard_pending = self._in_flight = False
         self._guard_read = None
+        self._counters_pending = []
         # drop the jitted callables (each owns its executable + caches)
         self._step_raw = None
         for attr in ("_step_fn", "_eval_fn", "_rep_fn"):

@@ -465,6 +465,8 @@ def Variable(name, attr=None, shape=None, lr_mult=None, wd_mult=None,
         attrs["lr_mult"] = str(lr_mult)
     if wd_mult is not None:
         attrs["wd_mult"] = str(wd_mult)
+    if init is not None:
+        attrs["__init__"] = init if isinstance(init, str) else init.dumps()
     attrs.update({k: str(v) for k, v in kwargs.items()})
     return Symbol([(_Node(None, name, attrs), 0)])
 

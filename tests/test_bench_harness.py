@@ -249,7 +249,9 @@ def test_roofline_bench_small_preset_proves_wins():
     kernel (and every mxfuse pass) reports fused/unfused timings, a
     roofline bound with its binding side, and beats its unfused
     composition (the win each kernel must prove in the artifact)."""
-    out = bench._roofline_bench(preset="small", trials=1)
+    # best of 3 trials: one trial's timing of a 50 us call can read ten
+    # times high when the whole suite loads the machine
+    out = bench._roofline_bench(preset="small", trials=3)
     for op in ("bn_act", "lstm_cell", "flash_attention",
                "eltwise_chain", "concat_fuse", "pool_act"):
         assert out["roofline_%s_fused_us" % op] > 0

@@ -184,6 +184,19 @@ def data_iter():
 _applied = set()
 
 
+def wait_for_go():
+    # the orchestrator appends "go" once the fleet and the clients are up:
+    # whatever the bring-up took, every publish after the first goes to a
+    # LIVE region, so a scheduled rot can land neither after the last
+    # publish nor on it (the fleet would then never serve the last epoch)
+    while True:
+        if os.path.exists(CMD):
+            with open(CMD) as f:
+                if "go" in f.read().split():
+                    return
+        time.sleep(0.05)
+
+
 def poll_commands():
     # the orchestrator's storm scheduler appends "arm point:times[@after]"
     # lines; each is applied exactly once, in-process, BEFORE the next
@@ -240,6 +253,7 @@ for epoch in range(start_epoch + 1, EPOCHS + 1):
     t.save_checkpoint(mgr, epoch, blocking=True)
     write_status(epoch, it, batches, t0)
     print("REGION_TRAINER epoch=%d world=%d" % (epoch, world), flush=True)
+    wait_for_go()
     time.sleep(GAP_S)
 it.close()
 print("REGION_TRAINER done epochs=%d" % EPOCHS, flush=True)
@@ -441,6 +455,8 @@ class Region(object):
         self._start_stats_server()
         self._start_poller()
         self._start_clients()
+        with open(self.cmd_file, "a") as f:
+            f.write("go\n")
         _log("region is up: %d data server(s), trainer(world=%d), "
              "%d replica(s), %d client(s); stats at http://%s/region/stats"
              % (self.spec.data_servers, self._world, self.spec.replicas,
