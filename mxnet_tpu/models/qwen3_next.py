@@ -28,7 +28,7 @@ from ..attribute import AttrScope
 #: counters of the routed-expert layers, in the order RoutedExpertsStats
 #: gives them; a trainer adds them to ``profiler.count`` one step late
 MOE_COUNTERS = ("moe.assignments", "moe.assignments_here", "moe.load_max",
-                "moe.load_mean")
+                "moe.load_mean", "moe.calls", "moe.compact_calls")
 
 
 def _norm(x, name, width, zero_centered=True, gate=None, eps=1e-6):

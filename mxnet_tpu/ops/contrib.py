@@ -10,6 +10,8 @@ the reference layers that declare no backward.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 import jax
@@ -651,10 +653,141 @@ def _grouped_dot(rows, weight, sizes, valid):
     return jnp.where(valid, lax.ragged_dot(rows, weight, sizes), 0)
 
 
+def _expert_products(rows, gate_up_weight, down_weight, sizes, valid):
+    """``(silu(x @ gate_e) * (x @ up_e)) @ down_e`` for rows grouped by
+    expert e: (rows, hidden) -> (rows, hidden), zeros where not ``valid``."""
+    gate, up = jnp.split(_grouped_dot(rows, gate_up_weight, sizes, valid),
+                         2, axis=-1)
+    return _grouped_dot(jax.nn.silu(gate) * up, down_weight, sizes, valid)
+
+
+@jax.custom_vjp
+def _rows_at(x, token):
+    """``x[token]`` for (tokens, width) ``x``, a token taken any number of
+    times: the backward adds the rows' cotangents up by token in float32."""
+    return jnp.take(x, token, axis=0)
+
+
+def _rows_at_bwd(res, g):
+    token, tokens = res
+    back = jnp.zeros((tokens, g.shape[-1]), jnp.float32)
+    return back.at[token].add(g.astype(jnp.float32)).astype(g.dtype), None
+
+
+_rows_at.defvjp(
+    lambda x, token: (jnp.take(x, token, axis=0), (token, x.shape[0])),
+    _rows_at_bwd)
+
+
+def _experts_full(data, weight, gate_up_weight, down_weight, order, sizes,
+                  here):
+    """The held experts' weighted sum over ALL tokens * k pairs in sorted
+    order: whatever the router did, at the width of the worst case."""
+    tokens, k = weight.shape
+    inverse = jnp.argsort(order)
+    valid = (jnp.arange(tokens * k) < jnp.sum(sizes))[:, None]
+    rows = _rows_by_pair(data, order, inverse, k)        # (tokens * k, H)
+    out = _expert_products(rows, gate_up_weight, down_weight, sizes, valid)
+    # back to token-major pairs, weighted, summed over a token's k
+    out = _permuted(out, inverse, order).reshape(tokens, k, -1)
+    scale = jnp.where(here.reshape(tokens, k), weight, 0.0)[..., None]
+    return jnp.sum(out.astype(jnp.float32) * scale,
+                   axis=1).astype(data.dtype)
+
+
+def _experts_block(data, weight, gate_up_weight, down_weight, order, sizes,
+                   start, capacity, into):
+    """``into`` (tokens, hidden) float32 plus the held experts' weighted
+    sum over the sorted pairs ``start .. start + capacity - 1``: nothing
+    here is tokens * k long but ``order`` and ``weight``."""
+    k = weight.shape[1]
+    pair = lax.dynamic_slice(order, (start,), (capacity,))
+    token = pair // k
+    # the part of each expert's group that lies inside this block
+    ends = jnp.cumsum(sizes)
+    block = (jnp.clip(ends, start, start + capacity)
+             - jnp.clip(ends - sizes, start, start + capacity))
+    live = (jnp.arange(capacity) < jnp.sum(block))[:, None]
+    rows = _rows_at(data, token)                         # (capacity, H)
+    out = _expert_products(rows, gate_up_weight, down_weight, block, live)
+    scale = jnp.take(weight.reshape(-1), pair)[:, None]
+    return into.at[token].add(out.astype(jnp.float32) * scale)
+
+
+def _blocks(order, sizes, capacity):
+    """``order`` padded to whole blocks of ``capacity`` pairs, and the
+    test that block ``i`` still holds a held expert's pair."""
+    order = jnp.pad(order, (0, -order.shape[0] % capacity))
+    landed = jnp.sum(sizes)
+    return order, lambda carry: carry[0] * capacity < landed
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _experts_blocked(data, weight, gate_up_weight, down_weight, order, sizes,
+                     capacity):
+    """The held experts' weighted sum, a block of ``capacity`` sorted
+    pairs at a time for as many blocks as the held experts' pairs fill —
+    counted on the device: one on a step whose pairs fit ``capacity``,
+    none when no pair landed.  The backward is the same loop over each
+    block's own vjp, the gradients summed in float32."""
+    return _experts_blocked_fwd(data, weight, gate_up_weight, down_weight,
+                                order, sizes, capacity)[0]
+
+
+def _experts_blocked_fwd(data, weight, gate_up_weight, down_weight, order,
+                         sizes, capacity):
+    padded, more = _blocks(order, sizes, capacity)
+
+    def body(carry):
+        i, out = carry
+        return i + 1, _experts_block(
+            data, weight, gate_up_weight, down_weight, padded, sizes,
+            i * capacity, capacity, out)
+    _, out = lax.while_loop(
+        more, body, (0, jnp.zeros(data.shape, jnp.float32)))
+    return out.astype(data.dtype), (data, weight, gate_up_weight,
+                                    down_weight, order, sizes)
+
+
+def _experts_blocked_bwd(capacity, res, g):
+    leaves, (order, sizes) = res[:4], res[4:]
+    padded, more = _blocks(order, sizes, capacity)
+    g = g.astype(jnp.float32)
+    nothing = jnp.zeros(g.shape, jnp.float32)
+
+    def body(carry):
+        i, total = carry
+        grads = jax.vjp(
+            lambda *a: _experts_block(*a, padded, sizes, i * capacity,
+                                      capacity, nothing), *leaves)[1](g)
+        return i + 1, tuple(t + x.astype(jnp.float32)
+                            for t, x in zip(total, grads))
+    _, total = lax.while_loop(more, body, (0, tuple(
+        jnp.zeros(x.shape, jnp.float32) for x in leaves)))
+    # the weights' gradients leave in the weights' dtype: without the
+    # barrier XLA fuses this narrowing with the optimizer's widening, and
+    # a step that holds every gradient until its all-finite check holds
+    # the float32 sums, at twice the size
+    grads = tuple(t.astype(x.dtype) for t, x in zip(total, leaves))
+    return tuple(lax.optimization_barrier(grads)) + (None, None)
+
+
+_experts_blocked.defvjp(_experts_blocked_fwd, _experts_blocked_bwd)
+
+
+def _capacity(pairs, held, num_experts):
+    """Rows of one block of the blocked path: twice the share of the
+    ``pairs`` (tokens * k) that a uniform router lands on ``held`` of
+    ``num_experts`` experts, rounded up to a multiple of 1,024; at
+    ``pairs`` there is no blocked path."""
+    share = -(-2 * pairs * held // num_experts)
+    return min(pairs, -(-share // 1024) * 1024)
+
+
 def _routed_infer(attrs, in_shapes):
     data = in_shapes[0]
     return list(in_shapes), \
-        [None if data is None else tuple(data), (4,)], []
+        [None if data is None else tuple(data), (6,)], []
 
 
 @register("_contrib_RoutedExperts", aliases=("RoutedExperts",),
@@ -673,45 +806,61 @@ def routed_experts(data, router_weight, gate_up_weight, down_weight,
     takes its ``top_k`` largest (divided by their sum with
     ``norm_topk_prob``); expert e gives ``(silu(x @ gate_e) * (x @ up_e)) @
     down_e``.  Output 0 is the part of the weighted sum that the held
-    experts give — a token none of whose experts is held gets zeros, no
-    token is dropped and there is no capacity; what the other experts
-    would add is their chips' to compute.  Output 1 (no gradient) is four
-    float32 counts of this call: token-expert pairs routed, those that
-    landed on held experts, the fullest held expert's pairs, the mean over
-    held experts.
+    experts give — a token none of whose experts is held gets zeros; what
+    the other experts would add is their chips' to compute.  Output 1 (no
+    gradient) is six float32 counts of this call: token-expert pairs
+    routed, those that landed on held experts, the fullest held expert's
+    pairs, the mean over held experts, 1, and 1 if the blocked path ran
+    and one block held all the pairs that landed (``n <= C``).
 
     Pairs are sorted by expert, so each held expert's tokens are one
-    contiguous group of rows and the expert products are two
-    ``lax.ragged_dot`` calls over those groups; rows past the last group
-    (pairs of absent experts) are never multiplied."""
+    contiguous group of rows, the held experts' pairs are the sorted
+    order's first ``n`` and the expert products are two ``lax.ragged_dot``
+    calls over those groups.  Two paths compute the same sum and neither
+    drops a pair; the shapes alone choose between them.  Where half or
+    more of the experts are held (or there are under ~1,024 pairs) the
+    full path works at tokens x ``top_k`` rows, whatever ``n``.  Where
+    fewer are held, the blocked path works on ``C`` sorted pairs at a
+    time (``_capacity``: twice a uniform router's share) in a loop whose
+    trip count ``ceil(n / C)`` is counted on the device: one block on a
+    step whose held pairs fit ``C``, more on a step that overflows it —
+    that step is slower, never different."""
+    return _routed(data, router_weight, gate_up_weight, down_weight,
+                   int(top_k), int(expert_offset), norm_topk_prob,
+                   _capacity(data.shape[0] * int(top_k),
+                             gate_up_weight.shape[0],
+                             router_weight.shape[0]))
+
+
+def _routed(data, router_weight, gate_up_weight, down_weight, k, offset,
+            norm_topk_prob, capacity):
+    """``routed_experts`` with the blocked path's rows a block,
+    ``capacity``, as an argument (tokens * k: the full path)."""
     f32 = jnp.float32
     tokens = data.shape[0]
     held = gate_up_weight.shape[0]
-    k = int(top_k)
     logits = jnp.dot(data, router_weight.T, preferred_element_type=f32)
     weight, expert = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
     if norm_topk_prob:
         weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
-    local = expert.reshape(-1) - int(expert_offset)      # (tokens * k,)
+    local = expert.reshape(-1) - offset                  # (tokens * k,)
     here = (local >= 0) & (local < held)
     # held pairs first, grouped by expert; absent ones behind them
     order = jnp.argsort(jnp.where(here, local, held), stable=True)
-    inverse = jnp.argsort(order)
     sizes = jnp.sum(jax.nn.one_hot(jnp.where(here, local, held), held,
                                    dtype=jnp.int32), axis=0)
-    valid = (jnp.arange(tokens * k) < jnp.sum(sizes))[:, None]
-    rows = _rows_by_pair(data, order, inverse, k)        # (tokens * k, H)
-    gate, up = jnp.split(_grouped_dot(rows, gate_up_weight, sizes, valid),
-                         2, axis=-1)
-    out = _grouped_dot(jax.nn.silu(gate) * up, down_weight, sizes, valid)
-    # back to token-major pairs, weighted, summed over a token's k
-    out = _permuted(out, inverse, order).reshape(tokens, k, -1)
-    scale = jnp.where(here.reshape(tokens, k), weight, 0.0)[..., None]
-    out = jnp.sum(out.astype(f32) * scale, axis=1).astype(data.dtype)
+    if capacity < tokens * k:
+        out = _experts_blocked(data, weight, gate_up_weight, down_weight,
+                               order, sizes, capacity)
+        compact = (jnp.sum(sizes) <= capacity).astype(f32)
+    else:
+        out = _experts_full(data, weight, gate_up_weight, down_weight,
+                            order, sizes, here)
+        compact = jnp.zeros((), f32)
     load = sizes.astype(f32)
     stats = lax.stop_gradient(jnp.stack([
         jnp.asarray(tokens * k, f32), jnp.sum(load), jnp.max(load),
-        jnp.mean(load)]))
+        jnp.mean(load), jnp.ones((), f32), compact]))
     return out, stats
 
 
@@ -719,14 +868,14 @@ def routed_experts(data, router_weight, gate_up_weight, down_weight,
           variable_inputs=True,
           input_names=lambda attrs: tuple(
               "arg%d" % i for i in range(int(attrs.get("num_args", 1)))),
-          infer_shape=lambda attrs, in_shapes: (list(in_shapes), [(4,)], []))
+          infer_shape=lambda attrs, in_shapes: (list(in_shapes), [(6,)], []))
 def routed_experts_stats(*stats, num_args=1):
     """One step's counts over several routed-expert layers, from each
     layer's ``stats`` output: pairs routed and pairs that landed here
     summed over the layers; the fullest held expert's pairs and the mean
     over held experts, both of the layer whose fullest expert is
-    fullest."""
-    s = jnp.stack(stats)                                 # (layers, 4)
+    fullest; the calls and those that needed one block at most, summed."""
+    s = jnp.stack(stats)                                 # (layers, 6)
     worst = jnp.argmax(s[:, 2])
     return jnp.stack([jnp.sum(s[:, 0]), jnp.sum(s[:, 1]), s[worst, 2],
-                      s[worst, 3]])
+                      s[worst, 3], jnp.sum(s[:, 4]), jnp.sum(s[:, 5])])

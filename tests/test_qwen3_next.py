@@ -286,8 +286,9 @@ def test_routed_experts_match_a_dense_loop_with_absent_and_idle_experts():
     np.testing.assert_array_equal(np.asarray(out[0]), 0.0)
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-6)
     load = [(chosen == e).sum() for e in range(4, 8)]
+    # 160 pairs are fewer than a block's 1,024: the full path
     np.testing.assert_allclose(stats, [160, sum(load), max(load),
-                                       np.mean(load)])
+                                       np.mean(load), 1, 0])
 
     def grads(fn):
         return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
@@ -309,14 +310,170 @@ def test_routed_experts_symbol_has_two_outputs_and_a_combiner():
                                 "routedexperts0_stats"] or \
         len(r.list_outputs()) == 2
     _, out, _ = r.infer_shape(x=(10, 8))
-    assert out == [(10, 8), (4,)]
+    assert out == [(10, 8), (6,)]
     both = mx.sym.RoutedExpertsStats(r[1], r[1])
     loc = {"x": _n((10, 8), 0), "wr": _n((6, 8), 1), "wgu": _n((2, 8, 8), 2),
            "wd": _n((2, 4, 8), 3)}
     one = check_symbolic_forward(r[1], loc, [], rtol=1e-6)[0]
     two = check_symbolic_forward(both, loc, [], rtol=1e-6)[0]
-    np.testing.assert_allclose(two, [2 * one[0], 2 * one[1], one[2], one[3]])
-    assert one[0] == 20
+    np.testing.assert_allclose(two, [2 * one[0], 2 * one[1], one[2], one[3],
+                                     2, 0])
+    assert one[0] == 20 and one[4] == 1
+
+
+# -- the blocked path: the rows of the held experts, a block at a time --------
+
+def _blocked_inputs(case):
+    """40 tokens, 16 experts, top-4, experts 4 and 5 held: 160 pairs of
+    which about 20 land here.  ``idle``: expert 5 gets no token; ``none``:
+    neither held expert gets one."""
+    wr = _n((16, 16), 61)
+    x = np.abs(_n((40, 16), 60)) + 0.1
+    if case in ("idle", "none"):
+        wr[5] = -1.0                     # x > 0: the lowest logit of all
+    if case == "none":
+        wr[4] = -1.0
+    return tuple(jnp.asarray(a) for a in (
+        x, wr, _n((2, 16, 16), 62, 0.3), _n((2, 8, 16), 63, 0.3)))
+
+
+def _capacity_of(case, landed):
+    """A block's rows for a case, and whether one block holds the step."""
+    return {"under": (64, True), "exact": (landed, True),
+            "overflow": (landed - 1, False), "blocks": (8, False),
+            "idle": (64, True), "none": (8, True)}[case]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["under", "exact", "overflow", "blocks",
+                                  "idle", "none"])
+def test_blocked_path_equals_the_full_path_and_the_dense_loop(case, dtype):
+    """Output and the four gradients of ``_routed`` with a block the
+    step's landed pairs fit, fill exactly, overflow by one (a second block
+    of one pair) or fill three times over (``blocks``; no pair landed: no
+    block runs): equal to the full path and to the dense loop; the
+    gradients under ``jax.jit`` and ``jax.checkpoint``, as a
+    ``mirror_stage`` runs the op."""
+    from mxnet_tpu.ops.contrib import _routed, routed_experts_stats
+    args = tuple(a.astype(dtype) for a in _blocked_inputs(case))
+    wide = tuple(a.astype("float32") for a in args)
+
+    def routed(capacity):
+        return lambda *a: _routed(*a, 4, 4, True, capacity)
+    full, counts = routed(160)(*args)
+    landed = int(counts[1])
+    capacity, compact = _capacity_of(case, landed)
+    chosen = np.asarray(_dense_experts(*wide, 4, 4)[1])
+    assert landed == ((chosen == 4) | (chosen == 5)).sum()
+    if case == "none":
+        assert landed == 0
+    elif case == "idle":
+        assert landed > 0 and not (chosen == 5).any()
+    else:
+        assert 16 < landed < 64 and (chosen == 4).any() \
+            and (chosen == 5).any()
+
+    out, stats = routed(capacity)(*args)
+    assert out.dtype == args[0].dtype
+    np.testing.assert_allclose(stats, [160, landed, counts[2], counts[3], 1,
+                                       float(compact)])
+    np.testing.assert_array_equal(counts[4:], [1, 0])    # the full path
+    both = routed_experts_stats(stats, counts, num_args=2)
+    np.testing.assert_allclose(both, [320, 2 * landed, counts[2], counts[3],
+                                      2, float(compact)])
+    exact = dict(rtol=1e-5, atol=1e-6) if dtype == "float32" else \
+        dict(rtol=2e-2, atol=2e-3)       # a sum of bf16 rows, reordered
+    loose = dict(rtol=1e-4, atol=1e-6) if dtype == "float32" else \
+        dict(rtol=5e-2, atol=2e-2)
+    f = lambda a: np.asarray(a, np.float32)
+    np.testing.assert_allclose(f(out), f(full), **exact)
+    np.testing.assert_allclose(f(out), f(_dense_experts(*wide, 4, 4)[0]),
+                               **loose)
+    if case == "none":
+        np.testing.assert_array_equal(f(out), 0.0)
+
+    def grads(fn, at, staged=False):
+        fn = jax.checkpoint(fn) if staged else fn
+        g = jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a).astype("float32"))),
+                     argnums=(0, 1, 2, 3))
+        return (jax.jit(g) if staged else g)(*at)
+    mine = grads(lambda *a: routed(capacity)(*a)[0], args, staged=True)
+    alone = grads(lambda *a: routed(160)(*a)[0], args)
+    dense = grads(lambda *a: _dense_experts(*a, 4, 4)[0], wide)
+    for name, a, b, c in zip(("data", "router", "gate_up", "down"), mine,
+                             alone, dense):
+        assert a.dtype == b.dtype and a.shape == c.shape, name
+        np.testing.assert_allclose(f(a), f(b), err_msg=name, **exact)
+        np.testing.assert_allclose(f(a), f(c), err_msg=name, **loose)
+        if case == "none":
+            np.testing.assert_array_equal(f(a), 0.0, err_msg=name)
+
+
+def _routed_grad_jaxpr(tokens, k, held, experts, hidden=32, width=16):
+    from mxnet_tpu.ops.contrib import routed_experts
+    shapes = ((tokens, hidden), (experts, hidden), (held, hidden, 2 * width),
+              (held, width, hidden))
+
+    def loss(*a):
+        out = jax.checkpoint(lambda *b: routed_experts(*b, top_k=k)[0])(*a)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+    return jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3)))(
+        *(jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes)).jaxpr
+
+
+def _eqns(jaxpr):
+    from mxnet_tpu.analysis.graph_lint import iter_eqns
+    return iter_eqns(jaxpr)
+
+
+def test_blocked_path_holds_nothing_as_wide_as_all_the_pairs():
+    """1,024 tokens, top-10, 4 of 64 experts held: forward and backward
+    are one loop each, no conditional, and in the whole op nothing
+    floating has tokens * k rows but the flat (tokens, k) router weights,
+    nor more elements than a block's rows of the widest activation."""
+    from mxnet_tpu.ops.contrib import _capacity
+    tokens, k, held, experts, hidden, width = 1024, 10, 4, 64, 32, 16
+    pairs = tokens * k
+    capacity = _capacity(pairs, held, experts)
+    assert capacity == 2048
+    eqns = list(_eqns(_routed_grad_jaxpr(tokens, k, held, experts)))
+    names = [e.primitive.name for e in eqns]
+    assert names.count("while") == 2 and "cond" not in names
+    most = capacity * max(hidden, 2 * width)
+    seen = 0
+    for eqn in eqns:
+        for var in list(eqn.invars) + list(eqn.outvars):
+            aval = var.aval
+            if not jnp.issubdtype(aval.dtype, jnp.floating):
+                continue
+            seen += 1
+            rows = aval.shape[0] if aval.shape else 1
+            if rows == pairs or rows == tokens and aval.shape[1:2] == (k,):
+                assert aval.size == pairs, (eqn.primitive.name, aval)
+            assert aval.size <= most, (eqn.primitive.name, aval)
+    assert seen > 100
+    # the full path is what carries such rows
+    full = _eqns(_routed_grad_jaxpr(tokens, k, 32, experts))
+    assert any(getattr(v.aval, "shape", ())[:1] == (pairs,)
+               and len(v.aval.shape) == 2 and v.aval.shape[1] >= hidden
+               for e in full for v in e.outvars)
+
+
+@pytest.mark.parametrize("held", [32, 48, 64])
+def test_half_or_more_of_the_experts_held_builds_no_loop(held):
+    names = {e.primitive.name
+             for e in _eqns(_routed_grad_jaxpr(1024, 10, held, 64))}
+    assert not names & {"cond", "while"} and "ragged_dot_general" in names
+
+
+@pytest.mark.parametrize("pairs,held,experts,want", [
+    (163840, 32, 512, 20480), (10240, 4, 64, 2048), (10240, 32, 64, 10240),
+    (160, 2, 16, 160), (2048, 2, 16, 1024), (4096, 1, 512, 1024),
+    (163840, 33, 512, 21504)])
+def test_capacity_is_twice_the_uniform_share_in_whole_1024s(pairs, held,
+                                                            experts, want):
+    from mxnet_tpu.ops.contrib import _capacity
+    assert _capacity(pairs, held, experts) == want
 
 
 def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
@@ -377,7 +534,7 @@ def test_symbol_has_the_reference_leaves_and_named_stages():
             if a not in ("data", "softmax_label")]
     assert sorted(args) == sorted(params)
     shapes, out, _ = sym.infer_shape(data=(2, 80), softmax_label=(2, 80))
-    assert out == [(160, 300), (4,)]
+    assert out == [(160, 300), (6,)]
     want = ref.shapes(cfg)[0]
     for name, shape in zip(sym.list_arguments(), shapes):
         if name in want:
@@ -388,17 +545,19 @@ def test_symbol_has_the_reference_leaves_and_named_stages():
                       "l0_moe", "l1_moe", "l2_moe", "l3_moe"}
 
 
-def test_model_matches_the_reference_through_fit():
+@pytest.mark.parametrize("seq_len,held", [(80, 4), (256, 2)])
+def test_model_matches_the_reference_through_fit(seq_len, held):
     """Loss of each of three steps, the first gradient as the optimizer
     got it and the change after three steps, float32, through
-    ``SPMDModule.fit`` from int32 rows."""
+    ``SPMDModule.fit`` from int32 rows.  Rows of 256 tokens with 2 of 16
+    experts held run the expert layers' blocked path."""
     from benchmark.reference import common, qwen3_next as ref
     from mxnet_tpu.parallel import SPMDModule, default_mesh
-    sym, cfg, params = _toy_model()
+    sym, cfg, params = _toy_model(seq_len=seq_len, held=held)
     opt = {"learning_rate": 0.01, "momentum": 0.9, "wd": 0.0}
     rs = RS(1)
-    data = rs.randint(0, 300, (6, 80)).astype(np.int32)
-    label = rs.randint(0, 300, (6, 80)).astype(np.int32)
+    data = rs.randint(0, 300, (6, seq_len)).astype(np.int32)
+    label = rs.randint(0, 300, (6, seq_len)).astype(np.int32)
     mod = SPMDModule(sym, mesh=default_mesh(devices=jax.devices()[:1]))
     seen = {"loss": []}
 
@@ -406,7 +565,7 @@ def test_model_matches_the_reference_through_fit():
         trainer = mod._deferred_metric_trainer()
         prob = np.asarray(trainer.outputs[0].asnumpy(), np.float64)
         lab = label[2 * param.nbatch:2 * param.nbatch + 2].T.reshape(-1)
-        seen["loss"].append(-np.mean(np.log(prob[np.arange(160), lab])))
+        seen["loss"].append(-np.mean(np.log(prob[np.arange(2 * seq_len), lab])))
         if param.nbatch == 0:
             seen["grad1"] = {k: np.asarray(v[0]) / -0.01
                              for k, v in trainer.opt_state.items()}
@@ -467,20 +626,27 @@ def test_staged_remat_gives_the_same_gradients_and_names_the_stages():
 
 # -- counters the graph computes, settled one step late -----------------------
 
-def test_step_counters_leave_the_outputs_and_settle_one_step_late():
+@pytest.mark.parametrize("seq_len,held,compact", [(16, 4, 0), (256, 2, 4)])
+def test_step_counters_leave_the_outputs_and_settle_one_step_late(
+        seq_len, held, compact):
+    """Rows of 16 tokens: 128 pairs a layer, the full path.  Rows of 256
+    with 2 of 16 experts held: 2,048 pairs in blocks of 1,024 and ~256
+    landed, so each of the four layers' calls is one block."""
     from mxnet_tpu.parallel import SPMDTrainer
-    sym, cfg, params = _toy_model(seq_len=16)
+    sym, cfg, params = _toy_model(seq_len=seq_len, held=held)
     tr = SPMDTrainer(sym, "sgd", {"learning_rate": 0.01,
                                   "rescale_grad": 0.5})
-    tr.bind([("data", (2, 16))], [("softmax_label", (2, 16))])
+    tr.bind([("data", (2, seq_len))], [("softmax_label", (2, seq_len))])
     tr.init_params(None, {k: mx.nd.NDArray._from_jax(v + 0)
                           for k, v in params.items()}, {})
-    assert tr.out_shapes == [(32, 300)]
+    assert tr.out_shapes == [(2 * seq_len, 300)]
     rs = RS(3)
-    batch = (rs.randint(0, 300, (2, 16)).astype(np.int32),
-             rs.randint(0, 300, (2, 16)).astype(np.int32))
+    batch = (rs.randint(0, 300, (2, seq_len)).astype(np.int32),
+             rs.randint(0, 300, (2, seq_len)).astype(np.int32))
     before = profiler.counters().get("moe.assignments", 0)
-    pairs = 2 * 16 * 4 * 4                       # tokens x top-k x layers
+    calls = profiler.counters().get("moe.calls", 0)
+    compacts = profiler.counters().get("moe.compact_calls", 0)
+    pairs = 2 * seq_len * 4 * 4                  # tokens x top-k x layers
     assert len(tr.step(*batch)) == 1
     assert profiler.counters().get("moe.assignments", 0) == before
     tr.step(*batch)
@@ -492,6 +658,10 @@ def test_step_counters_leave_the_outputs_and_settle_one_step_late():
     assert c["moe.load_max"] >= c["moe.load_mean"] > 0
     records = [r for r in profiler.spans() if r["name"] == "step.counters"]
     assert records[-1]["ids"]["moe.assignments"] == pairs
+    assert records[-1]["ids"]["moe.calls"] == 4
+    assert records[-1]["ids"]["moe.compact_calls"] == compact
+    assert c["moe.calls"] - calls == 8 and \
+        c["moe.compact_calls"] - compacts == 2 * compact
     assert len(tr.eval_step(*batch)) == 1
     assert c["step.overlapped"] >= 1
     tr.close()
