@@ -44,10 +44,10 @@ import numpy as np
 MODEL = "resnet-50"
 NUM_CLASSES = 1000          # the head stays 1000 wide ...
 LABEL_CLASSES = 8           # ... labels come from a few classes (a divisor
-#                             of 16: bench._make_dataset), so that thirty
-#                             steps can show the optimizer acting
+#                             of 16: make_dataset), so that thirty steps
+#                             can show the optimizer acting
 IMAGE = 224
-BATCH = 256                 # bench.py compute-large's shape; fits 16 GB
+BATCH = 256                 # fits 16 GB
 STEPS_PER_EPOCH = 8
 EPOCHS = 4                  # 32 steps
 SERVE_BUCKETS = (1, 32)
@@ -131,15 +131,16 @@ def peak_bytes():
 def phase_calibration(n=8192, chain=32, peak_tflops=None):
     """Time a dependent chain of ``chain`` (n x n) bf16 matmuls twice: ended
     by ``block_until_ready`` and ended by fetching a scalar that depends on
-    the result.  Both rates must sit inside the chip's peak (``bench.py``'s
-    ``PEAK_TFLOPS`` row for this ``device_kind``; an unknown kind raises)."""
+    the result.  Both rates must sit inside the chip's peak (the row of
+    this ``device_kind`` in ``benchmark/peaks.json``; an unknown kind
+    raises)."""
     import jax
     import jax.numpy as jnp
 
     kind = jax.devices()[0].device_kind
     if peak_tflops is None:
-        from bench import PEAK_TFLOPS
-        peak_tflops = PEAK_TFLOPS[kind]
+        from benchmark.flops import peaks
+        peak_tflops = peaks(kind)["bf16_flops"] / 1e12
     a = jnp.full((n, n), 0.01, jnp.bfloat16)
 
     @jax.jit
@@ -182,6 +183,40 @@ def phase_calibration(n=8192, chain=32, peak_tflops=None):
 # ---------------------------------------------------------------------------
 # training through fit(), fed from RecordIO
 # ---------------------------------------------------------------------------
+
+def make_dataset(n_img, side=256, classes=1000, directory=None):
+    """A seeded RecordIO file of JPEGs with the statistics of photographs
+    (smooth gradients plus low-frequency texture, ~13 KB an image at q90;
+    white noise carries ~4x the entropy and decodes several times slower
+    than any photo).  Image i carries texture i % 16 and label i % classes,
+    so where ``classes`` divides 16 the label can be learnt from the
+    pixels: that is why the smoke may assert that its loss falls, and why
+    it does not take ``benchmark.datagen.make_recordio``, whose labels are
+    drawn from the seed.  ``classes`` must not exceed the head: a label out
+    of range one-hots to a zero row under SoftmaxOutput and the loss
+    diverges.  Written under ``directory`` (default: a fresh temporary
+    one); returns the prefix of the ``.rec`` / ``.idx`` pair."""
+    import cv2
+
+    from mxnet_tpu import recordio
+
+    prefix = os.path.join(
+        directory or tempfile.mkdtemp(prefix="chip_smoke_rec_"), "smoke")
+    rs = np.random.RandomState(0)
+    xs = np.linspace(0, 1, side)
+    rec = recordio.MXIndexedRecordIO(prefix + ".idx", prefix + ".rec", "w")
+    tex_bank = [
+        cv2.GaussianBlur(rs.randn(side, side, 3).astype(np.float32) * 40,
+                         (7, 7), 0) for _ in range(16)]
+    for i in range(n_img):
+        base = (np.outer(xs, np.roll(xs, (i * 37) % side))[..., None]
+                * np.array([255, 180, 120])).astype(np.float32)
+        img = np.clip(base + tex_bank[i % 16], 0, 255).astype(np.uint8)
+        header = recordio.IRHeader(0, float(i % classes), i, 0)
+        rec.write_idx(i, recordio.pack_img(header, img, quality=90))
+    rec.close()
+    return prefix
+
 
 def _device_transform():
     """uint8 NHWC -> normalized bf16 NCHW, on the device."""
@@ -519,9 +554,8 @@ def main():
         log("calibration: %r" % since(snap))
 
         snap = mark()
-        from bench import _make_dataset
-        rec = _make_dataset(STEPS_PER_EPOCH * BATCH, 256, LABEL_CLASSES,
-                            directory=work)
+        rec = make_dataset(STEPS_PER_EPOCH * BATCH, 256, LABEL_CLASSES,
+                           directory=work)
         log("dataset: %d JPEGs in %.1fs"
             % (STEPS_PER_EPOCH * BATCH, since(snap)["wall_s"]))
 

@@ -605,8 +605,7 @@ def repo_registry():
                        (F, "Stats.merged_snapshot")],
             consumers=[(F, "Stats.merged_snapshot"),
                        (R, "FleetRouter.stats_payload"),
-                       (T_SERVE, "*"), (T_FLEET, "*"),
-                       ("bench.py", "*")],
+                       (T_SERVE, "*"), (T_FLEET, "*")],
             # batches.avg_ms is a human gauge next to the machine-read
             # fill_ratio/count fields; p99_recent travels on the
             # router's OWN snapshot only because the one snapshot shape
@@ -634,15 +633,14 @@ def repo_registry():
                         "Autoscaler._pressure_ms"),
                        ("mxnet_tpu/fleet/deploy.py",
                         "RollingSwap._replica_epoch"),
-                       (T_SERVE, "*"), (T_FLEET, "*"),
-                       ("bench.py", "*")],
+                       (T_SERVE, "*"), (T_FLEET, "*")],
             # the watcher deploy block is promote forensics (which
             # model/dir, last outcome, error counters) for operators
             # reading /stats; draining is mirrored machine-readably on
             # /healthz (what the router prober actually uses)
             unread_ok=("avg_ms", "directory", "draining",
-                       "last_outcome", "model", "poll_s", "polls",
-                       "swap_errors", "watching"),
+                       "last_outcome", "last_swap_ms", "model",
+                       "poll_s", "polls", "swap_errors", "watching"),
         ),
         Surface(
             "router-stats",
@@ -658,8 +656,7 @@ def repo_registry():
                        (REG, "Region._fire"),
                        (REG, "Region.stats_payload"),
                        (REG, "Region._replica_epochs"),
-                       (T_FLEET, "*"), (T_CHAOS, "*"),
-                       ("bench.py", "*")],
+                       (T_FLEET, "*"), (T_CHAOS, "*")],
             # the per-replica table and view block are the operator's
             # triage surface (why is this replica slow/evicted/dead);
             # machine consumers key off healthy/epochs/restarts instead.
@@ -668,8 +665,9 @@ def repo_registry():
             # the fleet is to shedding — and WHY it already is
             unread_ok=("age_s", "draining", "est_wait_ms",
                        "forward_errors", "heartbeat_age_s", "inflight",
-                       "last_rc", "probe_retries", "read_errors",
-                       "replicas_total", "pressure_ms", "slo_ms"),
+                       "last_rc", "port", "probe_retries",
+                       "read_errors", "replicas_total", "pressure_ms",
+                       "slo_ms"),
         ),
         Surface(
             "fleet-manifest",

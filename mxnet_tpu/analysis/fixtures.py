@@ -1,11 +1,10 @@
 """The ONE definition of the "standard MLP fused step" fixture.
 
-Three consumers assert the same claim — "the standard MLP step lints
+Two consumers assert the same claim — "the standard MLP step lints
 clean" — and must lint the same program: ``tools/mxlint.py --graph``
-(the CLI gate), ``bench.py``'s ``analyze`` metric (collective
-count/bytes per step), and ``tests/test_analysis.py`` (the tier-1
-regression gate).  A hand-copied fixture drifting in any of them would
-quietly turn one claim into three different ones.
+(the CLI gate) and ``tests/test_analysis.py`` (the tier-1 regression
+gate).  A hand-copied fixture drifting in either would quietly turn one
+claim into two different ones.
 
 Imports are function-local: the analysis package stays stdlib-only at
 import time (the CLI's AST level must run without jax).

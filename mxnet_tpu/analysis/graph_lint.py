@@ -630,8 +630,8 @@ def lint_lowered(lowered, closed_jaxpr=None, compute_dtype=None,
     the callback/dtype rules (pass ``jax.make_jaxpr(fn)(*args)``);
     ``compiled_text`` skips the internal ``lowered.compile()`` when the
     caller already has the executable.  Returns a :class:`Report` whose
-    ``stats["collectives"]`` always carries the audit tally (bench reads
-    it even when nothing flags).
+    ``stats["collectives"]`` always carries the audit tally, even when
+    nothing flags.
     """
     rep = Report(tool="mxlint.graph")
     rep.extend(audit_donation(lowered, min_bytes=min_donate_bytes,

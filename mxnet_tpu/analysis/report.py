@@ -4,8 +4,8 @@ Stdlib-only on purpose: ``tools/mxlint.py`` and the AST level must stay
 importable and fast in contexts where no accelerator runtime exists
 (pre-commit hooks, CI containers without a device plugin).
 
-The JSON report format is a STABLE contract (``REPORT_VERSION``): CI and
-bench diff reports across commits, so findings are emitted in a
+The JSON report format is a STABLE contract (``REPORT_VERSION``): CI
+diffs reports across commits, so findings are emitted in a
 deterministic order and no timing/host-specific data lives inside the
 ``findings`` array.
 """

@@ -219,7 +219,7 @@ def check_call(ret):  # pragma: no cover - API-parity shim
 ENV_TEST_PLATFORM = register_env(
     "MXTPU_TEST_PLATFORM", default="cpu", scope="test",
     doc="Test-suite platform: cpu = 8-device virtual mesh, tpu = real "
-        "chip (read by tests/conftest.py and bench tooling)")
+        "chip (read by tests/conftest.py)")
 # Registered here (not in data_service/) because it is read across
 # modules: image.py routes ImageRecordIter through the data service when
 # it is set, and data_service.service sizes the worker fleet from it.

@@ -73,7 +73,7 @@ class Context(object):
             # tpu/gpu name the default backend's devices: the chips on a
             # TPU host, the host-platform CPU devices where there is none
             # (the test mesh).  Code that must not run off-chip checks
-            # ``jax_device.platform`` itself (chip_smoke.py, bench.py).
+            # ``jax_device.platform`` itself (chip_smoke.py).
             devs = jax.local_devices()
         if self.device_id >= len(devs):
             raise MXNetError(

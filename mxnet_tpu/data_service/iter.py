@@ -34,8 +34,8 @@ class DataServiceIter(DataIter):
     "uploading" it must truly copy (on the CPU backend
     ``jax.device_put`` ALIASES numpy memory; use
     ``jnp.array(view, copy=True)``).  ``ImageRecordIter``'s
-    ``host_batches`` service mode and the decode bench use
-    ``copy=False``; wrapping either flavor in
+    ``host_batches`` service mode uses ``copy=False``; wrapping either
+    flavor in
     ``dataflow.DevicePrefetchIter(stage=trainer)`` is safe — the
     prefetcher snapshots slot-backed batches on its background thread
     and releases the slot before running ahead."""

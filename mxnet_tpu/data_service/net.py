@@ -159,7 +159,7 @@ class BatchServer(object):
     handshake config, and streams published ring slots as frames.
 
     Runs inside ``tools/data_server.py`` on remote hosts, or in-process
-    for loopback tests/benches.  Concurrent connections each get their
+    for loopback tests.  Concurrent connections each get their
     own service (their own worker processes), so one server process can
     feed several consumers — a consumer that disconnects tears its
     service (and decode workers) down.
@@ -260,7 +260,7 @@ class BatchServer(object):
         # it before the (milliseconds-long) crc+send: the decode worker
         # starts the next batch while this thread pushes bytes — a
         # send-while-holding-the-slot serialized ~12% of the pipeline
-        # into dead time (measured on the loopback bench)
+        # into dead time (measured over loopback on a CPU host)
         label_n = svc._bs * svc._lw
         label_bytes = label_n * 4
         data_n = svc._bs * int(np.prod(svc._ring_shape))

@@ -24,15 +24,13 @@ Robustness is part of the design, not a bolt-on:
   as an ``MXNetError`` carrying its stderr tail.
 
 Per-stage counters (ring occupancy, producer/consumer stall, batches
-and respawns per worker) are exposed via :meth:`DataService.stats` and
-the ``bench.py data_service`` mode.
+and respawns per worker) are exposed via :meth:`DataService.stats`.
 
 Slot lifetime contract: with ``copy=False`` the arrays a delivered
 batch holds ALIAS the ring slot; the slot is recycled when the batch's
 ``release()`` is called, or automatically when the NEXT batch is
 pulled — so zero-copy views are for STRICTLY SERIAL consumers that
-finish with batch N before pulling N+1 (the decode bench, a plain
-training loop).  Anything that runs ahead of its consumer must
+finish with batch N before pulling N+1 (a plain training loop).  Anything that runs ahead of its consumer must
 snapshot before the next pull: ``dataflow.DevicePrefetchIter`` does
 exactly that (copies on its background thread, then releases), and
 ``DataServiceIter``'s default ``copy=True`` hands out private arrays.

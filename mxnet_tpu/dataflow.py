@@ -77,7 +77,7 @@ class DevicePrefetchIter(DataIter):
     depth : int
         Number of batches staged ahead (default 2).  ``depth=0`` stages
         synchronously on the consuming thread — same batches, no
-        overlap — which is the bench's baseline mode.
+        overlap.
 
     Semantics: batches come out byte-identical and in order vs the
     source; a source error (after the retry ladder is exhausted) is

@@ -25,8 +25,7 @@ daemons into one serving system:
   fleet-level p50/p99/shed aggregation on ``/stats``.
 - :mod:`.warm` — the AOT warm store: pre-compile every (model, bucket)
   forward into ``JAX_COMPILATION_CACHE_DIR`` so a fresh or respawned replica
-  warms from disk instead of from XLA (``fleet_warm_start_x`` in
-  ``bench.py fleet`` measures the win; >= 3x is the bar).
+  warms from disk instead of from XLA.
 - :mod:`.view` — the shared fleet view that shards the front end: ONE
   controller-side prober publishes manifest + health + the fenced set
   into an atomic JSON snapshot with a generation counter; N
@@ -40,8 +39,8 @@ daemons into one serving system:
   path (never below the capacity floor).
 
 ``tools/fleet.py`` is the CLI (``serve`` + ``warmup`` +
-``router-worker`` subcommands); ``bench.py fleet`` / ``bench.py
-overdrive`` are the load generators and self-proof.  Every
+``router-worker`` subcommands); ``tests/test_chaos.py``'s fleet drills
+drive the real daemons.  Every
 ``MXTPU_FLEET_*`` knob is registered EAGERLY at its owner module
 below (the PR-7 lazy-registration lesson); this package never imports
 jax — the router and controller are pure-host processes by design.

@@ -211,6 +211,10 @@ class FleetRouter(object):
         self._health_thread = None
         self._stop_dump = threading.Event()
         self._dump_thread = None
+        #: one dump at a time: the dump loop and a caller's dump (the
+        #: drain's last one) share a temp path, and a counter export
+        #: taken before the lock must never land after a newer one
+        self._dump_lock = threading.Lock()
         #: serve/drain handshake: a drain that arrives BEFORE the
         #: accept loop starts marks _aborted so serve_forever returns
         #: immediately instead of serving a drained fleet forever;
@@ -1075,14 +1079,15 @@ class FleetRouter(object):
         if self.worker_id is None or self.run_dir is None:
             return None
         from ..resilience import atomic_write
-        doc = {"worker": self.worker_id, "pid": os.getpid(),
-               "updated_at": time.time(),
-               "router": self.stats.export(),
-               "generation": self._view.generation
-               if self._view is not None else None}
         path = worker_stats_path(self.run_dir, self.worker_id)
-        atomic_write(path, json.dumps(doc).encode("utf-8"),
-                     fault_point="worker_stats_dump")
+        with self._dump_lock:
+            doc = {"worker": self.worker_id, "pid": os.getpid(),
+                   "updated_at": time.time(),
+                   "router": self.stats.export(),
+                   "generation": self._view.generation
+                   if self._view is not None else None}
+            atomic_write(path, json.dumps(doc).encode("utf-8"),
+                         fault_point="worker_stats_dump")
         return path
 
     def healthz_payload(self):

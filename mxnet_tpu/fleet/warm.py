@@ -18,9 +18,6 @@ The store is built by the same binary that serves — ONE
 manifest — so the stored programs are exactly the forwards a replica
 runs (same eval graph, same platform, same shapes; bit-parity between
 the AOT and Predictor paths is pinned in tests/test_serving.py).
-``bench.py fleet`` measures the effect as ``fleet_warm_start_x``
-(cold-compile vs from-store bring-up; the >= 3x acceptance bar) rather
-than assuming it.
 """
 from __future__ import annotations
 

@@ -1226,8 +1226,8 @@ class ImageRecordIter(mxio.DataIter):
         if servers or use_local:
             # an EXPLICIT data_service=True sizes the fleet from the
             # call's preprocess_threads; the env sizes only env-routed
-            # iterators (it must not silently override a call site —
-            # the bench's scaling sweep depends on this).  On the
+            # iterators (it must not silently override a call site;
+            # tests/test_data_service.py pins the precedence).  On the
             # network tier preprocess_threads is the per-SERVER decode
             # worker count.
             workers = env_workers if (use_local and env_routed) \
@@ -1426,9 +1426,9 @@ class ImageRecordIter(mxio.DataIter):
                 num_parts=num_parts, num_workers=workers,
                 dtype=svc_dtype, layout=layout, aug=svc_aug,
                 fast_dct=fast_dct)
-        # copy=False: the host_batches contract (views valid until the
-        # next pull) matches the bench's ephemeral reads, and the device
-        # path makes its own guaranteed copy in _next_service
+        # copy=False: host_batches hands out views valid until the next
+        # pull, and the device path makes its own guaranteed copy in
+        # _next_service
         self._service_iter = DataServiceIter(
             self._service, data_name=data_name, label_name=label_name,
             copy=False)

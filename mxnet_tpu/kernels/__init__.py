@@ -27,9 +27,7 @@ Routing is per-kernel via ``MXTPU_FUSED_KERNELS`` (registered in
 exact pre-fusion graphs, a comma list enables individual kernels.  The
 env is consulted at trace/bind time (symbol build, executor bind, jit
 trace), so toggling it affects the NEXT graph built, never a compiled
-program.  ``bench.py roofline`` times each kernel fused-vs-unfused and
-against a bytes/FLOPs roofline estimate so every kernel proves its win
-in the artifact (docs/how_to/kernels.md).
+program (docs/how_to/kernels.md).
 
 Kernel catalog (``KNOWN_KERNELS``):
 
@@ -75,7 +73,7 @@ from ..base import ENV_FUSED_KERNELS, get_env, register_env
 __all__ = ["KNOWN_KERNELS", "fused_enabled", "enabled_kernels",
            "by_platform", "auto_partitioned", "compiled_kernels",
            "ENV_FLASH_BLOCK", "bn_act",
-           "lstm_cell", "flash_attention", "roofline", "augment",
+           "lstm_cell", "flash_attention", "augment",
            "concat_fuse", "pool_act", "eltwise_chain"]
 
 _LOG = logging.getLogger(__name__)
@@ -186,7 +184,6 @@ def compiled_kernels(hlo_text):
     return counts
 
 
-from . import roofline            # noqa: E402  (stdlib-light, analytic)
 from . import bn_act              # noqa: E402
 from . import lstm_cell           # noqa: E402
 from . import flash_attention     # noqa: E402

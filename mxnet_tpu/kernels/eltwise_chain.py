@@ -3,8 +3,7 @@
 A run of elementwise ops at dispatch granularity is one memory
 round-trip PER OP: each stage writes its full tensor and the next reads
 it back.  Fused into one region the chain is one read and one write —
-the canonical memory-bound fusion (``bench.py roofline``,
-``roofline_eltwise_chain_*``).  Under the whole-graph jit the composed
+the canonical memory-bound fusion.  Under the whole-graph jit the composed
 function traces the IDENTICAL op sequence, so the compiled program —
 and therefore forward AND gradient values — are bit-identical to the
 unfused plan; the win is real on the eager paths (no-jit graphs,

@@ -39,8 +39,8 @@ __all__ = ["make_act_then_maxpool", "make_pool_then_act",
            "make_pool_opt", "pooling_opt", "POOL_SLICE_MAX_SPATIAL"]
 
 #: input spatial extent (H*W) above which the slice lowering loses to
-#: reduce_window (measured on the bench host: 48² wins 2.5x, 112²
-#: loses) — bigger maps fall back
+#: reduce_window (measured on a CPU host, not on the chip: 48² wins
+#: 2.5x, 112² loses) — bigger maps fall back
 POOL_SLICE_MAX_SPATIAL = 3200
 
 

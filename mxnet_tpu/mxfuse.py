@@ -602,7 +602,7 @@ def pass_eltwise_chain(view):
     as extra refs.  The composed function applies the identical op
     sequence, so the whole-graph jit program is bit-identical — the win
     is dispatch count on the eager/no-jit paths and one compiled region
-    instead of N at dispatch granularity (bench.py roofline)."""
+    instead of N at dispatch granularity."""
     from .kernels import eltwise_chain as EC
 
     def chainable(node):

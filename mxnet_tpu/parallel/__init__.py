@@ -16,7 +16,8 @@ Components:
   gather groups) and the serializable ShardingPlan artifact checkpoints
   persist for elastic world-size resume
 - trainer.py: SPMDTrainer — fused fwd+bwd+optimizer-update step, sharded
-  over the mesh (the kvstore='tpu' fast path and the bench path)
+  over the mesh (the kvstore='tpu' fast path; what every benchmark
+  cell trains through)
 - spmd_module.py: SPMDModule — Module-API adapter over SPMDTrainer
 - ring_attention.py: ring attention over the 'sp' axis (sequence/context
   parallelism — capability beyond the reference, SURVEY §5.7)

@@ -1788,8 +1788,8 @@ class SPMDTrainer(object):
         (``min_donate_bytes=0`` — in THIS step's signature every carry
         should be donated regardless of size), no host callbacks, the
         collective audit (``report.stats['collectives']`` carries
-        count+bytes even when nothing flags — bench.py's ``analyze``
-        metric reads it), and dtype drift under ``compute_dtype``.
+        count+bytes even when nothing flags), and dtype drift under
+        ``compute_dtype``.
         Traces and compiles the step once; with a warm persistent
         compile cache (JAX_COMPILATION_CACHE_DIR) the XLA work is reused."""
         args = self._example_args(*batch_arrays)
@@ -1797,8 +1797,8 @@ class SPMDTrainer(object):
 
     def _example_args(self, *batch_arrays):
         """The fully assembled argument tuple ``_step_fn`` would see for
-        one batch — what ``analyze`` lints and what ``bench.py zero3``
-        lowers for ``memory_analysis`` without dispatching a step."""
+        one batch — what ``analyze`` lints, and what a caller lowers for
+        ``memory_analysis`` without dispatching a step."""
         from .. import random as _random
         if self._step_fn is None or self.params is None:
             raise MXNetError(

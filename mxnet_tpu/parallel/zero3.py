@@ -6,7 +6,8 @@ step all-gathers each PARAMETER GROUP on demand inside the jitted
 program, the backward RE-GATHERS instead of keeping the replicated
 copies alive between the passes, and gradients leave the backward as
 reduce-scatter.  Nothing replicated persists between steps — per-device
-parameter residency is ~1/world (``bench.py zero3`` proves it).
+parameter residency is ~1/world
+(``tests/test_parallel.py::test_zero3_param_residency_is_one_over_world``).
 
 Two tiers, the kernels-package discipline (Pallas/lax):
 

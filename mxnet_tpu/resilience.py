@@ -1285,8 +1285,7 @@ class CheckpointManager(object):
         self.keep_last = None if keep_last is None else max(1, int(keep_last))
         self._writer = None
         #: {"peak_blob_bytes", "total_blob_bytes", ...} of the most
-        #: recent save_sharded on this manager (bench.py ckpt mode reads
-        #: it for ckpt_peak_host_frac), or None
+        #: recent save_sharded on this manager, or None
         self.last_save_stats = None
         # every rank may write (replica shards), so every rank needs the
         # directory — on per-host disks each rank creates its own
@@ -1574,9 +1573,9 @@ class CheckpointManager(object):
         ``shard_payloads(k)`` -> the serialized bytes of shard ``k``
         (or None when this rank does not hold it).  It is called one
         shard at a time and each blob is released before the next is
-        built, so peak host bytes stay O(P/world) — the property
-        ``bench.py ckpt`` gates as ``ckpt_peak_host_frac``
-        (:attr:`last_save_stats` records the peaks).
+        built, so peak host bytes stay O(P/world)
+        (:attr:`last_save_stats` records the peaks;
+        ``tests/test_resilience.py`` asserts one blob's worth).
 
         The manifest entry is format 2: ``shard_set`` lists every
         blob's shard index, size and digest (the same records also land

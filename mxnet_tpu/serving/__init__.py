@@ -17,8 +17,7 @@ a traffic-serving daemon:
   variable-length token streams length-bucketed at the front door, one
   batcher per (model, length) pair, answers trimmed to true length.
 
-``tools/serve.py`` is the CLI daemon; ``bench.py``'s ``serve`` mode is
-the load generator.
+``tools/serve.py`` is the CLI daemon.
 """
 from .batcher import (BucketBatcher, DeadlineExpired, Draining, QueueFull,
                       TenantQuotaExceeded, parse_buckets, pick_bucket,

@@ -8,8 +8,7 @@ store removes THAT too: the fleet's warmup builder compiles each
 (model, bucket) forward ONCE, serializes the compiled executable
 (``jax.experimental.serialize_executable`` — the true AOT artifact:
 no trace, no lower, no compile at load), and a fresh or respawned
-replica ``deserialize_and_load``\\ s it in ~0.1s per program.
-``bench.py fleet`` measures the effect as ``fleet_warm_start_x``.
+replica ``deserialize_and_load``\\ s it.
 
 Artifacts are WEIGHT-FREE: the compiled program takes the parameters as
 call arguments (the pool keeps the single device-resident copy), so a

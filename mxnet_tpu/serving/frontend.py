@@ -911,7 +911,7 @@ class _Handler(BaseHTTPRequestHandler):
 
 
 class ServeClient(object):
-    """Minimal keep-alive client for the daemon (tests, bench, drills).
+    """Minimal keep-alive client for the daemon (tests, drills).
     One instance per thread — ``http.client`` connections are not
     thread-safe."""
 

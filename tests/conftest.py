@@ -43,6 +43,10 @@ if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
 
 import jax  # noqa: E402
 
+# tests/test_benchmark_<name>.py re-export the harness's tests: have their
+# asserts explained like any collected module's
+pytest.register_assert_rewrite("benchmark.tests")
+
 if _PLATFORM == "cpu":
     jax.config.update("jax_platforms", "cpu")
 else:
@@ -79,9 +83,7 @@ def spawn_data_server(tmp_path, n, port=0, extra_env=None):
     """Spawn one real ``tools/data_server.py`` on a loopback port and
     wait for its port file: ``(proc, 'host:port')``.  ONE helper shared
     by the data-service tests and the chaos drills — the spawn/poll
-    protocol must not drift between them.  (bench.py keeps its own
-    standalone copy by design: bench metric subprocesses must not
-    import this pytest/jax-side module.)"""
+    protocol must not drift between them."""
     import subprocess
     import sys
     import time

@@ -831,7 +831,7 @@ def test_mxlint_cli_needs_no_accelerator_runtime(tmp_path):
 
 def test_report_json_is_stable(tmp_path):
     """Two runs over the same tree produce identical reports modulo the
-    top-level timing field — the property bench/CI diffing relies on."""
+    top-level timing field — the property CI diffing relies on."""
     def run(i):
         out = tmp_path / ("r%d.json" % i)
         res = subprocess.run(
@@ -1039,9 +1039,8 @@ from mxnet_tpu.analysis import fixtures as l3fx
 
 
 def _default_scope():
-    """The CLI's zero-carve-out default: package + tools + bench."""
-    return [PKG, os.path.join(REPO, "tools"),
-            os.path.join(REPO, "bench.py")]
+    """The CLI's zero-carve-out default: package + tools."""
+    return [PKG, os.path.join(REPO, "tools")]
 
 
 def test_repo_race_lint_zero_findings():

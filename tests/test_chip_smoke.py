@@ -4,9 +4,9 @@
   import (the same functions ``main()`` runs at ResNet-50's width on the
   chip), and ``main()`` itself refuses a host without a TPU;
 - nothing on that path hides the device: ``rtc.on_tpu`` lets backend
-  errors surface, ``bench.py`` fails on an unknown ``device_kind`` and
-  refuses its chip modes off-chip, ``tools/launch.py`` refuses to start
-  several ranks on one host's accelerators;
+  errors surface, the calibration refuses a ``device_kind`` that
+  ``benchmark/peaks.json`` has no row for, ``tools/launch.py`` refuses to
+  start several ranks on one host's accelerators;
 - the compile cache is placed from outside by ``JAX_COMPILATION_CACHE_DIR``
   and is one fixed in-checkout directory otherwise.
 """
@@ -26,7 +26,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 
 
@@ -46,7 +45,8 @@ def _toy_net(classes=16):
 @pytest.fixture(scope="module")
 def toy(tmp_path_factory):
     work = tmp_path_factory.mktemp("chip_smoke")
-    rec = bench._make_dataset(64, side=40, classes=4, directory=str(work))
+    rec = chip_smoke.make_dataset(64, side=40, classes=4,
+                                  directory=str(work))
     return work, rec, _toy_net()
 
 
@@ -111,7 +111,7 @@ def test_calibration_fails_on_a_rate_above_the_peak():
     assert 0 < ok["block_until_ready_tflops"] < 1e9
     with pytest.raises(RuntimeError, match="exceeds"):
         chip_smoke.phase_calibration(n=128, chain=2, peak_tflops=1e-9)
-    # without a given peak the device_kind must be in bench.PEAK_TFLOPS
+    # without a given peak the device_kind must be in benchmark/peaks.json
     with pytest.raises(KeyError):
         chip_smoke.phase_calibration(n=128, chain=2)
 
@@ -144,21 +144,6 @@ def test_on_tpu_lets_backend_errors_surface(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", broken)
     with pytest.raises(RuntimeError, match="failed to initialize"):
         mx.rtc.on_tpu()
-
-
-def test_bench_unknown_device_kind_and_chip_modes_off_chip():
-    assert jax.devices()[0].device_kind not in bench.PEAK_TFLOPS
-    with pytest.raises(KeyError, match="PEAK_TFLOPS"):
-        bench._roofline(100.0, 1e9)
-    with pytest.raises(SystemExit, match="refusing"):
-        bench._require_tpu()
-    # every chip mode checks before it builds anything
-    res = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                         env=dict(os.environ, JAX_PLATFORMS="cpu",
-                                  BENCH_MODE="compute-large"),
-                         capture_output=True, text=True, timeout=120)
-    assert res.returncode != 0 and "BENCH_PART" not in res.stdout
-    assert "refusing" in res.stderr
 
 
 def test_launch_refuses_several_ranks_on_one_hosts_accelerators():
@@ -201,7 +186,7 @@ def test_only_the_package_root_sets_the_cache_dir():
         for dirpath, _, files in os.walk(os.path.join(REPO, root)):
             hits += [os.path.join(dirpath, f) for f in files
                      if f.endswith(".py")]
-    hits += [os.path.join(REPO, f) for f in ("bench.py", "chip_smoke.py",
+    hits += [os.path.join(REPO, f) for f in ("chip_smoke.py",
                                              "__graft_entry__.py")]
     setters = []
     for path in hits:

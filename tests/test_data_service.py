@@ -413,8 +413,7 @@ def test_env_var_routes_through_service(rec_dataset, monkeypatch):
                                **_kw(path, idx))
     assert it._service is None
     it.close()
-    # an EXPLICIT data_service=True sizes from the call, not the env —
-    # the bench's worker-count sweep depends on this precedence
+    # an EXPLICIT data_service=True sizes from the call, not the env
     it = mx.io.ImageRecordIter(preprocess_threads=3, data_service=True,
                                **_kw(path, idx))
     assert it._service.num_workers == 3

@@ -3,7 +3,7 @@ routing/spill/eviction/idempotency policies against FAKE replicas
 (stdlib HTTP servers — no jax, no daemons), the controller's relaunch
 discipline against dummy children, and the warm-store build against a
 stub serve binary.  The real-daemon composition lives in
-tests/test_chaos.py (SIGKILL drill) and ``bench.py fleet``.
+tests/test_chaos.py (the SIGKILL, gray-failure and rolling-swap drills).
 """
 import json
 import os
@@ -338,7 +338,11 @@ def test_router_dead_replica_retried_once_elsewhere(two_fakes):
     ONCE to a different healthy replica with the same request id — the
     client gets a 200 carrying ``retried: true`` instead of the old
     fail-once 502."""
-    router = _mk_router(two_fakes)
+    # evict_s far past what die() takes (a listener's shutdown waits out
+    # its 0.5 s poll): the router must still BELIEVE the dead replica
+    # healthy, or it answers 503 from the heartbeat age and never
+    # forwards — same in the three tests below
+    router = _mk_router(two_fakes, evict_s=60.0)
     router.probe()
     two_fakes[0].die()              # dies AFTER probing healthy
     status, body, _ = _predict(router, "a")[0]
@@ -357,7 +361,7 @@ def test_router_retry_is_once_then_final_502(two_fakes):
     """The resend happens at most ONCE: with every candidate dead the
     client sees a single 502 with ``retried: true`` (the resend was
     attempted) and replica_errors counts exactly that final failure."""
-    router = _mk_router(two_fakes)
+    router = _mk_router(two_fakes, evict_s=60.0)
     router.probe()
     two_fakes[0].die()
     two_fakes[1].die()
@@ -376,7 +380,7 @@ def test_router_no_resend_target_keeps_fail_once_surface():
     surface remains (one 502, ``retried: false``)."""
     fake = _FakeReplica()
     try:
-        router = _mk_router([fake])
+        router = _mk_router([fake], evict_s=60.0)
         router.probe()
         fake.die()
         status, body, _ = _predict(router, "a")[0]
@@ -427,7 +431,7 @@ def test_router_hedged_path_still_absorbs_dead_replica(
     out — either way the client never sees the 502."""
     monkeypatch.setenv("MXTPU_FLEET_HEDGE_PCT", "95")
     monkeypatch.setenv("MXTPU_FLEET_HEDGE_MIN_MS", "40")
-    router = _mk_router(two_fakes)
+    router = _mk_router(two_fakes, evict_s=60.0)
     router.probe()
     two_fakes[0].die()
     status, body, _ = _predict(router, "a")[0]
@@ -741,6 +745,56 @@ def test_controller_restart_budget_exhausts_to_failed(tmp_path):
         state = json.loads((tmp_path / "state-0.json").read_text())
         # initial + 2 relaunches, then the budget stops the bleeding
         assert state["runs"] == 3
+    finally:
+        ctl.kill()
+
+
+_SERVE_FLAGS_CHILD = """
+import os, signal, sys, time
+port_file = sys.argv[sys.argv.index("--port-file") + 1]
+with open(port_file + ".tmp", "w") as f:
+    f.write("127.0.0.1:1234")
+os.replace(port_file + ".tmp", port_file)
+signal.signal(signal.SIGTERM, lambda sig, frame: sys.exit(0))
+time.sleep(600)
+"""
+
+
+def test_controller_add_replica_then_stop_replica_stays_down(tmp_path):
+    """The autoscaler's two endpoints on real child processes:
+    ``add_replica`` spawns the next free id from the controller's own
+    serve.py command line and supervises it like the rest;
+    ``stop_replica`` retires one through SIGTERM (rc 0), takes its port
+    out of routing, and its death is never answered with a relaunch."""
+    child = tmp_path / "child.py"
+    child.write_text(_SERVE_FLAGS_CHILD)
+    man = FleetManifest.from_flags(["m=/x:1"], ["data=4"], replicas=1)
+    ctl = ReplicaController(man, str(tmp_path / "run"),
+                            serve_py=str(child), backoff=0.05)
+    ctl.start()
+    try:
+        assert ctl.wait_ready(timeout=20) == {0: 1234}
+        rep = ctl.add_replica()
+        assert rep.id == 1
+        assert ctl.wait_ready(timeout=20) == {0: 1234, 1: 1234}
+        pid = rep.proc.pid
+
+        assert ctl.stop_replica(1) == 0
+        # replica 1's supervisor returns; replica 0's keeps waiting
+        _wait(lambda: not ctl._threads[1].is_alive(),
+              msg="the retired replica's supervisor to return")
+        assert ctl._threads[0].is_alive()
+        snap = {r["id"]: r for r in ctl.snapshot()}
+        assert snap[1]["state"] == "scaled_down"
+        assert snap[1]["pid"] == pid and snap[1]["restarts"] == 0
+        assert not os.path.exists(rep.port_file)
+        assert ctl.ports() == {0: 1234}
+        with pytest.raises(MXNetError, match="no replica"):
+            ctl.stop_replica(7)
+
+        assert ctl.drain(timeout=10)[0] == 0
+        with pytest.raises(MXNetError, match="draining"):
+            ctl.add_replica()
     finally:
         ctl.kill()
 
@@ -1305,6 +1359,44 @@ def test_router_workers_share_reuseport_and_merge_stats(tmp_path,
         for w in workers:
             w.drain_and_stop(timeout=5)
         sock.close()
+
+
+def test_worker_stats_dump_from_two_threads_never_tears(tmp_path,
+                                                        two_fakes):
+    """The dump loop and a caller's dump (the drain's last one, a
+    test's) run in one process and share a temp path: no dump may
+    raise, and a reader never meets a torn file."""
+    prober = _mk_router(two_fakes)
+    prober.probe()
+    path = str(tmp_path / "fleet-view.json")
+    FleetViewPublisher(prober, path).publish_once()
+    w = FleetRouter(FleetViewReader(path, refresh_s=0.05),
+                    _mk_manifest(two_fakes), port=0, worker_id=0,
+                    run_dir=str(tmp_path), evict_s=60.0)
+    dump_path = w.dump_worker_stats()
+    stop = threading.Event()
+    failed = []
+
+    def dumper():
+        while not stop.is_set():
+            try:
+                w.dump_worker_stats()
+            except Exception as e:  # noqa: BLE001 — the seam assert
+                failed.append(e)
+                return
+
+    threads = [threading.Thread(target=dumper) for _ in range(2)]
+    for t in threads:
+        t.start()
+    try:
+        for _ in range(2000):
+            with open(dump_path) as f:
+                assert json.load(f)["worker"] == 0
+    finally:
+        stop.set()
+        for t in threads:
+            t.join()
+    assert not failed, failed[:1]
 
 
 class _SupervisedFakes(object):

@@ -28,8 +28,8 @@ import jax.numpy as jnp
 
 import mxnet_tpu as mx
 from mxnet_tpu.kernels import (bn_act as BA, flash_attention as FA,
-                               lstm_cell as LC, roofline as RL,
-                               enabled_kernels, fused_enabled)
+                               lstm_cell as LC, enabled_kernels,
+                               fused_enabled)
 from mxnet_tpu.ops import nn as NN
 
 #: documented Pallas-interpret tolerances per dtype (forward; gradients
@@ -283,22 +283,6 @@ def test_env_routing(monkeypatch):
     assert not fused_enabled("flash_attention")
     monkeypatch.setenv("MXTPU_FUSED_KERNELS", "lstm_cell,bogus_kernel")
     assert enabled_kernels() == frozenset({"lstm_cell"})
-
-
-def test_roofline_workloads_sane():
-    for name, shape in (("bn_act", dict(n=4, c=8, hw=49)),
-                        ("lstm_cell", dict(b=4, h=32)),
-                        ("flash_attention",
-                         dict(b=2, t=64, heads=2, d=16))):
-        w = RL.workload(name, **shape)
-        assert w["flops"] > 0
-        # the unfused composition always moves MORE bytes — that gap is
-        # the fusion win the roofline bench measures
-        assert w["unfused_bytes"] > w["fused_bytes"] > 0
-    assert RL.bound_side(10**12, 1, 10**12, 10**9) == "compute"
-    assert RL.bound_side(1, 10**12, 10**12, 10**9) == "memory"
-    with pytest.raises(KeyError):
-        RL.workload("nope")
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Trainer/Executor lifecycle: deterministic release of device memory and
 compiled programs, so several models can live sequentially in ONE process
-(guards the 12x step-time degradation bench.py documented in r03 when a
-prior trainer's state lingered; reference analog: ~GraphExecutor frees
+(guards the 12x step-time degradation seen in round 3 when a prior
+trainer's state lingered; reference analog: ~GraphExecutor frees
 its memory pool)."""
 import time
 

@@ -558,7 +558,7 @@ def test_plan_fusion_parity_lint_flags_broken_pass(monkeypatch):
 
 def test_trainer_analyze_carries_plan_fusion_stats(monkeypatch):
     """The plan-fusion-parity rule rides every trainer.analyze() —
-    the fixtures path mxlint --graph and bench analyze share."""
+    the fixtures path ``mxlint --graph`` lints."""
     from mxnet_tpu.analysis import fixtures
     monkeypatch.setenv("MXTPU_FUSED_KERNELS", "1")
     trainer = fixtures.standard_mlp_trainer()

@@ -835,16 +835,41 @@ def test_bucket_shape_stats_expose_batching(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# bench serve-mode helpers (unit level; the full mode runs in bench.py)
+# two checkpoints of different shape behind one pool
 # ---------------------------------------------------------------------------
 
-def test_bench_serve_models_save_and_load(tmp_path):
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-        specs = bench._save_serving_models(str(tmp_path))
-    finally:
-        sys.path.remove(REPO)
+def _save_serving_models(tmp):
+    """Write two serving checkpoints — the standard MLP (models/mlp.py)
+    and a small-image ResNet-20 (the cifar branch of models/resnet.py) —
+    and return {name: (prefix, epoch, sample_shape)}."""
+    from mxnet_tpu import models
+    from mxnet_tpu.model import save_checkpoint
+
+    rs = np.random.RandomState(7)
+    out = {}
+    for name, sym, sample in (
+            ("mlp", models.get_symbol("mlp", num_classes=10), (784,)),
+            ("resnet", models.get_symbol("resnet", num_classes=10,
+                                         num_layers=20,
+                                         image_shape=(3, 32, 32)),
+             (3, 32, 32))):
+        arg_shapes, _, aux_shapes = sym.infer_shape(data=(1,) + sample)
+        args = {n: mx.nd.array(rs.uniform(-0.1, 0.1, s).astype("f"))
+                for n, s in zip(sym.list_arguments(), arg_shapes)
+                if n not in ("data", "softmax_label")}
+        # BN moving stats: mean 0, var 1 — a forward through random
+        # weights stays finite
+        auxs = {n: mx.nd.array((np.ones(s) if n.endswith("var")
+                                else np.zeros(s)).astype("f"))
+                for n, s in zip(sym.list_auxiliary_states(), aux_shapes)}
+        prefix = os.path.join(tmp, name)
+        save_checkpoint(prefix, 1, sym, args, auxs, blocking=True)
+        out[name] = (prefix, 1, sample)
+    return out
+
+
+def test_serving_models_save_and_load(tmp_path):
+    specs = _save_serving_models(str(tmp_path))
     assert set(specs) == {"mlp", "resnet"}
     pool = ModelPool()
     for name, (prefix, epoch, sample) in specs.items():

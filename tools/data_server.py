@@ -69,7 +69,7 @@ def main(argv=None):
                         help="TCP port (0 = ephemeral; see --port-file)")
     parser.add_argument("--port-file", default=None,
                         help="write 'host:port' here once listening "
-                             "(benches/tests discover ephemeral ports)")
+                             "(tests discover ephemeral ports)")
     args = parser.parse_args(argv)
 
     net = _bootstrap()

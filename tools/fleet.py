@@ -356,7 +356,7 @@ def main(argv=None):
                          help="extra env for ONE replica (repeatable) "
                               "— e.g. 0:MXTPU_FAULTS=slow_replica:100 "
                               "arms a fault on replica 0 only (chaos "
-                              "drills, bench.py tail)")
+                              "drills)")
     p_serve.add_argument("--max-restarts", type=int, default=3,
                          help="per-replica consecutive-relaunch budget")
     p_serve.add_argument("--slo-ms", type=float, default=0.0,

@@ -3,8 +3,8 @@
 
 Level 2 (AST) runs always: traced-host calls in jitted functions,
 lock-order cycles, bare excepts, and env-registry discipline over the
-given paths (default: the ``mxnet_tpu`` package, ``tools/`` and
-``bench.py`` next to this script — zero carve-outs).
+given paths (default: the ``mxnet_tpu`` package and ``tools/`` — zero
+carve-outs).
 Level 3 (whole-repo) also runs always: the shared-mutation race lint
 (``repo-shared-mutation`` / ``repo-check-then-act``) and the
 wire-contract drift lint (``wire-contract-drift``, driven by the
@@ -17,7 +17,7 @@ Exit codes: 0 = clean, 1 = findings, 2 = internal/usage error.
 
 Reports: human lines on stdout; ``--json PATH`` (or the
 ``MXTPU_ANALYZE_REPORT`` env var) writes the stable machine-readable
-report CI/bench diff across commits (see
+report CI diffs across commits (see
 docs/how_to/static_analysis.md).  Suppress a finding inline with
 ``# mxlint: disable=<rule>`` on (or above) the offending line.
 
@@ -90,12 +90,9 @@ def _graph_lint_mlp():
 
 
 def _default_paths():
-    """The zero-carve-out lint scope: the package, the tools, and the
-    bench harness (PR 16 retired bench.py's last inline-disable; keeping
-    it in the default scope is what keeps it retired)."""
+    """The zero-carve-out lint scope: the package and the tools."""
     return [os.path.join(_REPO, "mxnet_tpu"),
-            os.path.join(_REPO, "tools"),
-            os.path.join(_REPO, "bench.py")]
+            os.path.join(_REPO, "tools")]
 
 
 def _changed_paths(ref):
@@ -170,7 +167,7 @@ def main(argv=None):
         formatter_class=argparse.RawDescriptionHelpFormatter)
     parser.add_argument("paths", nargs="*",
                         help="files/directories to lint (default: the "
-                             "mxnet_tpu package + tools/ + bench.py)")
+                             "mxnet_tpu package + tools/)")
     parser.add_argument("--self", dest="lint_self", action="store_true",
                         help="lint the linter (tools/mxlint.py + the "
                              "analysis package) along with the package")

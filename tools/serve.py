@@ -190,9 +190,8 @@ def main(argv=None):
                 sys.stderr.write("mxserve: warmed %r over buckets %s\n"
                                  % (name, [b for b in buckets
                                            if b not in entry._aot]))
-        # the bring-up number bench.py fleet compares cold vs AOT-warm
-        # (process start/imports excluded — this is the compile cost
-        # the warm store removes)
+        # the bring-up number (process start/imports excluded — this is
+        # the compile cost the warm store removes)
         sys.stderr.write("mxserve: warmup_s=%.3f\n"
                          % (_time.monotonic() - tic))
     if args.warmup_only:
