@@ -58,6 +58,23 @@ Kernel catalog (``KNOWN_KERNELS``):
   with the ``bn_fold`` serving default; values are bit-identical, the
   win is trace/bind time per serving bucket.
 
+Outside the registry, decided by platform, mesh and shapes alone (a
+kernel that wins its cell is unconditional, ``ROADMAP.md`` Design 2):
+
+- ``delta_rule`` — the chunked gated delta rule of ``GatedDeltaRule``
+  (:mod:`.delta_rule`): ``mxtpu_delta_rule_fwd`` / ``mxtpu_delta_rule_bwd``
+  walk a row's chunks with the state in VMEM, one key head and the value
+  heads it serves a grid step; q, k and v are read as the graph has them.
+  Compiled in a program lowered for a TPU when the head sizes are
+  multiples of 128, the value heads a multiple of the key heads and the
+  row whole chunks (of a multiple of 16); the lax tier otherwise and
+  under :func:`auto_partitioned`.  Each lowering records a
+  ``kernel.route`` event (kernel, tier, reason) in the program's
+  recorder; the benchmark's ``gdn_kernel_share`` reads them.  On the
+  v5e at the benchmark's shape (2 x 8,192 positions, 16 key / 32 value
+  heads of 128, chunk 64) the lax tier took 25.4 ms forward and 59.3 ms
+  forward + backward, the kernels 9.6 and 17.9 (PERF.md, PR 30).
+
 The plan-level passes live in :mod:`mxnet_tpu.mxfuse` (the
 match-and-rewrite framework over the executor's node plan); this
 registry routes them exactly like the kernel bodies.
@@ -71,7 +88,8 @@ import threading
 from ..base import ENV_FUSED_KERNELS, get_env, register_env
 
 __all__ = ["KNOWN_KERNELS", "fused_enabled", "enabled_kernels",
-           "by_platform", "auto_partitioned", "compiled_kernels",
+           "by_platform", "auto_partitioned", "partitioned",
+           "compiled_kernels",
            "ENV_FLASH_BLOCK", "bn_act",
            "lstm_cell", "flash_attention", "augment",
            "concat_fuse", "pool_act", "eltwise_chain"]
@@ -147,6 +165,11 @@ def auto_partitioned():
         _auto_partitioned.on = prev
 
 
+def partitioned():
+    """Whether the trace is inside :func:`auto_partitioned`."""
+    return getattr(_auto_partitioned, "on", False)
+
+
 def by_platform(pallas_fn, lax_fn, *args):
     """Tier selection by the platform the computation is LOWERED for
     (``lax.platform_dependent``): the compiled Pallas kernel in a TPU
@@ -157,7 +180,7 @@ def by_platform(pallas_fn, lax_fn, *args):
     outputs are cast to the lax tier's dtypes: both branches must present
     one signature."""
     import jax
-    if getattr(_auto_partitioned, "on", False):
+    if partitioned():
         return lax_fn(*args)
     want = jax.eval_shape(lax_fn, *args)
 

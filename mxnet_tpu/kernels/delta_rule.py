@@ -17,20 +17,38 @@ with ``G`` the running sum of ``g`` inside the chunk and
 ``u`` rows solve the unit-lower-triangular system ``(I + L) u = beta * (v
 - exp(G) K S0)``, so ``u = U - W S0`` with ``U = (I + L)^-1 (beta * V)`` and
 ``W = (I + L)^-1 (beta * exp(G) * K)``; only ``S0`` — the state entering
-the chunk — is carried from chunk to chunk, by ``lax.scan``.
+the chunk — is carried from chunk to chunk.
 
-Pure lax, differentiable by jax; the triangular inverse has its own
-backward (two products) so that its doubling steps are not saved.
+Two tiers, one algorithm (package docstring):
+
+- :func:`gated_delta_rule` — pure lax, differentiable by jax: every
+  chunk's tiles at once, then a ``lax.scan`` over the chunks; the
+  triangular inverse has its own backward (two products) so that its
+  doubling steps are not saved.  Every platform lowers it; it is the
+  numeric oracle.
+- :func:`gated_delta_net_pallas` — ``mxtpu_delta_rule_fwd`` /
+  ``mxtpu_delta_rule_bwd`` behind one ``jax.custom_vjp``: a grid over
+  (row, key head) streams and, innermost and sequential, the row's
+  chunks, with a chunk's tiles and ``S`` in VMEM.  HBM sees q, k, v, g,
+  beta and o, and for the backward the state that entered each chunk and
+  the chunk's inverse.  :func:`chunk_forward` is the chunk's map on tiles
+  and :func:`chunk_backward` its transpose by hand; the kernels' bodies
+  and the tests share them.
+
+:func:`gated_delta_net` routes between them from platform, mesh and
+shapes.
 """
 from __future__ import annotations
 
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["gated_delta_rule"]
+__all__ = ["gated_delta_rule", "gated_delta_net", "gated_delta_net_lax",
+           "gated_delta_net_pallas", "chunk_forward", "chunk_backward"]
 
 _HIGHEST = lax.Precision.HIGHEST
 
@@ -41,12 +59,13 @@ def _mm(a, b):
     return jnp.matmul(a, b, precision=_HIGHEST)
 
 
-def _unit_lower_inverse_impl(low):
+def _unit_lower_inverse_impl(low, order=None):
     """(I + L)^-1 for strictly lower triangular ``L`` (..., C, C): L is
     nilpotent, so the inverse is the finite sum of (-L)^i, built by
-    doubling: (I - L)(I + L^2)(I + L^4)..."""
-    c = low.shape[-1]
-    eye = jnp.eye(c, dtype=low.dtype)
+    doubling: (I - L)(I + L^2)(I + L^4)...  ``order``: L^order = 0 (the
+    block length of a block-diagonal L; default C)."""
+    c = order or low.shape[-1]
+    eye = jnp.eye(low.shape[-1], dtype=low.dtype)
     x, p = eye - low, _mm(low, low)
     span = 2
     while span < c:
@@ -123,3 +142,552 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64):
     o = jnp.moveaxis(o, 0, 2)                           # (B, H, n, C, dv)
     o = jnp.moveaxis(o, 1, 3).reshape(B, n * C, H, dv)
     return o[:, :T]
+
+
+# ---------------------------------------------------------------------------
+# The compiled tier: one chunk's map on tiles, and the two kernels that walk
+# a row's chunks with the state in VMEM.
+#
+# A grid step works on one key head and the ``rep`` value heads it serves.
+# What meets the state is stacked along rows, head-major (v, v_new, o: R =
+# rep * C rows); the heads' chunk-local (C x C) tiles lie side by side along
+# lanes ((C, R)), and a product with them streams C rows through the heads'
+# blocks on the diagonal of one (R x R) tile: with rep * C = 128 that is
+# one full MXU tile.
+# ---------------------------------------------------------------------------
+
+def _nt(a, b, precision=None):
+    """a @ b^T."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+def _tn(a, b, precision=None):
+    """a^T @ b."""
+    return lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                           precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+def _heads(x, rep):
+    """The ``rep`` row blocks of a stacked tile."""
+    c = x.shape[0] // rep
+    return [x[r * c:(r + 1) * c] for r in range(rep)]
+
+
+def _stack(blocks):
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=0)
+
+
+def _total(x):
+    """The sum of a tile as a (1, 1) tile."""
+    return jnp.sum(jnp.sum(x, axis=0, keepdims=True), axis=1, keepdims=True)
+
+
+def _down(g_last, like):
+    """exp of a (1, 1) log-decay as a column for the rows of ``like``
+    (Mosaic broadcasts along one of lanes and sublanes at a time: down
+    the rows here, along the lanes in the product that follows)."""
+    return jnp.exp(jnp.broadcast_to(g_last, (like.shape[0], 1)))
+
+
+def _unit(x, eps):
+    """Rows of ``x`` at unit L2 length, and the factor that made them so."""
+    r = lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+    return x * r, r
+
+
+def _unit_bwd(unit, r, d_unit):
+    return r * (d_unit - unit * jnp.sum(unit * d_unit, axis=-1,
+                                        keepdims=True))
+
+
+def _masks(C, rep):
+    """The index masks of a chunk's tiles, R = rep * C.  (R, R): ``own``
+    the heads' diagonal blocks, ``eye`` the diagonal.  (C, R), the heads'
+    (C, C) blocks side by side along lanes: ``head[r]`` head r's lanes,
+    ``low`` / ``strict`` the blocks' lower triangles, ``eyes`` their
+    diagonals.  ``row`` (R, 1) the row index."""
+    R = rep * C
+    i = lax.broadcasted_iota(jnp.int32, (R, R), 0)
+    j = lax.broadcasted_iota(jnp.int32, (R, R), 1)
+    own = functools.reduce(jnp.logical_or, [
+        (i >= r * C) & (i < (r + 1) * C) & (j >= r * C) & (j < (r + 1) * C)
+        for r in range(rep)])
+    ic = lax.broadcasted_iota(jnp.int32, (C, R), 0)
+    jc = lax.broadcasted_iota(jnp.int32, (C, R), 1)
+    head = [(jc >= r * C) & (jc < (r + 1) * C) for r in range(rep)]
+    any_of = functools.partial(functools.reduce, jnp.logical_or)
+    return dict(
+        own=own, eye=i == j, head=head,
+        low=any_of([h & (ic + r * C >= jc) for r, h in enumerate(head)]),
+        strict=any_of([h & (ic + r * C > jc) for r, h in enumerate(head)]),
+        eyes=any_of([jc == ic + r * C for r in range(rep)]),
+        row=lax.broadcasted_iota(jnp.int32, (R, 1), 0))
+
+
+def _side_by_side(x, own, rep):
+    """The diagonal blocks of (R, R) -> (C, R), side by side."""
+    return sum(_heads(jnp.where(own, x, 0.0), rep))
+
+
+def _blocks(x, own):
+    """(C, R) side by side -> (R, R) with the blocks on the diagonal."""
+    return jnp.where(own, _stack([x] * (x.shape[1] // x.shape[0])), 0.0)
+
+
+def _along(cols, head):
+    """The heads' (C, 1) columns, each along its head's lanes: (C, R)."""
+    out = cols[-1]
+    for col, lanes in zip(cols[-2::-1], head[-2::-1]):
+        out = jnp.where(lanes, col, out)
+    return out
+
+
+def _down_heads(x, head):
+    """(C, R) side by side -> (R, 1) stacked: each head's lanes summed."""
+    return _stack([jnp.sum(jnp.where(lanes, x, 0.0), axis=1, keepdims=True)
+                   for lanes in head])
+
+
+def _chunk_tiles(q, k, gcol, grow, bcol, rep, eps, scale, m):
+    """What a chunk computes before it meets the state.  q, k (C, dk) as
+    the graph has them (float32 here); gcol, bcol (R, 1) the running
+    log-decay and beta of the stacked heads down rows, grow (1, R) the
+    former along lanes.  The (C, C) tiles of the heads lie side by side."""
+    C = q.shape[0]
+    qh, rq = _unit(q, eps)
+    kh, rk = _unit(k, eps)
+    qs = qh * scale
+    k2 = _stack([kh] * rep)
+    exponent = _along(_heads(gcol, rep), m["head"]) - grow
+    decay = jnp.where(m["low"],
+                      jnp.exp(jnp.where(m["low"], exponent, 0.0)), 0.0)
+    last = [b[C - 1:C] for b in _heads(gcol, rep)]
+    gend = _stack([jnp.broadcast_to(b, (C, 1)) for b in last])
+    eg, er, ec = jnp.exp(gcol), jnp.exp(gend - gcol), jnp.exp(gend)
+    # every head's block of k k^T (q k^T) is the same: C rows of product
+    return dict(qh=qh, rq=rq, kh=kh, rk=rk, qs=qs, q2=_stack([qs] * rep),
+                k2=k2, decay=decay, kk=_nt(kh, k2, _HIGHEST),
+                qk=_nt(qs, k2, _HIGHEST),
+                beta=_along(_heads(bcol, rep), m["head"]),
+                eg=eg, er=er, ec=ec, last=last)
+
+
+def _inverse_side_by_side(low, m):
+    """:func:`_unit_lower_inverse_impl` for the heads' strictly lower (C,
+    C) tiles side by side (C, R): each product streams C rows through the
+    heads' blocks on the diagonal of one (R, R) tile."""
+    C, own = low.shape[0], m["own"]
+    x, p = jnp.where(m["eyes"], 1.0, 0.0) - low, _mm(low, _blocks(low, own))
+    span = 2
+    while span < C:
+        x = x + _mm(x, _blocks(p, own))
+        span *= 2
+        if span < C:
+            p = _mm(p, _blocks(p, own))
+    return x
+
+
+def _per_head(fn, rep, *stacked):
+    """``fn`` over the heads' row blocks, stacked again."""
+    return _stack([fn(r, *xs) for r, xs in enumerate(
+        zip(*(_heads(x, rep) for x in stacked)))])
+
+
+def chunk_forward(s0, q, k, v, gcol, grow, bcol, *, rep, eps, scale,
+                  masks=None):
+    """One chunk of the rule for one key head: the states entering it
+    ``s0`` (a list of ``rep`` (dk, dv) tiles), q, k (C, dk), v (R, dv),
+    gcol, bcol (R, 1), grow (1, R) -> (o (R, dv), the states leaving it,
+    the heads' unit-lower inverses side by side (C, R)).  The module
+    docstring's mathematics with ``v_new = T (beta * (V - (K e^G) S0))``;
+    chunk-local products at full float32 precision, products with the
+    state at the default one, as the lax tier has them."""
+    m = masks or _masks(q.shape[0], rep)
+    t = _chunk_tiles(q, k, gcol, grow, bcol, rep, eps, scale, m)
+    inverse = _inverse_side_by_side(jnp.where(
+        m["strict"], t["beta"] * t["kk"] * t["decay"], 0.0), m)
+    kg, qg, kr = t["k2"] * t["eg"], t["q2"] * t["eg"], t["k2"] * t["er"]
+    p = _per_head(lambda r, x: jnp.matmul(x, s0[r]), rep, kg)
+    v_new = _mm(_blocks(inverse, m["own"]), bcol * (v - p))
+    o = _per_head(lambda r, x: jnp.matmul(x, s0[r]), rep, qg) \
+        + jnp.matmul(_blocks(t["qk"] * t["decay"], m["own"]), v_new)
+    s1 = [_down(c, s) * s + _tn(x, y) for s, c, x, y in zip(
+        s0, t["last"], _heads(kr, rep), _heads(v_new, rep))]
+    return o, s1, inverse
+
+
+def chunk_backward(s0, inverse, q, k, v, gcol, grow, bcol, do, ds1, *,
+                   rep, eps, scale, masks=None):
+    """The transpose of :func:`chunk_forward` at ``(do, ds1)``, by hand:
+    (ds0, dq, dk (C, dk), dv (R, dv), dgcol (R, 1), dgrow (1, R), dbcol
+    (R, 1)).  ``inverse`` is the forward's; its derivative needs no
+    product with it twice over: with ``dR = T^T dv_new``, the cotangent of
+    the strict-lower tile is ``-strict(dR v_new^T)``."""
+    C = q.shape[0]
+    m = masks or _masks(C, rep)
+    own, head = m["own"], m["head"]
+    t = _chunk_tiles(q, k, gcol, grow, bcol, rep, eps, scale, m)
+    decay, kk, qk, k2, q2 = t["decay"], t["kk"], t["qk"], t["k2"], t["q2"]
+    eg, er, beta = t["eg"], t["er"], t["beta"]
+    kg, qg, kr = k2 * eg, q2 * eg, k2 * er
+    p = _per_head(lambda r, x: jnp.matmul(x, s0[r]), rep, kg)
+    vmp = v - p
+    inverse = _blocks(inverse, own)
+    v_new = _mm(inverse, bcol * vmp)
+    # s1 = ec * s0 + kr^T v_new;  o = qg s0 + (qk * decay) v_new
+    d_kr = _per_head(lambda r, x: _nt(x, ds1[r]), rep, v_new)
+    d_qg = _per_head(lambda r, x: _nt(x, s0[r]), rep, do)
+    d_a = _side_by_side(_nt(do, v_new), own, rep)
+    d_vn = _per_head(lambda r, x: jnp.matmul(x, ds1[r]), rep, kr) \
+        + _tn(_blocks(qk * decay, own), do)
+    # v_new = T (bcol * (v - p))
+    d_r = _tn(inverse, d_vn, _HIGHEST)
+    d_low = jnp.where(m["strict"],
+                      -_side_by_side(_nt(d_r, v_new, _HIGHEST), own, rep), 0.0)
+    dv = bcol * d_r
+    d_kg = _per_head(lambda r, x: -_nt(x, s0[r]), rep, dv)
+    ds0 = [_down(c, d) * d + _tn(x, y) - _tn(z, w)
+           for d, c, x, y, z, w in zip(
+        ds1, t["last"], _heads(qg, rep), _heads(do, rep),
+        _heads(kg, rep), _heads(dv, rep))]
+    # low = strict(beta * kk * decay);  a = qk * decay
+    dbcol = jnp.sum(d_r * vmp, axis=-1, keepdims=True) \
+        + _down_heads(d_low * kk * decay, head)
+    d_kk, d_qk = d_low * beta * decay, d_a * decay
+    e = (d_low * beta * kk + d_a * qk) * decay
+    x_kr = jnp.sum(d_kr * kr, axis=-1, keepdims=True)
+    dgcol = _down_heads(e, head) \
+        + jnp.sum(d_qg * qg + d_kg * kg, axis=-1, keepdims=True) - x_kr
+    dgrow = -jnp.sum(e, axis=0, keepdims=True)
+    # the chunk's last row carries gend: er's and ec's exponents
+    ends = [jnp.sum(x, axis=0, keepdims=True) + c[:1] * _total(d * s)
+            for x, c, d, s in zip(_heads(x_kr, rep), _heads(t["ec"], rep),
+                                  ds1, s0)]
+    dgcol = dgcol + _per_head(
+        lambda r, row: jnp.where(row == (r + 1) * C - 1, ends[r], 0.0),
+        rep, m["row"])
+    # k2, q2 repeat kh, qh: the heads' blocks side by side sum over heads
+    # in the product
+    d_kh = _mm(d_kk, k2) + sum(_heads(
+        _tn(d_kk, t["kh"], _HIGHEST) + _tn(d_qk, t["qs"], _HIGHEST)
+        + d_kg * eg + d_kr * er, rep))
+    d_qh = _mm(d_qk, k2) + sum(_heads(d_qg * eg, rep))
+    dq = _unit_bwd(t["qh"], t["rq"], scale * d_qh)
+    dk = _unit_bwd(t["kh"], t["rk"], d_kh)
+    return ds0, dq, dk, dv, dgcol, dgrow, dbcol
+
+
+#: bytes of one grid step's blocks (each double-buffered) that decide how
+#: many chunks it walks: 8 at the heads of 128 the kernels are tuned for.
+#: A v5e starts a grid step in ~0.35 us, and the walk is unrolled so that
+#: the scheduler lays one chunk's products into the gaps of the next's: 8
+#: chunks a step took 17.9 ms forward + backward where 2 took 19.3 and 16
+#: do not fit the 16 MiB of scoped VMEM (chip, PR 30)
+_BLOCK_BYTES = 4 << 20
+
+
+def _chunks_per_step(n, C, rep, dk, dv):
+    """The largest divisor of a row's ``n`` chunks whose blocks in the
+    backward kernel (the larger: states, inverse, q, k, v, do and their
+    cotangents, counted at 4 bytes) stay within ``_BLOCK_BYTES``."""
+    chunk = 4 * (rep * dk * dv + C * rep * C + 4 * C * dk
+                 + 3 * C * rep * dv)
+    fit = max(1, _BLOCK_BYTES // chunk)
+    return max(d for d in range(1, min(n, fit) + 1) if n % d == 0)
+
+
+def _to_col(row, eye):
+    """(1, R) along lanes -> (R, 1) down rows, exactly: a select against
+    the identity and a sum of zeros."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _to_row(col, eye):
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _read_chunk(refs, c_here, c_row, C, rep, eye):
+    """One chunk's tiles, in float32: q, k (C, dk), v (R, dv) stacked,
+    gcol, bcol (R, 1), grow (1, R).  ``c_here`` counts chunks inside the
+    grid step's block, ``c_row`` inside the row."""
+    from jax.experimental import pallas as pl
+    q_ref, k_ref, v_ref, g_ref, b_ref = refs
+    f32 = jnp.float32
+    rows = pl.ds(pl.multiple_of(c_here * C, C), C)
+    q = q_ref[0, rows, :].astype(f32)
+    k = k_ref[0, rows, :].astype(f32)
+    v = _stacked(v_ref[0, rows, :], rep)
+    grow = g_ref[0, 0, pl.ds(c_row, 1), :]
+    bcol = _to_col(b_ref[0, 0, pl.ds(c_row, 1), :], eye)
+    return rows, q, k, v, _to_col(grow, eye), grow, bcol
+
+
+def _along_lanes(x, rep):
+    """(R, n) stacked -> (C, rep * n): the heads' row blocks along lanes."""
+    blocks = _heads(x, rep)
+    return blocks[0] if rep == 1 else jnp.concatenate(blocks, axis=1)
+
+
+def _stacked(x, rep):
+    """(C, rep * n), the heads along lanes as the graph has them -> (R, n)
+    stacked, in float32."""
+    n = x.shape[1] // rep
+    x = x.astype(jnp.float32)
+    return _stack([x[:, r * n:(r + 1) * n] for r in range(rep)])
+
+
+def _fwd_kernel(*refs, rep, C, nb, eps, scale, save):
+    """Forward body: the grid step's ``nb`` chunks in order (unrolled: a
+    chunk's tiles and inverse wait for no state, so the scheduler lays
+    them into the gaps of the chunk before), the states in ``s_scr``.
+    With ``save`` each chunk also writes the states that entered it and
+    its inverse, for the backward."""
+    from jax.experimental import pallas as pl
+    o_ref = refs[5]
+    s_scr = refs[-1]
+    m = _masks(C, rep)
+    t = pl.program_id(2)
+
+    @pl.when(t == 0)
+    def _():
+        s_scr[...] = jnp.zeros_like(s_scr)
+
+    def body(ci, carry):
+        rows, q, k, v, gcol, grow, bcol = _read_chunk(
+            refs[:5], ci, t * nb + ci, C, rep, m["eye"])
+        s0 = [s_scr[r] for r in range(rep)]
+        o, s1, inverse = chunk_forward(
+            s0, q, k, v, gcol, grow, bcol, rep=rep, eps=eps, scale=scale,
+            masks=m)
+        o_ref[0, rows, :] = _along_lanes(o, rep).astype(o_ref.dtype)
+        for r in range(rep):
+            if save:
+                refs[6][0, r, ci] = s0[r]
+            s_scr[r] = s1[r]
+        if save:
+            refs[7][0, 0, ci] = inverse
+        return carry
+    lax.fori_loop(0, nb, body, 0, unroll=True)
+
+
+def _bwd_kernel(*refs, rep, C, nb, eps, scale):
+    """Backward body: the grid step's chunks in reverse, the states'
+    cotangent in ``ds_scr``."""
+    from jax.experimental import pallas as pl
+    s_ref, t_ref, do_ref = refs[5:8]
+    dq_ref, dk_ref, dv_ref, dg_ref, db_ref = refs[8:13]
+    ds_scr = refs[-1]
+    m = _masks(C, rep)
+    eye = m["eye"]
+    t = pl.num_programs(2) - 1 - pl.program_id(2)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_scr[...] = jnp.zeros_like(ds_scr)
+
+    def body(step, carry):
+        ci = nb - 1 - step
+        c_row = t * nb + ci
+        rows, q, k, v, gcol, grow, bcol = _read_chunk(
+            refs[:5], ci, c_row, C, rep, eye)
+        s0 = [s_ref[0, r, ci] for r in range(rep)]
+        inverse = t_ref[0, 0, ci]
+        do = _stacked(do_ref[0, rows, :], rep)
+        ds1 = [ds_scr[r] for r in range(rep)]
+        ds0, dq, dk, d_v, dgcol, dgrow, dbcol = chunk_backward(
+            s0, inverse, q, k, v, gcol, grow, bcol, do, ds1,
+            rep=rep, eps=eps, scale=scale, masks=m)
+        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
+        dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, rows, :] = _along_lanes(d_v, rep).astype(dv_ref.dtype)
+        dg_ref[0, 0, pl.ds(c_row, 1), :] = dgrow + _to_row(dgcol, eye)
+        db_ref[0, 0, pl.ds(c_row, 1), :] = _to_row(dbcol, eye)
+        for r in range(rep):
+            ds_scr[r] = ds0[r]
+        return carry
+    lax.fori_loop(0, nb, body, 0, unroll=True)
+
+
+@functools.lru_cache(maxsize=None)
+def _pallas_rule(C, eps, interpret):
+    """The rule over row-major operands as one ``custom_vjp``: q, k (B, T,
+    Hk * dk), v (B, T, Hv * dv), grow, brow (B, Hk, n, rep * C) -> o like
+    v."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def calls(q, v, grow):
+        B, T, _ = q.shape
+        _, Hk, n, R = grow.shape
+        rep = R // C
+        dk, dv = q.shape[2] // Hk, v.shape[2] // (Hk * rep)
+        nb = _chunks_per_step(n, C, rep, dk, dv)
+        steps = n // nb
+
+        def specs(when):
+            """Block specs of a grid step at time block ``when(t)``."""
+            def rowwise(width):
+                return pl.BlockSpec((1, nb * C, width),
+                                    lambda b, h, t: (b, when(t), h),
+                                    memory_space=pltpu.VMEM)
+
+            def chunkwise(lead, *tail):
+                zeros = (0,) * len(tail)
+                return pl.BlockSpec(
+                    (1, lead, nb) + tail,
+                    lambda b, h, t: (b, h, when(t)) + zeros,
+                    memory_space=pltpu.VMEM)
+            return dict(
+                q=rowwise(dk), v=rowwise(rep * dv),
+                # a row's decays and betas stay for all its grid steps
+                row=pl.BlockSpec((1, 1, n, R), lambda b, h, t: (b, h, 0, 0),
+                                 memory_space=pltpu.VMEM),
+                states=chunkwise(rep, dk, dv), inverse=chunkwise(1, C, R))
+        scratch = [pltpu.VMEM((rep, dk, dv), jnp.float32)]
+        params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
+        kw = dict(rep=rep, C=C, nb=nb, eps=eps, scale=dk ** -0.5)
+        f32 = jnp.float32
+        shape = jax.ShapeDtypeStruct
+        states = shape((B, Hk * rep, n, dk, dv), f32)
+        inverse = shape((B, Hk, n, C, R), f32)
+
+        def forward(save):
+            s = specs(lambda t: t)
+            return pl.pallas_call(
+                functools.partial(_fwd_kernel, save=save, **kw),
+                grid=(B, Hk, steps),
+                in_specs=[s["q"], s["q"], s["v"], s["row"], s["row"]],
+                out_specs=(s["v"], s["states"], s["inverse"]) if save
+                else s["v"],
+                out_shape=(shape(v.shape, v.dtype), states, inverse)
+                if save else shape(v.shape, v.dtype),
+                scratch_shapes=scratch, compiler_params=params,
+                name="mxtpu_delta_rule_fwd", interpret=interpret)
+
+        def backward(k):
+            s = specs(lambda t: steps - 1 - t)
+            return pl.pallas_call(
+                functools.partial(_bwd_kernel, **kw),
+                grid=(B, Hk, steps),
+                in_specs=[s["q"], s["q"], s["v"], s["row"], s["row"],
+                          s["states"], s["inverse"], s["v"]],
+                out_specs=(s["q"], s["q"], s["v"], s["row"], s["row"]),
+                out_shape=(shape(q.shape, q.dtype), shape(k.shape, k.dtype),
+                           shape(v.shape, v.dtype), shape(grow.shape, f32),
+                           shape(grow.shape, f32)),
+                scratch_shapes=scratch, compiler_params=params,
+                name="mxtpu_delta_rule_bwd", interpret=interpret)
+        return forward, backward
+
+    # jitted, so that a model's layers of one shape trace and lower each
+    # kernel once
+    @functools.partial(jax.jit, static_argnames=("save",))
+    def run_forward(q, k, v, grow, brow, save):
+        return calls(q, v, grow)[0](save)(q, k, v, grow, brow)
+
+    @jax.jit
+    def run_backward(q, k, v, grow, brow, states, inverse, do):
+        return calls(q, v, grow)[1](k)(q, k, v, grow, brow, states, inverse,
+                                       do)
+
+    @jax.custom_vjp
+    def rule(q, k, v, grow, brow):
+        return run_forward(q, k, v, grow, brow, save=False)
+
+    def rule_fwd(q, k, v, grow, brow):
+        o, states, inverse = run_forward(q, k, v, grow, brow, save=True)
+        return o, (q, k, v, grow, brow, states, inverse)
+
+    def rule_bwd(res, do):
+        return run_backward(*res, do)
+
+    rule.defvjp(rule_fwd, rule_bwd)
+    return rule
+
+
+def gated_delta_net_pallas(query, key, value, g, beta, chunk=64, eps=1e-6,
+                           interpret=False):
+    """The compiled tier of :func:`gated_delta_net` (same operands, same
+    result).  The kernels take q, k and v as they are; of g and beta —
+    (B, T, Hv) float32, a thousandth of the bytes — they take g's running
+    sum inside each chunk and beta with a key head's value heads side by
+    side along lanes ((B, Hk, n, rep * C)), and jax differentiates that
+    rearrangement."""
+    B, T, Hk, dk = query.shape
+    Hv, dv = value.shape[2:]
+    rep, C = Hv // Hk, int(chunk)
+    n = T // C
+
+    def rows(x):                       # (B, T, Hv) -> (B, Hv, n, C)
+        return jnp.transpose(x.astype(jnp.float32).reshape(B, n, C, Hv),
+                             (0, 3, 1, 2))
+
+    def side_by_side(x):               # -> (B, Hk, n, rep * C)
+        x = jnp.transpose(x.reshape(B, Hk, rep, n, C), (0, 1, 3, 2, 4))
+        return x.reshape(B, Hk, n, rep * C)
+    run = side_by_side(jnp.cumsum(rows(g), axis=-1))
+    out = _pallas_rule(C, float(eps), bool(interpret))(
+        query.reshape(B, T, Hk * dk), key.reshape(B, T, Hk * dk),
+        value.reshape(B, T, Hv * dv), run, side_by_side(rows(beta)))
+    return out.reshape(value.shape)
+
+
+def gated_delta_net_lax(query, key, value, g, beta, chunk=64, eps=1e-6):
+    """The lax tier of :func:`gated_delta_net`: q and k normalised and
+    shared out to the value heads, then :func:`gated_delta_rule`."""
+    f32 = jnp.float32
+    rep = value.shape[2] // query.shape[2]
+
+    def unit(x):
+        x = x.astype(f32)
+        x = x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                          + float(eps))
+        return jnp.repeat(x, rep, axis=2) if rep > 1 else x
+
+    q = unit(query) * (query.shape[-1] ** -0.5)
+    out = gated_delta_rule(q, unit(key), value, g, beta, chunk=int(chunk))
+    return out.astype(value.dtype)
+
+
+def _lax_reason(query, value, chunk):
+    """Why these operands are not the compiled tier's, or None."""
+    from . import partitioned
+    (_, T, Hk, dk), (Hv, dv) = query.shape, value.shape[2:]
+    if partitioned():
+        return "mesh"
+    if dk % 128 or dv % 128 or Hv % Hk or T % int(chunk) or int(chunk) % 16:
+        return "shapes"
+    return None
+
+
+def gated_delta_net(query, key, value, g, beta, chunk=64, eps=1e-6):
+    """The gated delta rule on operands as the graph has them: query, key
+    (B, T, Hk, dk), value (B, T, Hv, dv), g (log-decay, <= 0) and beta (B,
+    T, Hv), each key head serving ``Hv // Hk`` consecutive value heads;
+    query and key are L2-normalised per head (``eps``) and the query
+    scaled by dk^-0.5.  Returns o like value.
+
+    Which tier runs follows from what the trace can see: the compiled
+    kernels in a program lowered for a TPU, for lane-aligned heads and
+    whole chunks; the lax tier on other platforms, for other shapes (it
+    pads the tail) and in a program the SPMD partitioner will split.
+    Each call records one ``kernel.route`` event in the program's
+    recorder with the kernel, the tier and the reason."""
+    from .. import profiler
+    from . import by_platform
+    reason = _lax_reason(query, value, chunk)
+    tier = "lax" if reason else "pallas"
+    now = time.perf_counter_ns()
+    profiler.event("kernel.route", now, now, kernel="delta_rule", tier=tier,
+                   reason=reason or "aligned")
+    profiler.count("kernel.delta_rule." + tier)
+    lax_fn = functools.partial(gated_delta_net_lax, chunk=chunk, eps=eps)
+    if reason:
+        return lax_fn(query, key, value, g, beta)
+    return by_platform(
+        functools.partial(gated_delta_net_pallas, chunk=chunk, eps=eps),
+        lax_fn, query, key, value, g, beta)

@@ -592,23 +592,13 @@ def gated_delta_rule_op(query, key, value, a, b, A_log, dt_bias, chunk=64,
     value head, from a zero state, position t does ``S = exp(g_t) S;
     u = (v_t - S^T k_t) beta_t; S = S + k_t u^T; o_t = S^T q_t``.  Returns
     o shaped like value."""
-    from ..kernels.delta_rule import gated_delta_rule
+    from ..kernels.delta_rule import gated_delta_net
     f32 = jnp.float32
-    rep = value.shape[2] // query.shape[2]
-
-    def unit(x):
-        x = x.astype(f32)
-        x = x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
-                          + float(eps))
-        return jnp.repeat(x, rep, axis=2) if rep > 1 else x
-
-    q = unit(query) * (query.shape[-1] ** -0.5)
-    k = unit(key)
     beta = jax.nn.sigmoid(b.astype(f32))
     g = -jnp.exp(A_log.astype(f32)) * jax.nn.softplus(
         a.astype(f32) + dt_bias.astype(f32))
-    out = gated_delta_rule(q, k, value, g, beta, chunk=int(chunk))
-    return out.astype(value.dtype)
+    return gated_delta_net(query, key, value, g, beta, chunk=int(chunk),
+                           eps=float(eps))
 
 
 @jax.custom_vjp

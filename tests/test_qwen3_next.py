@@ -193,13 +193,14 @@ def _delta_recurrence(q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 1)
 
 
-def _rule_inputs(t, seed=20):
-    q, k = _n((2, t, 2, 8), seed), _n((2, t, 2, 8), seed + 1)
-    v = _n((2, t, 4, 6), seed + 2)
-    a, b = _n((2, t, 4), seed + 3), _n((2, t, 4), seed + 4)
-    a_log = np.log(RS(seed + 5).uniform(0.1, 4, 4)).astype("f")
+def _rule_inputs(t, seed=20, dk=8, dv=6, hk=2, a_shift=0.0):
+    q, k = _n((2, t, hk, dk), seed), _n((2, t, hk, dk), seed + 1)
+    v = _n((2, t, 2 * hk, dv), seed + 2)
+    a = _n((2, t, 2 * hk), seed + 3) + a_shift
+    b = _n((2, t, 2 * hk), seed + 4)
+    a_log = np.log(RS(seed + 5).uniform(0.1, 4, 2 * hk)).astype("f")
     return tuple(jnp.asarray(x) for x in
-                 (q, k, v, a, b, a_log, np.ones(4, "f")))
+                 (q, k, v, a, b, a_log, np.ones(2 * hk, "f")))
 
 
 def _rule_oracle(q, k, v, a, b, a_log, dt_bias):
@@ -207,7 +208,7 @@ def _rule_oracle(q, k, v, a, b, a_log, dt_bias):
         x = x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
         return jnp.repeat(x, 2, axis=2)
     g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
-    return _delta_recurrence(unit(q) * 8 ** -0.5, unit(k), v, g,
+    return _delta_recurrence(unit(q) * q.shape[-1] ** -0.5, unit(k), v, g,
                              jax.nn.sigmoid(b))
 
 
@@ -245,6 +246,171 @@ def test_delta_rule_symbol_numeric_gradient():
     _, out, _ = s.infer_shape(**{k: v.shape for k, v in loc.items()})
     assert out == [(1, 6, 2, 2)]
     check_numeric_gradient(s, loc, rtol=3e-2, atol=3e-3)
+
+
+@pytest.mark.parametrize("rep", [1, 2])
+def test_chunk_backward_is_the_transpose_of_chunk_forward(rep):
+    """The kernels' bodies call ``chunk_forward`` and the hand-written
+    ``chunk_backward``: the second is jax's transpose of the first."""
+    from mxnet_tpu.kernels.delta_rule import chunk_backward, chunk_forward
+    c, dk, dv = 8, 16, 12
+    r = rep * c
+    s0 = [jnp.asarray(_n((dk, dv), 80 + i, 0.3)) for i in range(rep)]
+    q, k, v = (jnp.asarray(_n(shape, 83 + i)) for i, shape in
+               enumerate([(c, dk), (c, dk), (r, dv)]))
+    run = jnp.concatenate([jnp.cumsum(-jnp.abs(x)) for x in jnp.split(
+        jnp.asarray(_n((r,), 86, 0.5)), rep)])
+    bcol = jax.nn.sigmoid(jnp.asarray(_n((r, 1), 87)))
+    kw = dict(rep=rep, eps=1e-6, scale=dk ** -0.5)
+    primals = (s0, q, k, v, run[:, None], run[None, :], bcol)
+    (_, _, inverse), pull = jax.vjp(
+        lambda *a: chunk_forward(*a, **kw), *primals)
+    do = jnp.asarray(_n((r, dv), 88))
+    ds1 = [jnp.asarray(_n((dk, dv), 89 + i)) for i in range(rep)]
+    want = pull((do, ds1, jnp.zeros_like(inverse)))
+    got = chunk_backward(s0, inverse, *primals[1:], do, ds1, **kw)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4,
+                                   atol=1e-6 * float(jnp.max(jnp.abs(b))))
+
+
+# the compiled tier, through the op, in the Pallas interpreter
+
+@pytest.fixture
+def compiled_tier(monkeypatch):
+    """``gated_delta_rule_op`` as a program lowered for a TPU routes it,
+    with the kernels run by the interpreter."""
+    import functools
+    import mxnet_tpu.kernels as kernels
+    monkeypatch.setattr(
+        kernels, "by_platform", lambda pallas_fn, lax_fn, *args:
+        functools.partial(pallas_fn, interpret=True)(*args))
+
+
+def _routes(since):
+    return [r["ids"] for r in profiler.spans(since=since)
+            if r["name"] == "kernel.route"
+            and r["ids"]["kernel"] == "delta_rule"]
+
+
+@pytest.mark.parametrize("t,chunk,a_shift", [
+    (64, 64, 0.0),                  # a stream of one chunk
+    (96, 16, 0.0),                  # six chunks, two grid steps a row
+    (48, 16, 200.0),                # decays that underflow to 0
+], ids=["one_chunk", "several_chunks", "strong_decay"])
+def test_compiled_delta_rule_matches_recurrence_and_lax_tier(
+        compiled_tier, t, chunk, a_shift):
+    """Two rows (the state is zero at each row's start), two value heads
+    to a key head, heads of 128."""
+    import time
+    from mxnet_tpu.kernels.delta_rule import gated_delta_net_lax
+    from mxnet_tpu.ops.contrib import gated_delta_rule_op
+    args = _rule_inputs(t, seed=50, dk=128, dv=128, hk=1, a_shift=a_shift)
+    since = time.perf_counter()
+    out = gated_delta_rule_op(*args, chunk=chunk)
+    assert _routes(since) == [
+        {"kernel": "delta_rule", "tier": "pallas", "reason": "aligned"}]
+    ref = _rule_oracle(*args)
+    assert out.shape == ref.shape == (2, t, 2, 128)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=2e-6)
+    q, k, v, a, b, a_log, dt_bias = args
+    lax_tier = gated_delta_net_lax(
+        q, k, v, -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias),
+        jax.nn.sigmoid(b), chunk=chunk)
+    np.testing.assert_allclose(out, lax_tier, rtol=1e-4, atol=2e-6)
+
+
+@pytest.mark.parametrize("t,chunk,a_shift", [
+    (64, 64, 0.0), (96, 16, 0.0), (48, 16, 200.0),
+], ids=["one_chunk", "several_chunks", "strong_decay"])
+def test_compiled_delta_rule_gradient_matches_the_recurrences(
+        compiled_tier, t, chunk, a_shift):
+    from mxnet_tpu.ops.contrib import gated_delta_rule_op
+    args = _rule_inputs(t, seed=60, dk=128, dv=128, hk=1, a_shift=a_shift)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(3 * fn(*a))),
+                        argnums=tuple(range(7)))(*args)
+    got = grads(lambda *a: gated_delta_rule_op(*a, chunk=chunk))
+    for a, b in zip(got, grads(_rule_oracle)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(a, b, rtol=2e-3,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7)
+
+
+def test_compiled_delta_rule_on_bfloat16_operands(compiled_tier):
+    """q, k, v arrive in bfloat16 and leave so; inside, the rule is the
+    float32 one on those values: forward and the cotangents agree with the
+    lax tier's to a bfloat16's last place."""
+    from mxnet_tpu.kernels.delta_rule import gated_delta_net_lax
+    from mxnet_tpu.ops.contrib import gated_delta_rule_op
+    args = _rule_inputs(96, seed=70, dk=128, dv=128, hk=1)
+    args = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
+
+    def lax_op(q, k, v, a, b, a_log, dt_bias):
+        return gated_delta_net_lax(
+            q, k, v, -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias),
+            jax.nn.sigmoid(b), chunk=16)
+
+    def both(fn):
+        out, pull = jax.vjp(fn, *args)
+        return (out,) + pull(jnp.ones_like(out))
+    got = both(lambda *a: gated_delta_rule_op(*a, chunk=16))
+    want = both(lax_op)
+    assert got[0].dtype == got[1].dtype == got[3].dtype == jnp.bfloat16
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        a, b = np.asarray(a, "f"), np.asarray(b, "f")
+        np.testing.assert_allclose(a, b, rtol=2 ** -7,
+                                   atol=2 ** -8 * np.abs(b).max())
+
+
+def test_delta_rule_takes_the_lax_tier_for_other_shapes_and_meshes():
+    """Heads that are not lane multiples, a tail that is no whole chunk,
+    and a trace the SPMD partitioner will split: the lax tier, with the
+    reason on the ``kernel.route`` record."""
+    import time
+    from mxnet_tpu.kernels import auto_partitioned
+    from mxnet_tpu.ops.contrib import gated_delta_rule_op
+    before = profiler.counters()
+    since = time.perf_counter()
+    gated_delta_rule_op(*_rule_inputs(150, dk=6), chunk=64)
+    gated_delta_rule_op(*_rule_inputs(150, dk=128, dv=128, hk=1), chunk=64)
+    aligned = _rule_inputs(64, dk=128, dv=128, hk=1)
+    with auto_partitioned():
+        jaxpr = jax.make_jaxpr(
+            lambda *a: gated_delta_rule_op(*a, chunk=64))(*aligned)
+    assert "pallas_call" not in str(jaxpr)
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda *a: gated_delta_rule_op(*a, chunk=64))(*aligned))
+    assert _routes(since) == [
+        {"kernel": "delta_rule", "tier": "lax", "reason": "shapes"},
+        {"kernel": "delta_rule", "tier": "lax", "reason": "shapes"},
+        {"kernel": "delta_rule", "tier": "lax", "reason": "mesh"},
+        {"kernel": "delta_rule", "tier": "pallas", "reason": "aligned"}]
+    after = profiler.counters()
+    assert after["kernel.delta_rule.lax"] \
+        - before.get("kernel.delta_rule.lax", 0) == 3
+    assert after["kernel.delta_rule.pallas"] \
+        - before.get("kernel.delta_rule.pallas", 0) == 1
+
+
+def test_mxlint_finds_the_delta_rule_kernels_behind_their_vjp():
+    """``graph-pallas-no-vjp`` on a graph that holds the op with both
+    tiers traced (``lax.platform_dependent``): the kernel is there, and
+    it is behind its ``custom_vjp``."""
+    from mxnet_tpu.analysis import graph_lint
+    from mxnet_tpu.ops.contrib import gated_delta_rule_op
+    args = _rule_inputs(64, dk=128, dv=128, hk=1)
+
+    def graph(*a):
+        return gated_delta_rule_op(*a, chunk=64)
+    report = graph_lint.lint_jit(graph, *args, expect_allgather=False,
+                                 min_donate_bytes=0)
+    assert "pallas_call" in str(jax.make_jaxpr(graph)(*args))
+    assert "graph-pallas-no-vjp" not in {f.rule for f in report.findings}, \
+        report.format_text()
 
 
 # -- the routed-expert layer ---------------------------------------------------
@@ -513,12 +679,13 @@ def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
 
 # -- the whole model against the plain reference, through fit ----------------
 
-def _toy_model(seq_len=80, held=4, offset=4):
+def _toy_model(seq_len=80, held=4, offset=4, **widths):
     from benchmark.reference import qwen3_next as ref
     from mxnet_tpu.models.qwen3_next import qwen3_next_sym
+    toy = dict(TOY, **widths)
     sym = qwen3_next_sym(seq_len, num_experts=16, num_experts_held=held,
-                         expert_offset=offset, **TOY)[0]
-    cfg = dict(TOY, num_experts=held, num_routed_experts=16,
+                         expert_offset=offset, **toy)[0]
+    cfg = dict(toy, num_experts=held, num_routed_experts=16,
                expert_offset=offset, seq_len=seq_len)
     params, _ = ref.init(jax.random.PRNGKey(0), cfg)
     # larger than the family's 0.02 so that every nonlinearity is exercised
@@ -622,6 +789,41 @@ def test_staged_remat_gives_the_same_gradients_and_names_the_stages():
     text = staged.lower(params).as_text(debug_info=True)
     assert "jvp(l0_gdn)" in text and "transpose(jvp(l3_attn))" in text
     assert "transpose(jvp(l2_moe))" in text
+
+
+def test_staged_model_through_the_compiled_delta_rule(compiled_tier):
+    """DeltaNet heads of 128 and rows of whole chunks: inside each
+    rematerialised stage the op takes the compiled tier (the interpreter
+    here), forward, again in the stage's backward, and its kernel
+    backward; outputs and every gradient equal the lax tier's."""
+    import time
+    from mxnet_tpu import kernels
+    from mxnet_tpu.executor import _build_eval
+    sym, cfg, params = _toy_model(
+        seq_len=64, linear_num_key_heads=1, linear_num_value_heads=2,
+        linear_key_head_dim=128, linear_value_head_dim=128)
+    rs = RS(4)
+    inputs = dict(params, data=rs.randint(0, 300, (2, 64)),
+                  softmax_label=rs.randint(0, 300, (2, 64)))
+    fn = _build_eval(sym)
+
+    def loss(p):
+        outs, _ = fn(dict(inputs, **p), {}, jax.random.PRNGKey(0), True)
+        return tuple(outs)
+
+    def grads(p):
+        return jax.vjp(loss, p)[1](tuple(jnp.ones_like(o)
+                                         for o in loss(p)))[0]
+    since = time.perf_counter()
+    compiled = jax.jit(grads)(params)
+    assert {r["tier"] for r in _routes(since)} == {"pallas"}
+    with kernels.auto_partitioned():         # the lax tier, by its rule
+        since = time.perf_counter()
+        plain = jax.jit(lambda p: grads(p))(params)
+        assert {r["reason"] for r in _routes(since)} == {"mesh"}
+    for k in params:
+        np.testing.assert_allclose(compiled[k], plain[k], rtol=2e-4,
+                                   atol=1e-6, err_msg=k)
 
 
 # -- counters the graph computes, settled one step late -----------------------
