@@ -236,6 +236,23 @@ class LogUniform(Initializer):
 
 
 @register
+class StepSizeBias(Initializer):
+    """softplus^-1 of a step size drawn log-uniformly from [low, high]: the
+    ``dt_bias`` leaves of gated linear-attention mixers, as their families'
+    code draws them (``softplus(dt_bias)`` is then that step size)."""
+
+    def __init__(self, low=1e-3, high=0.1):
+        super().__init__(low=low, high=high)
+        self.low, self.high = low, high
+
+    def _init_weight(self, _, arr):
+        _random.uniform(np.log(self.low), np.log(self.high), out=arr,
+                        shape=arr.shape)
+        dt = np.maximum(np.exp(arr.asnumpy()), 1e-4)
+        arr[:] = dt + np.log(-np.expm1(-dt))
+
+
+@register
 class Orthogonal(Initializer):
     """Orthogonal matrix init (reference initializer.py:Orthogonal)."""
 

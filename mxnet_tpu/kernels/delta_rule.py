@@ -25,7 +25,12 @@ Two tiers, one algorithm (package docstring):
   chunk's tiles at once, then a ``lax.scan`` over the chunks; the
   triangular inverse has its own backward (two products) so that its
   doubling steps are not saved.  Every platform lowers it; it is the
-  numeric oracle.
+  numeric oracle.  It also takes a decay per key channel (Kimi Delta
+  Attention: ``S = diag(exp(g_t)) S``, ``G`` a vector over dk): then
+  ``L_ij = beta_i sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])`` no longer
+  factors as a product times a decay, and the chunk's tiles are formed by
+  sub-blocks inside the walk (:func:`_channel_tiles`), the inverse by
+  substitution (:func:`_unit_lower_inverse_blocked_impl`).
 - :func:`gated_delta_net_pallas` — ``mxtpu_delta_rule_fwd`` /
   ``mxtpu_delta_rule_bwd`` behind one ``jax.custom_vjp``: a grid over
   (row, key head) streams and, innermost and sequential, the row's
@@ -94,12 +99,147 @@ def _uli_bwd(inv, g):
 _unit_lower_inverse.defvjp(_uli_fwd, _uli_bwd)
 
 
+#: positions of a sub-block of a chunk under a decay per key channel: of
+#: its decayed products and of its triangular inverse
+_SUB = 16
+
+
+def _unit_lower_inverse_blocked_impl(low, sub=_SUB):
+    """(I + L)^-1 for strictly lower triangular ``L`` (..., C, C), without
+    the doubling's powers of L: the diagonal blocks of ``sub`` rows by
+    forward substitution, row after row, then pairs of neighbours merged —
+    ``[[A, 0], [B, D]]^-1 = [[A^-1, 0], [-D^-1 B A^-1, D^-1]]`` — until one
+    block is left.  :func:`_unit_lower_inverse_impl` sums (-L)^i, whose
+    terms reach C(C - 2, C / 2 - 1) b^i where the inverse itself stays
+    under 1 (L = b x ones: slowly decaying, alike keys): in float32 they
+    cancel to garbage (160 at b = 0.5, 9e10 at 0.99, C = 64).  A slow decay
+    a key channel meets that case; this form has no such terms."""
+    C = low.shape[-1]
+    m = C // sub if C % sub == 0 else 1
+    if m & (m - 1):                    # pairs need a power of two
+        m = 1
+    size = C // m
+    eye = jnp.eye(size, dtype=low.dtype)
+    x = jnp.stack([low[..., i * size:(i + 1) * size,
+                       i * size:(i + 1) * size] for i in range(m)], axis=-3)
+    inv = jnp.zeros_like(x)
+    for i in range(size):              # rows past i are still zero
+        row = eye[i] - jnp.einsum("...j,...jk->...k", x[..., i, :], inv,
+                                  precision=_HIGHEST)
+        inv = inv.at[..., i, :].set(row)
+    parts = [inv[..., i, :, :] for i in range(m)]
+    while len(parts) > 1:
+        merged = []
+        for i in range(0, len(parts), 2):
+            a, d = parts[i], parts[i + 1]
+            at = i * size
+            b = low[..., at + size:at + 2 * size, at:at + size]
+            zeros = jnp.zeros_like(a)
+            merged.append(jnp.concatenate([
+                jnp.concatenate([a, zeros], axis=-1),
+                jnp.concatenate([-_mm(_mm(d, b), a), d], axis=-1)], axis=-2))
+        parts, size = merged, 2 * size
+    return parts[0]
+
+
+@jax.custom_vjp
+def _unit_lower_inverse_blocked(low):
+    return _unit_lower_inverse_blocked_impl(low)
+
+
+def _ulib_fwd(low):
+    inv = _unit_lower_inverse_blocked_impl(low)
+    return inv, inv
+
+
+_unit_lower_inverse_blocked.defvjp(_ulib_fwd, _uli_bwd)
+
+
+def _channel_tiles(q, k, gc, sub):
+    """The chunk-local tiles under a decay per key channel: for q, k and
+    the running log-decay gc (..., C, dk) the lower triangles (diagonal
+    included, zero above) of ``sum_d k_i[d] k_j[d] exp(gc_i[d] - gc_j[d])``
+    and of the same with ``q_i`` — the decay sits inside the contraction,
+    and ``(q e^gc)(k e^-gc)^T`` would overflow.  By sub-blocks of ``sub``
+    positions: a sub-block against itself takes the differences directly
+    (an elementwise product summed over dk); against an earlier one it is a
+    matrix product of ``x_i exp(gc_i - gc_s)`` and ``k_j exp(gc_s - gc_j)``
+    about the log-decay gc_s at its own first position s, both factors <=
+    1.  No exponent of a positive number is formed."""
+    lead, (C, dk) = q.shape[:-2], q.shape[-2:]
+    m = C // sub
+
+    def blocks(x):
+        return x.reshape(lead + (m, sub, dk))
+    qb, kb, gb = blocks(q), blocks(k), blocks(gc)
+    below = jnp.tril(jnp.ones((sub, sub), bool), -1)[..., None]
+    diff = gb[..., :, None, :] - gb[..., None, :, :]    # (.., m, sub, sub, dk)
+    # a position against itself decays by exp(0): a constant, so that no
+    # cotangent goes out to gc and comes back to cancel in round-off
+    near = jnp.where(below, jnp.exp(jnp.where(below, diff, 0.0)),
+                     jnp.eye(sub, dtype=q.dtype)[..., None])
+    kj = kb[..., None, :, :] * near
+    kk_near = jnp.sum(kb[..., :, None, :] * kj, axis=-1)
+    qk_near = jnp.sum(qb[..., :, None, :] * kj, axis=-1)  # (.., m, sub, sub)
+    start = gb[..., :1, :]                              # (.., m, 1, dk)
+    into = jnp.exp(gb - start)                          # (.., m, sub, dk)
+    # k_j about every later sub-block's start: (.., m, C, dk), j before it
+    before = (jnp.arange(C)[None, :] < sub * jnp.arange(m)[:, None])[..., None]
+    back = start - gc[..., None, :, :]
+    kfar = jnp.where(before, jnp.exp(jnp.where(before, back, 0.0)), 0.0) \
+        * k[..., None, :, :]
+    kfar_t = jnp.swapaxes(kfar, -1, -2)
+    own = jnp.eye(m, dtype=bool)[:, None, :, None]      # (m, 1, m, 1)
+
+    def whole(far, near_blocks):
+        far = far.reshape(lead + (m, sub, m, sub))
+        return jnp.where(own, near_blocks[..., :, :, None, :],
+                         far).reshape(lead + (C, C))
+    return (whole(_mm(kb * into, kfar_t), kk_near),
+            whole(_mm(qb * into, kfar_t), qk_near))
+
+
+def _channel_chunk_local(q, k, v, g, beta, sub):
+    """What the chunks (..., C, d) compute before they meet the state,
+    under a decay per key channel: (w, u, a, qg, kg, g_last) of the module
+    docstring with ``L = strict_lower(beta_i sum_d k_i k_j e^(G_i - G_j))``."""
+    gc = jnp.cumsum(g, axis=-2)
+    kk, qk = _channel_tiles(q, k, gc, sub)
+    inv = _unit_lower_inverse_blocked(jnp.tril(beta[..., None] * kk, -1))
+    eg = jnp.exp(gc)
+    w = _mm(inv, k * beta[..., None] * eg)
+    u = _mm(inv, v * beta[..., None])
+    kg = k * jnp.exp(gc[..., -1:, :] - gc)
+    return w, u, qk, q * eg, kg, jnp.exp(gc[..., -1, :])[..., None]
+
+
+def _walk_step(s, xs):
+    """One chunk of the walk: ``xs`` = (w, u, a, qg, kg, g_last) of the
+    chunk, from the state ``s`` (B, H, dk, dv) -> (state after, o)."""
+    w_n, u_n, a_n, qg_n, kg_n, gl_n = xs
+    v_new = u_n - jnp.matmul(w_n, s)
+    o_n = jnp.matmul(qg_n, s) + jnp.matmul(a_n, v_new)
+    s = s * gl_n + jnp.matmul(jnp.swapaxes(kg_n, -1, -2), v_new)
+    return s, o_n
+
+
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def gated_delta_rule(q, k, v, g, beta, chunk=64):
-    """q, k (B, T, H, dk); v (B, T, H, dv); g (log-decay, <= 0) and beta
-    (B, T, H); all heads already expanded to the value heads.  Returns o
-    (B, T, H, dv) in float32.  ``T`` need not be a multiple of ``chunk``:
-    the tail is padded with positions that leave the state as it is."""
+    """q, k (B, T, H, dk); v (B, T, H, dv); beta (B, T, H); g (log-decay,
+    <= 0) either (B, T, H), one decay a head, or (B, T, H, dk), one a key
+    channel (the state's rows decay each at its own rate); all heads
+    already expanded to the value heads.  Returns o (B, T, H, dv) in
+    float32.  ``T`` need not be a multiple of ``chunk``: the tail is padded
+    with positions that leave the state as it is.
+
+    The scalar decay factors out of the chunk-local products, which are
+    formed for every chunk at once before the walk.  The vector decay does
+    not (:func:`_channel_tiles`): each step of its walk forms its own
+    chunk's tiles and is rematerialised going backward, so that neither
+    pass holds more than a chunk's sub-block differences — a step's are
+    gigabytes.  (Two and four chunks a step were slower on the chip: 141
+    and 153 ms forward + backward against 112-116 at 2 x 8,192 positions
+    of 32 heads of 128, PR 31.)"""
     f32 = jnp.float32
     B, T, H, dk = q.shape
     dv = v.shape[-1]
@@ -115,30 +255,31 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64):
         return jnp.moveaxis(x, 3, 1)
 
     q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
-    gc = jnp.cumsum(g, axis=-1)                         # (B, H, n, C)
-    lower = jnp.tril(jnp.ones((C, C), bool))
-    diff = gc[..., :, None] - gc[..., None, :]
-    decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
-    kb, vb = k * beta[..., None], v * beta[..., None]
-    kt = jnp.swapaxes(k, -1, -2)
-    inv = _unit_lower_inverse(jnp.tril(_mm(kb, kt) * decay, -1))
-    w = _mm(inv, kb * jnp.exp(gc)[..., None])           # (B, H, n, C, dk)
-    u = _mm(inv, vb)                                    # (B, H, n, C, dv)
-    a = _mm(q, kt) * decay                              # (B, H, n, C, C)
-    qg = q * jnp.exp(gc)[..., None]
-    kg = k * jnp.exp(gc[..., -1:] - gc)[..., None]
-    g_last = jnp.exp(gc[..., -1])                       # (B, H, n)
+    s0 = jnp.zeros((B, H, dk, dv), f32)
+    if g.ndim == q.ndim:
+        sub = _SUB if C % _SUB == 0 else C
 
-    def step(s, xs):
-        w_n, u_n, a_n, qg_n, kg_n, gl_n = xs
-        v_new = u_n - jnp.matmul(w_n, s)
-        o_n = jnp.matmul(qg_n, s) + jnp.matmul(a_n, v_new)
-        s = s * gl_n[..., None, None] + jnp.matmul(
-            jnp.swapaxes(kg_n, -1, -2), v_new)
-        return s, o_n
-
-    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (w, u, a, qg, kg, g_last))
-    _, o = lax.scan(step, jnp.zeros((B, H, dk, dv), f32), xs)
+        @jax.checkpoint
+        def chunk_step(s, xs):
+            return _walk_step(s, _channel_chunk_local(*xs, sub))
+        _, o = lax.scan(chunk_step, s0, tuple(
+            jnp.moveaxis(x, 2, 0) for x in (q, k, v, g, beta)))
+    else:
+        gc = jnp.cumsum(g, axis=-1)                     # (B, H, n, C)
+        lower = jnp.tril(jnp.ones((C, C), bool))
+        diff = gc[..., :, None] - gc[..., None, :]
+        decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+        kb, vb = k * beta[..., None], v * beta[..., None]
+        kt = jnp.swapaxes(k, -1, -2)
+        inv = _unit_lower_inverse(jnp.tril(_mm(kb, kt) * decay, -1))
+        w = _mm(inv, kb * jnp.exp(gc)[..., None])       # (B, H, n, C, dk)
+        u = _mm(inv, vb)                                # (B, H, n, C, dv)
+        a = _mm(q, kt) * decay                          # (B, H, n, C, C)
+        qg = q * jnp.exp(gc)[..., None]
+        kg = k * jnp.exp(gc[..., -1:] - gc)[..., None]
+        g_last = jnp.exp(gc[..., -1])[..., None, None]  # (B, H, n, 1, 1)
+        _, o = lax.scan(_walk_step, s0, tuple(
+            jnp.moveaxis(x, 2, 0) for x in (w, u, a, qg, kg, g_last)))
     o = jnp.moveaxis(o, 0, 2)                           # (B, H, n, C, dv)
     o = jnp.moveaxis(o, 1, 3).reshape(B, n * C, H, dv)
     return o[:, :T]
@@ -653,10 +794,12 @@ def gated_delta_net_lax(query, key, value, g, beta, chunk=64, eps=1e-6):
     return out.astype(value.dtype)
 
 
-def _lax_reason(query, value, chunk):
+def _lax_reason(query, value, g, chunk):
     """Why these operands are not the compiled tier's, or None."""
     from . import partitioned
     (_, T, Hk, dk), (Hv, dv) = query.shape, value.shape[2:]
+    if g.ndim == 4:
+        return "channel_decay"
     if partitioned():
         return "mesh"
     if dk % 128 or dv % 128 or Hv % Hk or T % int(chunk) or int(chunk) % 16:
@@ -666,20 +809,23 @@ def _lax_reason(query, value, chunk):
 
 def gated_delta_net(query, key, value, g, beta, chunk=64, eps=1e-6):
     """The gated delta rule on operands as the graph has them: query, key
-    (B, T, Hk, dk), value (B, T, Hv, dv), g (log-decay, <= 0) and beta (B,
-    T, Hv), each key head serving ``Hv // Hk`` consecutive value heads;
-    query and key are L2-normalised per head (``eps``) and the query
-    scaled by dk^-0.5.  Returns o like value.
+    (B, T, Hk, dk), value (B, T, Hv, dv), beta (B, T, Hv) and the log-decay
+    g (<= 0), (B, T, Hv) or — a decay per key channel — (B, T, Hv, dk),
+    each key head serving ``Hv // Hk`` consecutive value heads; query and
+    key are L2-normalised per head (``eps``) and the query scaled by
+    dk^-0.5.  Returns o like value.
 
     Which tier runs follows from what the trace can see: the compiled
-    kernels in a program lowered for a TPU, for lane-aligned heads and
-    whole chunks; the lax tier on other platforms, for other shapes (it
-    pads the tail) and in a program the SPMD partitioner will split.
-    Each call records one ``kernel.route`` event in the program's
-    recorder with the kernel, the tier and the reason."""
+    kernels in a program lowered for a TPU, for a scalar decay,
+    lane-aligned heads and whole chunks; the lax tier on other platforms,
+    for a vector decay (the kernels carry one decay a head), for other
+    shapes (it pads the tail) and in a program the SPMD partitioner will
+    split.  Each call records one ``kernel.route`` event in the program's
+    recorder with the kernel, the tier and the reason (``aligned``,
+    ``channel_decay``, ``mesh``, ``shapes``)."""
     from .. import profiler
     from . import by_platform
-    reason = _lax_reason(query, value, chunk)
+    reason = _lax_reason(query, value, g, chunk)
     tier = "lax" if reason else "pallas"
     now = time.perf_counter_ns()
     profiler.event("kernel.route", now, now, kernel="delta_rule", tier=tier,

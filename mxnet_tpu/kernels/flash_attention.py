@@ -297,6 +297,28 @@ def _gqa_blocks(T, block_q):
     return bq, -(-T // bq)
 
 
+#: float32 score tiles of ALL query blocks together past which the blocks
+#: are chained.  The blocks do not depend on one another, and the TPU
+#: scheduler then holds every block's tile at once: at 32 heads x 2 rows of
+#: 8,192 positions that is 8.6 GB of a 16 GB chip, and the step does not
+#: compile (PR 31); at 16 heads it is 4.3 GB and fits.  Chained where it
+#: need not be, a step holds 1.5 GB less and runs 1.2 % slower (the
+#: scheduler can no longer start a block under the one before it: the
+#: 16-head cell read 30,604 -> 30,244 tok/s in 2 of 2 pairs, PR 31), so
+#: the blocks are chained only where they must be.
+_CHAIN_BYTES = 6 << 30
+
+
+def _gqa_chained(B, Hq, T):
+    return 2 * B * Hq * T * T > _CHAIN_BYTES          # 4 bytes x T^2 / 2
+
+
+def _after(x, done):
+    """``x``, not to be computed before ``done`` is: one query block's
+    operand held back until the block before it has finished."""
+    return x if done is None else lax.optimization_barrier((x, done))[0]
+
+
 def _gqa_scores(qi, k, i, bq, scale):
     """Scores of query block ``i`` against its causal key prefix, f32:
     qi (B, bq, Hkv, G, D), k (B, Lk, Hkv, D) -> (B, Hkv, G, bq, Lk)."""
@@ -312,10 +334,12 @@ def _gqa_fwd_blocks(q, k, v, scale, block_q):
     Hkv = k.shape[2]
     bq, nq = _gqa_blocks(T, block_q)
     q5 = q.reshape(B, T, Hkv, Hq // Hkv, D)
+    chained = _gqa_chained(B, Hq, T)
     outs, lses = [], []
     for i in range(nq):
         lo, hi = i * bq, min((i + 1) * bq, T)
-        s = _gqa_scores(q5[:, lo:hi], k[:, :hi], i, bq, scale)
+        qi = _after(q5[:, lo:hi], outs[-1] if chained and outs else None)
+        s = _gqa_scores(qi, k[:, :hi], i, bq, scale)
         m = jnp.max(s, axis=-1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
@@ -323,7 +347,7 @@ def _gqa_fwd_blocks(q, k, v, scale, block_q):
                        preferred_element_type=jnp.float32)
         outs.append(o / jnp.moveaxis(l, (1, 2, 3), (2, 3, 1)))
         lses.append((m + jnp.log(l))[..., 0])           # (B, Hkv, G, bq)
-    out = jnp.concatenate(outs, axis=1).reshape(B, T, Hq, D)
+    out = jnp.concatenate(outs, axis=1).reshape(B, T, Hq, v.shape[-1])
     return out.astype(q.dtype), jnp.concatenate(lses, axis=-1)
 
 
@@ -344,17 +368,19 @@ def _gqa_bwd(scale, block_q, res, g):
     G = Hq // Hkv
     bq, nq = _gqa_blocks(T, block_q)
     q5 = q.reshape(B, T, Hkv, G, D)
-    g5 = g.reshape(B, T, Hkv, G, D)
+    g5 = g.reshape(B, T, Hkv, G, v.shape[-1])
     # rowsum(dO * O): what the softmax's backward subtracts in each row
-    delta = jnp.sum(g5.astype(jnp.float32) * out.reshape(q5.shape)
+    delta = jnp.sum(g5.astype(jnp.float32) * out.reshape(g5.shape)
                     .astype(jnp.float32), axis=-1)      # (B, T, Hkv, G)
     delta = jnp.moveaxis(delta, 1, 3)                   # (B, Hkv, G, T)
     dk = jnp.zeros(k.shape, jnp.float32)
     dv = jnp.zeros(v.shape, jnp.float32)
+    chained = _gqa_chained(B, Hq, T)
     dqs = []
     for i in range(nq):
         lo, hi = i * bq, min((i + 1) * bq, T)
         qi, gi = q5[:, lo:hi], g5[:, lo:hi]
+        qi = _after(qi, dqs[-1] if chained and dqs else None)
         s = _gqa_scores(qi, k[:, :hi], i, bq, scale)
         p = jnp.exp(s - lse[..., lo:hi, None])
         dp = jnp.einsum("bqhgd,bkhd->bhgqk", gi, v[:, :hi],
@@ -377,9 +403,11 @@ _gqa.defvjp(_gqa_fwd, _gqa_bwd)
 
 
 def gqa_attention(q, k, v, scale=None, block_q=512):
-    """Causal grouped-query attention.  q (B, T, Hq, D); k, v
-    (B, T, Hkv, D) with ``Hq % Hkv == 0``: key/value head ``h`` serves
-    query heads ``h*G .. h*G+G-1``.  Returns (B, T, Hq, D) in q's dtype.
+    """Causal grouped-query attention.  q (B, T, Hq, D); k (B, T, Hkv, D)
+    and v (B, T, Hkv, Dv) with ``Hq % Hkv == 0``: key/value head ``h``
+    serves query heads ``h*G .. h*G+G-1``.  The value heads may be
+    narrower (or wider) than the query / key heads: the result follows
+    the value, (B, T, Hq, Dv), in q's dtype.
 
     Query rows go in blocks of ``block_q``; block ``i`` meets only its
     causal prefix of keys (a static slice), so the work is the lower

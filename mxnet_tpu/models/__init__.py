@@ -8,6 +8,7 @@ from . import lenet, mlp, alexnet, vgg, resnet, inception_bn, mobilenet
 from . import googlenet, inception_v3, resnext
 from . import lstm_lm
 from . import qwen3_next
+from . import kimi_linear
 
 _BUILDERS = {
     "lenet": lenet.get_symbol,

@@ -31,11 +31,14 @@ MOE_COUNTERS = ("moe.assignments", "moe.assignments_here", "moe.load_max",
                 "moe.load_mean", "moe.calls", "moe.compact_calls")
 
 
-def _norm(x, name, width, zero_centered=True, gate=None, eps=1e-6):
+def _norm(x, name, width, zero_centered=True, gate=None, eps=1e-6,
+          gate_act="silu"):
     gamma = sym.Variable(name + "_gamma", shape=(width,),
                          init=initializer.Zero() if zero_centered
                          else initializer.One())
     kw = {"gate": gate, "gated": True} if gate is not None else {}
+    if gate_act != "silu":
+        kw["gate_act"] = gate_act
     return sym.RMSNorm(data=x, gamma=gamma, eps=eps,
                        zero_centered=zero_centered, name=name, **kw)
 
@@ -127,6 +130,26 @@ def _experts(x, p, c, held, offset):
     return routed[0] + sym.broadcast_mul(shared, share), routed[1]
 
 
+def _head(h, label, stats, seq_len, hidden, vocab, eps, zero_centered=True):
+    """The residual stream (batch * T, hidden) -> (symbol, data names, label
+    names): final norm, untied head over time-major rows, ``SoftmaxOutput``
+    against ``label`` (batch, T) with its gradient scaled by 1 / T, and the
+    expert layers' counters as the second head."""
+    # (batch * T, H) -> time-major rows (T * batch, H), as the label's
+    h = sym.SwapAxis(sym.Reshape(h, shape=(-1, seq_len, hidden)),
+                     dim1=0, dim2=1)
+    h = _norm(sym.Reshape(h, shape=(-1, hidden)), "head_norm", hidden,
+              zero_centered=zero_centered, eps=eps)
+    logits = _linear(h, "head", vocab)
+    lab = sym.Reshape(sym.SwapAxis(label, dim1=0, dim2=1), shape=(-1,))
+    out = sym.SoftmaxOutput(data=logits, label=lab,
+                            grad_scale=1.0 / seq_len, name="softmax")
+    counters = sym.RoutedExpertsStats(
+        *stats, name="moe_counters",
+        attr={"__step_counters__": ",".join(MOE_COUNTERS)})
+    return sym.Group([out, counters]), ("data",), ("softmax_label",)
+
+
 def qwen3_next_sym(seq_len, num_experts_held=None, expert_offset=0,
                    **config):
     """The training symbol for rows of ``seq_len`` tokens: data (batch,
@@ -164,16 +187,4 @@ def qwen3_next_sym(seq_len, num_experts_held=None, expert_offset=0,
                                  c, held, int(expert_offset))
             h = h + out
             stats.append(stat)
-    # (batch * T, H) -> time-major rows (T * batch, H), as the label's
-    h = sym.SwapAxis(sym.Reshape(h, shape=(-1, seq_len, hidden)),
-                     dim1=0, dim2=1)
-    h = _norm(sym.Reshape(h, shape=(-1, hidden)), "head_norm", hidden,
-              eps=eps)
-    logits = _linear(h, "head", c["vocab_size"])
-    lab = sym.Reshape(sym.SwapAxis(label, dim1=0, dim2=1), shape=(-1,))
-    out = sym.SoftmaxOutput(data=logits, label=lab,
-                            grad_scale=1.0 / seq_len, name="softmax")
-    counters = sym.RoutedExpertsStats(
-        *stats, name="moe_counters",
-        attr={"__step_counters__": ",".join(MOE_COUNTERS)})
-    return sym.Group([out, counters]), ("data",), ("softmax_label",)
+    return _head(h, label, stats, seq_len, hidden, c["vocab_size"], eps)

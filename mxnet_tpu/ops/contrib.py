@@ -543,25 +543,31 @@ def contrib_attention(query, key, value, num_heads=1, causal=False,
 # lax.ragged_dot.
 # ---------------------------------------------------------------------------
 
-def _first_input_infer(attrs, in_shapes):
-    """The output has the first input's shape; every input's shape is the
-    caller's to give (the model builder declares its variables' shapes)."""
-    first = in_shapes[0]
-    return list(in_shapes), [None if first is None else tuple(first)], []
+def _gq_attention_infer(attrs, in_shapes):
+    """The output has the query's positions and heads and the value's head
+    size; every input's shape is the caller's to give (the model builder
+    declares its variables' shapes)."""
+    query, value = in_shapes[0], in_shapes[2]
+    out = None if query is None or value is None \
+        else tuple(query[:-1]) + (value[-1],)
+    return list(in_shapes), [out], []
 
 
 @register("_contrib_GQAttention", aliases=("GQAttention",),
           input_names=lambda attrs: ("query", "key", "value", "gate")
           if attrs.get("gated", False) else ("query", "key", "value"),
-          infer_shape=_first_input_infer)
+          infer_shape=_gq_attention_infer)
 def gq_attention(query, key, value, gate=None, scale=-1.0, block_q=512,
                  gated=False):
     """Causal grouped-query softmax attention that never holds a
     (positions x positions) matrix.  query (batch, positions, query_heads,
-    head_dim); key, value (batch, positions, kv_heads, head_dim), each
-    key/value head serving ``query_heads // kv_heads`` consecutive query
-    heads.  ``scale`` <= 0 means head_dim^-0.5.  With ``gated`` the result
-    is multiplied by ``sigmoid(gate)`` (gate shaped like query)."""
+    head_dim); key (batch, positions, kv_heads, head_dim) and value (batch,
+    positions, kv_heads, value_dim), each key/value head serving
+    ``query_heads // kv_heads`` consecutive query heads.  ``value_dim`` need
+    not be ``head_dim`` (latent attention: 192-wide keys, 128-wide values);
+    the output is (batch, positions, query_heads, value_dim).  ``scale``
+    <= 0 means head_dim^-0.5.  With ``gated`` the result is multiplied by
+    ``sigmoid(gate)`` (gate shaped like the output)."""
     from ..kernels.flash_attention import gqa_attention
     out = gqa_attention(query, key, value,
                         scale=None if float(scale) <= 0 else float(scale),
@@ -582,21 +588,29 @@ def _delta_rule_infer(attrs, in_shapes):
           infer_shape=_delta_rule_infer)
 def gated_delta_rule_op(query, key, value, a, b, A_log, dt_bias, chunk=64,
                         eps=1e-6):
-    """Gated delta rule (Gated DeltaNet), computed in chunks of ``chunk``
-    positions.  query, key (batch, positions, key_heads, dk); value (batch,
-    positions, value_heads, dv); a, b (batch, positions, value_heads);
-    A_log, dt_bias (value_heads,).  Each key head serves ``value_heads //
-    key_heads`` consecutive value heads.  In float32: query and key are
-    L2-normalised per head (``eps``), query scaled by dk^-0.5,
-    ``beta = sigmoid(b)``, ``g = -exp(A_log) * softplus(a + dt_bias)``; per
-    value head, from a zero state, position t does ``S = exp(g_t) S;
-    u = (v_t - S^T k_t) beta_t; S = S + k_t u^T; o_t = S^T q_t``.  Returns
-    o shaped like value."""
+    """Gated delta rule, computed in chunks of ``chunk`` positions.  query,
+    key (batch, positions, key_heads, dk); value (batch, positions,
+    value_heads, dv); b (batch, positions, value_heads); A_log
+    (value_heads,).  Each key head serves ``value_heads // key_heads``
+    consecutive value heads.  The rank of ``a`` says which rule: (batch,
+    positions, value_heads) with dt_bias (value_heads,) is Gated DeltaNet's
+    one decay a head; (batch, positions, value_heads, dk) with dt_bias
+    (value_heads * dk,) is Kimi Delta Attention's decay a key channel.  In
+    float32: query and key are L2-normalised per head (``eps``), query
+    scaled by dk^-0.5, ``beta = sigmoid(b)``, ``g = -exp(A_log) *
+    softplus(a + dt_bias)`` (``A_log`` a head's, whichever rank); per value
+    head, from a zero state, position t does ``S = exp(g_t) S`` — ``S =
+    diag(exp(g_t)) S``, row by row, for the vector — ``u = (v_t - S^T k_t)
+    beta_t; S = S + k_t u^T; o_t = S^T q_t``.  Returns o shaped like
+    value."""
     from ..kernels.delta_rule import gated_delta_net
     f32 = jnp.float32
     beta = jax.nn.sigmoid(b.astype(f32))
-    g = -jnp.exp(A_log.astype(f32)) * jax.nn.softplus(
-        a.astype(f32) + dt_bias.astype(f32))
+    rate = jnp.exp(A_log.astype(f32))
+    if a.ndim == 4:
+        rate = rate[:, None]
+    g = -rate * jax.nn.softplus(
+        a.astype(f32) + dt_bias.astype(f32).reshape(a.shape[2:]))
     return gated_delta_net(query, key, value, g, beta, chunk=int(chunk),
                            eps=float(eps))
 
@@ -781,22 +795,30 @@ def _routed_infer(attrs, in_shapes):
 
 
 @register("_contrib_RoutedExperts", aliases=("RoutedExperts",),
-          input_names=("data", "router_weight", "gate_up_weight",
-                       "down_weight"),
+          input_names=lambda attrs: (
+              "data", "router_weight", "gate_up_weight", "down_weight")
+          + (("select_bias",) if attrs.get("use_select_bias", False)
+             else ()),
           num_outputs=2, output_names=("output", "stats"),
           infer_shape=_routed_infer)
 def routed_experts(data, router_weight, gate_up_weight, down_weight,
-                   top_k=1, expert_offset=0, norm_topk_prob=True):
+                   select_bias=None, top_k=1, expert_offset=0,
+                   norm_topk_prob=True, score_func="softmax",
+                   routed_scaling_factor=1.0, use_select_bias=False):
     """Dropless top-``top_k`` routed experts, told which experts it holds.
     data (tokens, hidden); router_weight (num_experts, hidden) over ALL the
     experts of the layer; gate_up_weight (held, hidden, 2 x width) and
     down_weight (held, width, hidden) of the experts ``expert_offset ..
     expert_offset + held - 1`` that live here.  In float32
-    ``p = softmax(data @ router_weight.T)`` over all experts; each token
-    takes its ``top_k`` largest (divided by their sum with
-    ``norm_topk_prob``); expert e gives ``(silu(x @ gate_e) * (x @ up_e)) @
-    down_e``.  Output 0 is the part of the weighted sum that the held
-    experts give — a token none of whose experts is held gets zeros; what
+    ``p = score_func(data @ router_weight.T)`` over all experts
+    (``softmax`` or ``sigmoid``); each token takes the ``top_k`` experts
+    whose ``p`` is largest — whose ``p + select_bias`` is, with
+    ``use_select_bias`` and that (num_experts,) input, which enters the
+    choice alone and so gets no gradient — and weighs them by ``p``
+    (divided by their sum with ``norm_topk_prob``) times
+    ``routed_scaling_factor``; expert e gives ``(silu(x @ gate_e) * (x @
+    up_e)) @ down_e``.  Output 0 is the part of the weighted sum that the
+    held experts give — a token none of whose experts is held gets zeros; what
     the other experts would add is their chips' to compute.  Output 1 (no
     gradient) is six float32 counts of this call: token-expert pairs
     routed, those that landed on held experts, the fullest held expert's
@@ -815,24 +837,37 @@ def routed_experts(data, router_weight, gate_up_weight, down_weight,
     trip count ``ceil(n / C)`` is counted on the device: one block on a
     step whose held pairs fit ``C``, more on a step that overflows it —
     that step is slower, never different."""
+    if score_func not in ("softmax", "sigmoid"):
+        raise MXNetError("RoutedExperts: score_func %r is neither softmax "
+                         "nor sigmoid" % (score_func,))
     return _routed(data, router_weight, gate_up_weight, down_weight,
                    int(top_k), int(expert_offset), norm_topk_prob,
                    _capacity(data.shape[0] * int(top_k),
                              gate_up_weight.shape[0],
-                             router_weight.shape[0]))
+                             router_weight.shape[0]),
+                   score_func, select_bias, float(routed_scaling_factor))
 
 
 def _routed(data, router_weight, gate_up_weight, down_weight, k, offset,
-            norm_topk_prob, capacity):
+            norm_topk_prob, capacity, score_func="softmax", select_bias=None,
+            scaling=1.0):
     """``routed_experts`` with the blocked path's rows a block,
     ``capacity``, as an argument (tokens * k: the full path)."""
     f32 = jnp.float32
     tokens = data.shape[0]
     held = gate_up_weight.shape[0]
     logits = jnp.dot(data, router_weight.T, preferred_element_type=f32)
-    weight, expert = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    score = jax.nn.softmax(logits, axis=-1) if score_func == "softmax" \
+        else jax.nn.sigmoid(logits)
+    if select_bias is None:
+        weight, expert = lax.top_k(score, k)
+    else:
+        _, expert = lax.top_k(score + select_bias.astype(f32), k)
+        weight = jnp.take_along_axis(score, expert, axis=-1)
     if norm_topk_prob:
         weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    if scaling != 1.0:
+        weight = weight * scaling
     local = expert.reshape(-1) - offset                  # (tokens * k,)
     here = (local >= 0) & (local < held)
     # held pairs first, grouped by expert; absent ones behind them

@@ -453,18 +453,22 @@ def _rmsnorm_infer(attrs, in_shapes):
 
 @register("RMSNorm", input_names=_rmsnorm_inputs, infer_shape=_rmsnorm_infer)
 def rms_norm(data, gamma, gate=None, eps=1e-6, zero_centered=False,
-             gated=False):
+             gated=False, gate_act="silu"):
     """Root-mean-square norm over the last axis, computed in float32:
     ``x * rsqrt(mean(x^2) + eps) * w``, with ``w = 1 + gamma`` when
     ``zero_centered`` (gamma initialised 0) and ``w = gamma`` otherwise.
-    With ``gated`` the result is multiplied by ``silu(gate)`` (the output
-    norm of a gated linear-attention mixer)."""
+    With ``gated`` the result is multiplied by ``gate_act(gate)``, ``silu``
+    or ``sigmoid`` (the output norm of a gated linear-attention mixer)."""
+    if gate_act not in ("silu", "sigmoid"):
+        raise MXNetError("RMSNorm: gate_act %r is neither silu nor sigmoid"
+                         % (gate_act,))
     x = data.astype(jnp.float32)
     w = gamma.astype(jnp.float32)
     y = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + float(eps))
     y = y * (1.0 + w if zero_centered else w)
     if gate is not None:
-        y = y * jax.nn.silu(gate.astype(jnp.float32))
+        act = jax.nn.silu if gate_act == "silu" else jax.nn.sigmoid
+        y = y * act(gate.astype(jnp.float32))
     return y.astype(data.dtype)
 
 
