@@ -62,18 +62,25 @@ Outside the registry, decided by platform, mesh and shapes alone (a
 kernel that wins its cell is unconditional, ``ROADMAP.md`` Design 2):
 
 - ``delta_rule`` — the chunked gated delta rule of ``GatedDeltaRule``
-  (:mod:`.delta_rule`): ``mxtpu_delta_rule_fwd`` / ``mxtpu_delta_rule_bwd``
-  walk a row's chunks with the state in VMEM, one key head and the value
-  heads it serves a grid step; q, k and v are read as the graph has them.
-  Compiled in a program lowered for a TPU when the head sizes are
-  multiples of 128, the value heads a multiple of the key heads and the
-  row whole chunks (of a multiple of 16); the lax tier otherwise and
-  under :func:`auto_partitioned`.  Each lowering records a
-  ``kernel.route`` event (kernel, tier, reason) in the program's
-  recorder; the benchmark's ``gdn_kernel_share`` reads them.  On the
-  v5e at the benchmark's shape (2 x 8,192 positions, 16 key / 32 value
-  heads of 128, chunk 64) the lax tier took 25.4 ms forward and 59.3 ms
-  forward + backward, the kernels 9.6 and 17.9 (PERF.md, PR 30).
+  (:mod:`.delta_rule`), two compiled rules.  A decay a head:
+  ``mxtpu_delta_rule_fwd`` / ``mxtpu_delta_rule_bwd`` walk a row's chunks
+  with the state in VMEM, one key head and the value heads it serves a
+  grid step; q, k and v are read as the graph has them.  Compiled in a
+  program lowered for a TPU when the head sizes are multiples of 128, the
+  value heads a multiple of the key heads and the row whole chunks (of a
+  multiple of 16); the lax tier otherwise and under
+  :func:`auto_partitioned`.  A decay per key channel (Kimi Delta
+  Attention): ``mxtpu_delta_rule_channel_fwd`` / ``_bwd``, two heads a
+  grid step at chunks of 64, when besides there is one key head a value
+  head and the chunk is 16, 32, 64 or 128 positions.  Each lowering
+  records a ``kernel.route`` event (kernel, tier, reason, and ``decay`` =
+  ``channel`` for the vector rule) in the program's recorder; the
+  benchmark's ``gdn_kernel_share`` and ``kda_kernel_share`` read them.
+  On the v5e at the benchmark's shapes (2 x 8,192 positions, chunk 64,
+  heads of 128; forward / forward + backward): 16 key / 32 value heads
+  under a scalar decay, lax tier 25.4 / 59.3 ms, kernels 9.6 / 17.9
+  (PERF.md, PR 30); 32 heads under a vector decay, lax tier 32.4 / 109.9
+  ms, kernels 16.0 / 41.1 (PERF.md, PR 32).
 
 The plan-level passes live in :mod:`mxnet_tpu.mxfuse` (the
 match-and-rewrite framework over the executor's node plan); this

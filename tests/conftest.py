@@ -79,6 +79,18 @@ def clean_faults():
     faults.disarm()
 
 
+@pytest.fixture
+def compiled_tier(monkeypatch):
+    """``kernels.by_platform`` as a program lowered for a TPU resolves it,
+    with the Pallas kernels run by the interpreter: ``gated_delta_rule_op``
+    then takes the compiled tier of either rule on the CPU."""
+    import functools
+    import mxnet_tpu.kernels as kernels
+    monkeypatch.setattr(
+        kernels, "by_platform", lambda pallas_fn, lax_fn, *args:
+        functools.partial(pallas_fn, interpret=True)(*args))
+
+
 def spawn_data_server(tmp_path, n, port=0, extra_env=None):
     """Spawn one real ``tools/data_server.py`` on a loopback port and
     wait for its port file: ``(proc, 'host:port')``.  ONE helper shared

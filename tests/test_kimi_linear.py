@@ -99,8 +99,10 @@ def test_vector_decay_rule_matches_the_recurrence(t, chunk, a_shift, rate):
     args = _rule_inputs(t, a_shift=a_shift, rate=rate)
     since = time.perf_counter()
     out = gated_delta_rule_op(*args, chunk=chunk)
+    # heads of 8 are no lane multiple: the lax tier, and the event says
+    # which of the two rules it walked
     assert _routes(since) == [{"kernel": "delta_rule", "tier": "lax",
-                               "reason": "channel_decay"}]
+                               "reason": "shapes", "decay": "channel"}]
     ref = _rule_oracle(*args)
     assert out.shape == ref.shape == (2, t, 3, 6)
     assert bool(jnp.all(jnp.isfinite(out)))
@@ -179,8 +181,8 @@ def test_one_op_two_ranks_a_vector_of_equal_decays_is_the_scalar_rule():
     since = time.perf_counter()
     scalar = gated_delta_rule_op(q, k, v, a3, b, a_log, dt3, chunk=64)
     vector = gated_delta_rule_op(q, k, v, a4, b, a_log, dt4, chunk=64)
-    assert [r["reason"] for r in _routes(since)] == ["shapes",
-                                                     "channel_decay"]
+    assert [(r["reason"], r.get("decay")) for r in _routes(since)] == [
+        ("shapes", None), ("shapes", "channel")]
     np.testing.assert_allclose(vector, scalar, rtol=1e-4, atol=2e-6)
     g = -jnp.exp(a_log) * jax.nn.softplus(a3 + dt3)
 
@@ -192,7 +194,12 @@ def test_one_op_two_ranks_a_vector_of_equal_decays_is_the_scalar_rule():
     np.testing.assert_allclose(scalar, ref, rtol=1e-4, atol=2e-6)
 
 
-def test_vector_decay_symbol_infers_and_a_tpu_lowering_stays_on_lax():
+def _kernel_names(fn, *args):
+    import re
+    return re.findall(r"mxtpu_[a-z_]+", str(jax.make_jaxpr(fn)(*args)))
+
+
+def test_vector_decay_symbol_infers_and_a_tpu_lowering_takes_its_kernels():
     names = ("query", "key", "value", "a", "b", "A_log", "dt_bias")
     s = mx.sym.GatedDeltaRule(chunk=64, **{n: mx.sym.Variable(n)
                                            for n in names})
@@ -202,13 +209,221 @@ def test_vector_decay_symbol_infers_and_a_tpu_lowering_stays_on_lax():
     assert s.infer_shape(**shapes)[1] == [(1, 128, 2, 128)]
     from mxnet_tpu.ops.contrib import gated_delta_rule_op
     args = [jnp.zeros(shapes[n], jnp.float32) for n in names]
-    # lane-aligned heads and whole chunks: a scalar decay takes the
-    # compiled tier, the vector one does not
-    assert "pallas_call" not in str(jax.make_jaxpr(
-        lambda *a: gated_delta_rule_op(*a, chunk=64))(*args))
-    args[3], args[6] = args[3][..., 0], args[6][:2]
-    assert "pallas_call" in str(jax.make_jaxpr(
-        lambda *a: gated_delta_rule_op(*a, chunk=64))(*args))
+
+    def grads(*a):
+        return jax.grad(lambda *x: jnp.sum(gated_delta_rule_op(*x, chunk=64)),
+                        argnums=tuple(range(7)))(*a)
+    # lane-aligned heads, whole chunks, a pair of heads: each rule takes
+    # its own two kernels, told from the rank of ``a`` alone
+    since = time.perf_counter()
+    assert _kernel_names(grads, *args) == [
+        "mxtpu_delta_rule_channel_fwd", "mxtpu_delta_rule_channel_bwd"]
+    assert _routes(since) == [{"kernel": "delta_rule", "tier": "pallas",
+                               "reason": "aligned", "decay": "channel"}]
+    scalar = list(args)
+    scalar[3], scalar[6] = args[3][..., 0], args[6][:2]
+    since = time.perf_counter()
+    assert _kernel_names(grads, *scalar) == ["mxtpu_delta_rule_fwd",
+                                             "mxtpu_delta_rule_bwd"]
+    assert _routes(since) == [{"kernel": "delta_rule", "tier": "pallas",
+                               "reason": "aligned"}]
+
+
+@pytest.mark.parametrize("heads,t,chunk,why", [
+    (3, 128, 64, "a grid step lays two heads side by side"),
+    (2, 96, 48, "a chunk of 48 positions does not halve down to one"),
+    (2, 100, 64, "a tail that is no whole chunk"),
+], ids=["odd_heads", "chunk_48", "tail"])
+def test_vector_decay_takes_the_lax_tier_for_other_shapes(heads, t, chunk,
+                                                          why):
+    from mxnet_tpu.ops.contrib import gated_delta_rule_op
+    z = jnp.zeros
+    args = (z((1, t, heads, 128)), z((1, t, heads, 128)),
+            z((1, t, heads, 128)), z((1, t, heads, 128)), z((1, t, heads)),
+            z((heads,)), z((heads * 128,)))
+    since = time.perf_counter()
+    assert not _kernel_names(
+        lambda *a: gated_delta_rule_op(*a, chunk=chunk), *args), why
+    assert _routes(since) == [{"kernel": "delta_rule", "tier": "lax",
+                               "reason": "shapes", "decay": "channel"}]
+
+
+def test_vector_decay_takes_the_lax_tier_under_a_mesh():
+    from mxnet_tpu.kernels import auto_partitioned
+    from mxnet_tpu.ops.contrib import gated_delta_rule_op
+    z = jnp.zeros
+    args = (z((1, 128, 2, 128)),) * 4 + (z((1, 128, 2)), z((2,)), z((256,)))
+    since = time.perf_counter()
+    with auto_partitioned():
+        assert not _kernel_names(
+            lambda *a: gated_delta_rule_op(*a, chunk=64), *args)
+    assert _routes(since) == [{"kernel": "delta_rule", "tier": "lax",
+                               "reason": "mesh", "decay": "channel"}]
+
+
+# -- the vector rule's compiled tier: its chunk map, and the op through the
+# -- Pallas interpreter
+
+def _chunk_operands(c, pair, dk, dv, rate, seed=80):
+    r = pair * c
+    s0 = [jnp.asarray(_n((dk, dv), seed + i, 0.3)) for i in range(pair)]
+    q, k, v, a = (jnp.asarray(_n(shape, seed + 3 + i)) for i, shape in
+                  enumerate([(r, dk), (r, dk), (r, dv), (r, dk)]))
+    bcol = jax.nn.sigmoid(jnp.asarray(_n((r, 1), seed + 7)))
+    return s0, q, k, v, -rate * jax.nn.softplus(a), bcol
+
+
+@pytest.mark.parametrize("c,pair,rate", [
+    (32, 2, 0.05), (16, 1, 1.0), (64, 2, 16.0), (32, 4, 0.001)],
+    ids=["two_heads_slow", "one_sub_block", "four_sub_blocks_fast",
+         "four_heads_slowest"])
+def test_channel_chunk_map_is_the_lax_tiers_and_its_backward_the_transpose(
+        c, pair, rate):
+    """The kernels' bodies call ``channel_chunk_forward`` and the
+    hand-written ``channel_chunk_backward``: the first is the lax tier's
+    ``_channel_chunk_local`` + ``_walk_step`` head by head, the second
+    jax's transpose of the first."""
+    from mxnet_tpu.kernels import delta_rule as dr
+    dk, dv = 16, 12
+    primals = _chunk_operands(c, pair, dk, dv, rate)
+    kw = dict(pair=pair, eps=1e-6, scale=dk ** -0.5)
+
+    def lax_tier(s0, q, k, v, g, bcol):
+        out, states = [], []
+        for h in range(pair):
+            at = slice(h * c, (h + 1) * c)
+            s, o = dr._walk_step(s0[h], dr._channel_chunk_local(
+                dr._unit(q[at], 1e-6)[0] * dk ** -0.5,
+                dr._unit(k[at], 1e-6)[0], v[at], g[at], bcol[at, 0], 16))
+            out.append(o)
+            states.append(s)
+        return jnp.concatenate(out), states
+    (o, s1, inverse), pull = jax.vjp(
+        lambda *a: dr.channel_chunk_forward(*a, **kw), *primals)
+    for a, b in zip(jax.tree.leaves((o, s1)),
+                    jax.tree.leaves(lax_tier(*primals))):
+        np.testing.assert_allclose(a, b, rtol=1e-4,
+                                   atol=2e-6 * float(jnp.max(jnp.abs(b))))
+    do = jnp.asarray(_n((pair * c, dv), 90))
+    ds1 = [jnp.asarray(_n((dk, dv), 91 + i)) for i in range(pair)]
+    want = pull((do, ds1, jnp.zeros_like(inverse)))
+    ds0, dq, dk_, dg, d_v, dbcol = dr.channel_chunk_backward(
+        primals[0], inverse, *primals[1:], do, ds1, **kw)
+    for a, b in zip(jax.tree.leaves((ds0, dq, dk_, d_v, dg, dbcol)),
+                    jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4,
+                                   atol=2e-6 * float(jnp.max(jnp.abs(b))))
+
+
+@pytest.mark.parametrize("pair", [1, 2])
+def test_the_kernels_inverse_is_exact_where_the_doubling_cancels_to_garbage(
+        pair):
+    """The bar of the lax tier's blocked inverse, on the tiles the kernels
+    invert: the heads' (64, 64) ``b x ones`` side by side."""
+    from mxnet_tpu.kernels.delta_rule import (
+        _channel_inverse, _channel_masks, _inverse_side_by_side)
+    ones = np.tril(np.ones((64, 64), "f"), -1)
+    m = _channel_masks(64, pair, 128)
+    for b in (0.5, 0.99):
+        exact = np.linalg.inv(np.eye(64) + b * ones.astype(np.float64))
+        low = jnp.asarray(np.tile(b * ones, (1, pair)))
+        got = np.asarray(_channel_inverse(low, m))
+        assert np.abs(got - np.tile(exact, (1, pair))).max() < 1e-5
+        assert np.abs(np.asarray(_inverse_side_by_side(low, m))
+                      - np.tile(exact, (1, pair))).max() > 100
+    low = jnp.asarray(np.tile(np.tril(_n((64, 64), 4, 0.3), -1), (1, pair)))
+    exact = np.linalg.inv(np.eye(64) + np.asarray(low[:, :64], np.float64))
+    np.testing.assert_allclose(_channel_inverse(low, m),
+                               np.tile(exact, (1, pair)), rtol=1e-4,
+                               atol=1e-5 * np.abs(exact).max())
+
+
+def _lax_tier(q, k, v, a, b, a_log, dt_bias, chunk):
+    from mxnet_tpu.kernels.delta_rule import gated_delta_net_lax
+    g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+        a + dt_bias.reshape(a.shape[2:]))
+    return gated_delta_net_lax(q, k, v, g, jax.nn.sigmoid(b), chunk=chunk)
+
+
+# decays: slow is the initialisation's slowest step (softplus(-7) = 0.0009
+# under exp(A_log) = 1), fast its fastest rate under a large ``a`` (a
+# chunk's log-decay passes -1,000)
+@pytest.mark.parametrize("t,chunk,heads,a_shift,rate", [
+    (64, 64, 2, -7.0, 1.0),         # one chunk, one pair of heads
+    (256, 64, 4, 0.0, None),        # two chunks a grid step, two steps a
+                                    # row, an even and an odd pair
+    (256, 64, 4, 2.0, 16.0),
+    (96, 32, 4, -7.0, 1.0),         # four heads a step, one chunk a step
+], ids=["one_chunk_slow", "two_pairs", "two_pairs_fast", "four_heads_slow"])
+def test_compiled_vector_rule_matches_recurrence_and_lax_tier(
+        compiled_tier, t, chunk, heads, a_shift, rate):
+    """Two rows (the state is zero at each row's start), heads of 128:
+    forward and all seven gradients of the op against the position by
+    position recurrence, and the forward against the lax tier."""
+    from mxnet_tpu.kernels.delta_rule import _channel_chunks_per_step
+    from mxnet_tpu.ops.contrib import gated_delta_rule_op
+    args = _rule_inputs(t, seed=50, h=heads, dk=128, dv=128,
+                        a_shift=a_shift, rate=rate)
+    pair = 128 // chunk
+    assert _channel_chunks_per_step(t // chunk, chunk, pair, 128, 128) == \
+        {64: 1, 256: 2, 96: 1}[t]
+    since = time.perf_counter()
+    out = gated_delta_rule_op(*args, chunk=chunk)
+    assert _routes(since) == [{"kernel": "delta_rule", "tier": "pallas",
+                               "reason": "aligned", "decay": "channel"}]
+    ref = _rule_oracle(*args)
+    assert out.shape == ref.shape == (2, t, heads, 128)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=2e-6)
+    np.testing.assert_allclose(out, _lax_tier(*args, chunk), rtol=1e-4,
+                               atol=2e-6)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(3 * fn(*a))),
+                        argnums=tuple(range(7)))(*args)
+    for a, b in zip(grads(lambda *a: gated_delta_rule_op(*a, chunk=chunk)),
+                    grads(_rule_oracle)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(
+            a, b, rtol=2e-3, atol=1e-4 * float(jnp.max(jnp.abs(b))) + 2e-6)
+
+
+def test_compiled_vector_rule_on_bfloat16_operands(compiled_tier):
+    """q, k, v arrive in bfloat16 and leave so, the log-decay stays
+    float32; inside, the rule is the float32 one on those values."""
+    from mxnet_tpu.ops.contrib import gated_delta_rule_op
+    args = _rule_inputs(128, seed=70, h=2, dk=128, dv=128)
+    args = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
+
+    def both(fn):
+        out, pull = jax.vjp(fn, *args)
+        return (out,) + pull(jnp.ones_like(out))
+    got = both(lambda *a: gated_delta_rule_op(*a, chunk=64))
+    want = both(lambda *a: _lax_tier(*a, 64))
+    assert got[0].dtype == got[1].dtype == got[3].dtype == jnp.bfloat16
+    assert got[4].dtype == jnp.float32
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        a, b = np.asarray(a, "f"), np.asarray(b, "f")
+        np.testing.assert_allclose(a, b, rtol=2 ** -7,
+                                   atol=2 ** -8 * np.abs(b).max())
+
+
+def test_scalar_decay_still_lowers_to_its_two_kernels_and_no_other():
+    """A rank-3 ``a`` lowers to exactly what it lowered to before the
+    vector rule had kernels: ``mxtpu_delta_rule_fwd`` and ``_bwd``, one
+    each, forward and gradient."""
+    from mxnet_tpu.ops.contrib import gated_delta_rule_op
+    z = jnp.zeros
+    args = (z((2, 128, 2, 128)), z((2, 128, 2, 128)), z((2, 128, 4, 128)),
+            z((2, 128, 4)), z((2, 128, 4)), z((4,)), z((4,)))
+
+    def op(*a):
+        return gated_delta_rule_op(*a, chunk=64)
+    assert _kernel_names(op, *args) == ["mxtpu_delta_rule_fwd"]
+    assert _kernel_names(
+        jax.grad(lambda *a: jnp.sum(op(*a)), argnums=tuple(range(7))),
+        *args) == ["mxtpu_delta_rule_fwd", "mxtpu_delta_rule_bwd"]
 
 
 # -- attention whose values are narrower than its keys -----------------------
@@ -504,8 +719,10 @@ def test_model_matches_the_reference_through_fit(seq_len, held):
             eval_metric=mx.metric.Perplexity(None))
     after = {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
     # four KDA layers traced: each told apart from a scalar decay's route
+    # (the toy's heads of 8 keep them on the lax tier)
     routes = _routes(since)
-    assert routes and all(r["reason"] == "channel_decay" for r in routes)
+    assert routes and all(r["decay"] == "channel" and r["tier"] == "lax"
+                          and r["reason"] == "shapes" for r in routes)
     # four expert layers a step (the dense first layer has none)
     assert profiler.counters()["moe.calls"] - calls >= 2 * 4
 

@@ -276,17 +276,6 @@ def test_chunk_backward_is_the_transpose_of_chunk_forward(rep):
 
 # the compiled tier, through the op, in the Pallas interpreter
 
-@pytest.fixture
-def compiled_tier(monkeypatch):
-    """``gated_delta_rule_op`` as a program lowered for a TPU routes it,
-    with the kernels run by the interpreter."""
-    import functools
-    import mxnet_tpu.kernels as kernels
-    monkeypatch.setattr(
-        kernels, "by_platform", lambda pallas_fn, lax_fn, *args:
-        functools.partial(pallas_fn, interpret=True)(*args))
-
-
 def _routes(since):
     return [r["ids"] for r in profiler.spans(since=since)
             if r["name"] == "kernel.route"

@@ -25,24 +25,31 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("rows,t,hk,hv,dk,dv,chunk,dtype", [
-    (2, 8192, 16, 32, 128, 128, 64, "bfloat16"),   # the benchmark's layer
-    (1, 1024, 2, 2, 256, 128, 32, "float32"),      # one value head a key head
-], ids=["qwen3_next_8k", "wide_keys_f32"])
+@pytest.mark.parametrize("rows,t,hk,hv,dk,dv,chunk,dtype,decay", [
+    (2, 8192, 16, 32, 128, 128, 64, "bfloat16", "head"),    # Qwen3-Next's
+    (1, 1024, 2, 2, 256, 128, 32, "float32", "head"),   # one value head a key's
+    (2, 8192, 32, 32, 128, 128, 64, "bfloat16", "channel"),  # Kimi Linear's
+    (1, 512, 4, 4, 256, 128, 32, "float32", "channel"),    # four heads a step
+], ids=["qwen3_next_8k", "wide_keys_f32", "kimi_linear_8k",
+        "channel_wide_keys_f32"])
 def test_delta_rule_kernels_compile_for_the_v5e(one_chip, rows, t, hk, hv, dk,
-                                                dv, chunk, dtype):
+                                                dv, chunk, dtype, decay):
+    """Forward and backward of each rule: a decay a head (g of rank 3) and
+    a decay a key channel (rank 4, its own two kernels)."""
     from mxnet_tpu.kernels import compiled_kernels
     from mxnet_tpu.kernels.delta_rule import gated_delta_net_pallas
 
     def spec(*shape, dt=dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+    g_shape = (rows, t, hv) + ((dk,) if decay == "channel" else ())
     specs = (spec(rows, t, hk, dk), spec(rows, t, hk, dk),
-             spec(rows, t, hv, dv), spec(rows, t, hv, dt="float32"),
+             spec(rows, t, hv, dv), spec(*g_shape, dt="float32"),
              spec(rows, t, hv, dt="float32"))
 
     def grads(*a):
         return jax.grad(lambda *x: jnp.sum(gated_delta_net_pallas(
             *x, chunk=chunk).astype(jnp.float32)), argnums=(0, 1, 2, 3, 4))(*a)
     text = jax.jit(grads).lower(*specs).compile().as_text()
-    assert compiled_kernels(text) == {"mxtpu_delta_rule_fwd": 1,
-                                      "mxtpu_delta_rule_bwd": 1}
+    stem = "mxtpu_delta_rule_channel_" if decay == "channel" \
+        else "mxtpu_delta_rule_"
+    assert compiled_kernels(text) == {stem + "fwd": 1, stem + "bwd": 1}
