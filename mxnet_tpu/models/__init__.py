@@ -9,6 +9,8 @@ from . import googlenet, inception_v3, resnext
 from . import lstm_lm
 from . import qwen3_next
 from . import kimi_linear
+from . import zaya
+from .zaya import zaya_sym
 
 _BUILDERS = {
     "lenet": lenet.get_symbol,

@@ -43,9 +43,12 @@ def _norm(x, name, width, zero_centered=True, gate=None, eps=1e-6,
                        zero_centered=zero_centered, name=name, **kw)
 
 
-def _linear(x, name, width):
+def _linear(x, name, width, weight=None):
+    """``weight``: a variable the graph already has (a tied head's), else
+    the layer's own ``<name>_weight``."""
+    kw = {} if weight is None else {"weight": weight}
     return sym.FullyConnected(data=x, num_hidden=width, no_bias=True,
-                              name=name)
+                              name=name, **kw)
 
 
 def _cut(x, axis, begin, end):
@@ -130,17 +133,19 @@ def _experts(x, p, c, held, offset):
     return routed[0] + sym.broadcast_mul(shared, share), routed[1]
 
 
-def _head(h, label, stats, seq_len, hidden, vocab, eps, zero_centered=True):
+def _head(h, label, stats, seq_len, hidden, vocab, eps, zero_centered=True,
+          weight=None):
     """The residual stream (batch * T, hidden) -> (symbol, data names, label
-    names): final norm, untied head over time-major rows, ``SoftmaxOutput``
-    against ``label`` (batch, T) with its gradient scaled by 1 / T, and the
-    expert layers' counters as the second head."""
+    names): final norm, the head over time-major rows — untied, or tied to
+    the (vocab, hidden) variable ``weight`` —, ``SoftmaxOutput`` against
+    ``label`` (batch, T) with its gradient scaled by 1 / T, and the expert
+    layers' counters as the second head."""
     # (batch * T, H) -> time-major rows (T * batch, H), as the label's
     h = sym.SwapAxis(sym.Reshape(h, shape=(-1, seq_len, hidden)),
                      dim1=0, dim2=1)
     h = _norm(sym.Reshape(h, shape=(-1, hidden)), "head_norm", hidden,
               zero_centered=zero_centered, eps=eps)
-    logits = _linear(h, "head", vocab)
+    logits = _linear(h, "head", vocab, weight)
     lab = sym.Reshape(sym.SwapAxis(label, dim1=0, dim2=1), shape=(-1,))
     out = sym.SoftmaxOutput(data=logits, label=lab,
                             grad_scale=1.0 / seq_len, name="softmax")

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .nn import rms_norm
 from .registry import register
 from ..base import MXNetError
 
@@ -779,6 +780,18 @@ def _experts_blocked_bwd(capacity, res, g):
 _experts_blocked.defvjp(_experts_blocked_fwd, _experts_blocked_bwd)
 
 
+@jax.custom_vjp
+def _pushing(out, bias, push):
+    """``out`` as it is; going backward ``bias``, which no gradient
+    reaches, is handed ``push`` as its gradient."""
+    return out
+
+
+_pushing.defvjp(
+    lambda out, bias, push: (out, push.astype(bias.dtype)),
+    lambda push, g: (g, push, jnp.zeros_like(push)))
+
+
 def _capacity(pairs, held, num_experts):
     """Rows of one block of the blocked path: twice the share of the
     ``pairs`` (tokens * k) that a uniform router lands on ``held`` of
@@ -796,7 +809,8 @@ def _routed_infer(attrs, in_shapes):
 
 @register("_contrib_RoutedExperts", aliases=("RoutedExperts",),
           input_names=lambda attrs: (
-              "data", "router_weight", "gate_up_weight", "down_weight")
+              "data", "scores" if attrs.get("scores_given", False)
+              else "router_weight", "gate_up_weight", "down_weight")
           + (("select_bias",) if attrs.get("use_select_bias", False)
              else ()),
           num_outputs=2, output_names=("output", "stats"),
@@ -804,7 +818,8 @@ def _routed_infer(attrs, in_shapes):
 def routed_experts(data, router_weight, gate_up_weight, down_weight,
                    select_bias=None, top_k=1, expert_offset=0,
                    norm_topk_prob=True, score_func="softmax",
-                   routed_scaling_factor=1.0, use_select_bias=False):
+                   routed_scaling_factor=1.0, use_select_bias=False,
+                   scores_given=False, balance_rate=0.0):
     """Dropless top-``top_k`` routed experts, told which experts it holds.
     data (tokens, hidden); router_weight (num_experts, hidden) over ALL the
     experts of the layer; gate_up_weight (held, hidden, 2 x width) and
@@ -825,6 +840,24 @@ def routed_experts(data, router_weight, gate_up_weight, down_weight,
     pairs, the mean over held experts, 1, and 1 if the blocked path ran
     and one block held all the pairs that landed (``n <= C``).
 
+    With ``scores_given`` the second input is not a router's weight but
+    ``scores`` (tokens, num_experts), the ``p`` itself as the graph
+    computed it (a router that is more than one matrix product, say an MLP
+    that carries state from layer to layer: ``DepthRouter``); ``score_func``
+    is then unused, the choice, the weights and the counts are as above,
+    and the gradient of the weighted sum reaches ``scores`` through the
+    chosen experts' weights.  ``top_k=1, norm_topk_prob=False`` weighs a
+    token's one expert by its ``p`` as it is.
+
+    With ``balance_rate`` above 0 the load moves ``select_bias``: going
+    backward it is handed ``balance_rate x (pairs that chose expert e -
+    pairs / num_experts)`` as its gradient, over all the experts, held
+    here or not, so whatever rule the optimizer applies to every leaf
+    lowers the bias of an expert that took more than an even share and
+    raises the others' (the bias-balancing of arXiv:2408.15664 with the
+    load's error itself in the place of its sign).  The sum over a batch's
+    rows is the batch's, as a gradient's is.
+
     Pairs are sorted by expert, so each held expert's tokens are one
     contiguous group of rows, the held experts' pairs are the sorted
     order's first ``n`` and the expert products are two ``lax.ragged_dot``
@@ -840,25 +873,32 @@ def routed_experts(data, router_weight, gate_up_weight, down_weight,
     if score_func not in ("softmax", "sigmoid"):
         raise MXNetError("RoutedExperts: score_func %r is neither softmax "
                          "nor sigmoid" % (score_func,))
+    if balance_rate and select_bias is None:
+        raise MXNetError("RoutedExperts: balance_rate moves select_bias, "
+                         "which use_select_bias brings")
     return _routed(data, router_weight, gate_up_weight, down_weight,
                    int(top_k), int(expert_offset), norm_topk_prob,
                    _capacity(data.shape[0] * int(top_k),
                              gate_up_weight.shape[0],
-                             router_weight.shape[0]),
-                   score_func, select_bias, float(routed_scaling_factor))
+                             router_weight.shape[1 if scores_given else 0]),
+                   score_func, select_bias, float(routed_scaling_factor),
+                   bool(scores_given), float(balance_rate))
 
 
 def _routed(data, router_weight, gate_up_weight, down_weight, k, offset,
             norm_topk_prob, capacity, score_func="softmax", select_bias=None,
-            scaling=1.0):
+            scaling=1.0, scores_given=False, balance_rate=0.0):
     """``routed_experts`` with the blocked path's rows a block,
     ``capacity``, as an argument (tokens * k: the full path)."""
     f32 = jnp.float32
     tokens = data.shape[0]
     held = gate_up_weight.shape[0]
-    logits = jnp.dot(data, router_weight.T, preferred_element_type=f32)
-    score = jax.nn.softmax(logits, axis=-1) if score_func == "softmax" \
-        else jax.nn.sigmoid(logits)
+    if scores_given:
+        score = router_weight.astype(f32)
+    else:
+        logits = jnp.dot(data, router_weight.T, preferred_element_type=f32)
+        score = jax.nn.softmax(logits, axis=-1) if score_func == "softmax" \
+            else jax.nn.sigmoid(logits)
     if select_bias is None:
         weight, expert = lax.top_k(score, k)
     else:
@@ -882,11 +922,60 @@ def _routed(data, router_weight, gate_up_weight, down_weight, k, offset,
         out = _experts_full(data, weight, gate_up_weight, down_weight,
                             order, sizes, here)
         compact = jnp.zeros((), f32)
+    if balance_rate:
+        chosen = jnp.sum(jax.nn.one_hot(expert.reshape(-1), score.shape[1],
+                                        dtype=f32), axis=0)
+        out = _pushing(out, select_bias, lax.stop_gradient(
+            balance_rate * (chosen - tokens * k / score.shape[1])))
     load = sizes.astype(f32)
     stats = lax.stop_gradient(jnp.stack([
         jnp.asarray(tokens * k, f32), jnp.sum(load), jnp.max(load),
         jnp.mean(load), jnp.ones((), f32), compact]))
     return out, stats
+
+
+def _depth_router_infer(attrs, in_shapes):
+    data, down, fc3 = in_shapes[0], in_shapes[1], in_shapes[5]
+    if data is None or down is None or fc3 is None:
+        return list(in_shapes), [None, None], []
+    return list(in_shapes), [(data[0], down[0]), (data[0], fc3[0])], []
+
+
+@register("_contrib_DepthRouter", aliases=("DepthRouter",),
+          input_names=lambda attrs: (
+              "data", "down_weight", "norm_gamma", "fc1_weight",
+              "fc2_weight", "fc3_weight")
+          + (("state", "carry") if attrs.get("carried", False) else ()),
+          num_outputs=2, output_names=("state", "scores"),
+          infer_shape=_depth_router_infer)
+def depth_router(data, down_weight, norm_gamma, fc1_weight, fc2_weight,
+                 fc3_weight, state=None, carry=None, eps=1e-6,
+                 carried=False):
+    """A router that is a small MLP over a state carried from layer to
+    layer (ZAYA1, arXiv:2511.17127), all of it in float32 whatever the
+    data's dtype: ``r = data @ down_weight.T`` (tokens, width), plus
+    ``carry * state`` with ``carried`` — ``state`` (tokens, width) the
+    previous layer's ``r``, ``carry`` (width,) learned —; ``s =
+    fc3(gelu(fc2(gelu(fc1(rmsnorm(r; norm_gamma, eps))))))`` with the exact
+    ``gelu`` and no bias.  Output 0 is ``r`` (float32), what the next
+    layer's router is handed; output 1 is ``softmax(s)`` over all the
+    experts (float32), what ``RoutedExperts`` takes with ``scores_given``.
+    Contractions under ``highest`` precision: a choice of one expert in
+    sixteen turns on the fourth digit of ``s``."""
+    f32 = jnp.float32
+    hi = lax.Precision.HIGHEST
+
+    def fc(x, w):
+        return jnp.dot(x, w.astype(f32).T, precision=hi,
+                       preferred_element_type=f32)
+    r = jnp.dot(data, down_weight.T, precision=hi,
+                preferred_element_type=f32)
+    if state is not None:
+        r = r + carry.astype(f32) * state.astype(f32)
+    x = jax.nn.gelu(fc(rms_norm(r, norm_gamma, eps=eps), fc1_weight),
+                    approximate=False)
+    x = jax.nn.gelu(fc(x, fc2_weight), approximate=False)
+    return r, jax.nn.softmax(fc(x, fc3_weight), axis=-1)
 
 
 @register("_contrib_RoutedExpertsStats", aliases=("RoutedExpertsStats",),

@@ -432,8 +432,9 @@ def lrn(data, nsize=5, alpha=1e-4, beta=0.75, knorm=2.0):
 
 # ---------------------------------------------------------------------------
 # Layers of today's language models (no 2017 counterpart): RMSNorm, the
-# gated feed-forward's SwiGLU, partial rotary embedding, the causal
-# depthwise short convolution of linear-attention mixers
+# gated feed-forward's SwiGLU, partial rotary embedding, the causal short
+# convolution of linear-attention and latent mixers (depthwise or grouped),
+# heads normalised to a fixed length
 # ---------------------------------------------------------------------------
 
 def _rmsnorm_inputs(attrs):
@@ -506,25 +507,69 @@ def _causal_conv_infer(attrs, in_shapes):
     data = in_shapes[0]
     if data is None:
         return in_shapes, [None], []
-    return [tuple(data), (data[-1], int(attrs["kernel"]))], \
-        [tuple(data)], []
+    k, groups = int(attrs["kernel"]), int(attrs.get("num_group", 0))
+    weight = (data[-1], data[-1] // groups, k) if groups else (data[-1], k)
+    return [tuple(data), weight], [tuple(data)], []
 
 
 @register("_contrib_CausalConv1D", aliases=("CausalConv1D",),
           input_names=("data", "weight"), infer_shape=_causal_conv_infer)
-def causal_conv1d(data, weight, kernel=4, act_type=None):
-    """Causal depthwise convolution along positions, no bias: data (batch,
-    positions, channels), weight (channels, kernel);
+def causal_conv1d(data, weight, kernel=4, act_type=None, num_group=0):
+    """Causal convolution along positions, no bias: data (batch,
+    positions, channels).  Depthwise (``num_group`` 0, the default):
+    weight (channels, kernel),
     ``y[t] = sum_j weight[:, j] * x[t - (kernel-1) + j]`` with zeros before
-    a row's start.  ``act_type`` as in ``Activation``.  The taps are summed
-    (and the activation taken) in float32."""
-    k = int(kernel)
-    x = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
-    w = weight.astype(jnp.float32)
-    t = data.shape[1]
-    y = sum(x[:, j:j + t] * w[:, j] for j in range(k))
+    a row's start; the taps are summed in float32.  Grouped (``num_group``
+    g > 0): weight (channels, channels / g, kernel), output channel ``o``
+    mixing the channels of its own group over the taps,
+    ``y[t][o] = sum_j sum_{i in group(o)} weight[o, i, j] * x[t - (kernel-1)
+    + j][i]`` — ``lax.conv_general_dilated`` with ``feature_group_count``
+    on operands of the data's dtype.  ``act_type`` as in ``Activation``,
+    taken in float32."""
+    k, g = int(kernel), int(num_group)
+    if g:
+        y = lax.conv_general_dilated(
+            data, weight.astype(data.dtype), window_strides=(1,),
+            padding=[(k - 1, 0)], dimension_numbers=("NWC", "OIW", "NWC"),
+            feature_group_count=g)
+    else:
+        t = data.shape[1]
+        x = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+        w = weight.astype(jnp.float32)
+        y = sum(x[:, j:j + t] * w[:, j] for j in range(k))
     if act_type:
-        y = activation(y, act_type)
+        y = activation(y.astype(jnp.float32), act_type)
+    return y.astype(data.dtype)
+
+
+def _head_l2_inputs(attrs):
+    return ("data", "log_scale") if attrs.get("scaled", False) \
+        else ("data",)
+
+
+def _head_l2_infer(attrs, in_shapes):
+    data = in_shapes[0]
+    if data is None:
+        return in_shapes, [None], []
+    shapes = [tuple(data)]
+    if attrs.get("scaled", False):
+        shapes.append((data[-2],))
+    return shapes, [tuple(data)], []
+
+
+@register("_contrib_HeadL2Norm", aliases=("HeadL2Norm",),
+          input_names=_head_l2_inputs, infer_shape=_head_l2_infer)
+def head_l2_norm(data, log_scale=None, eps=1e-6, scaled=False):
+    """Every head brought to the length ``sqrt(head_dim)``, in float32:
+    data (batch, positions, heads, head_dim) ->
+    ``sqrt(head_dim) * x * rsqrt(sum(x^2) + eps)``; with ``scaled`` and
+    ``log_scale`` (heads,) each head is then multiplied by
+    ``exp(log_scale[head])`` (a learned temperature on normalised keys)."""
+    x = data.astype(jnp.float32)
+    y = x * (lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + float(eps))
+             * float(data.shape[-1]) ** 0.5)
+    if log_scale is not None:
+        y = y * jnp.exp(log_scale.astype(jnp.float32))[:, None]
     return y.astype(data.dtype)
 
 

@@ -1177,20 +1177,17 @@ def test_data_service_drill_sigkill_worker_mid_epoch(tmp_path):
             victims = it._service.worker_pids()
             assert len(victims) == 2
             os.kill(victims[0], signal.SIGKILL)
-    # the respawn is the monitor's heartbeat-policy decision: on a
-    # loaded single-core host the short epoch can complete before the
-    # monitor's next poll — wait for the respawn, don't race it (the
-    # service keeps monitoring between epochs); a respawn is a Python
-    # start, which under the whole suite's load has taken over 10 s
-    deadline = time.monotonic() + 60
-    while time.monotonic() < deadline:
-        st = it.stats()
-        if sum(w["respawns"] for w in st["workers"].values()) >= 1:
-            break
-        time.sleep(0.05)
-    assert sum(w["respawns"] for w in st["workers"].values()) == 1, st
+    # the collector brings a dead worker back while it waits for a batch
+    # that worker still owes; a victim that had already put its whole
+    # share of this short epoch into its ring (a consumer slowed by the
+    # whole suite's load) owes nothing more, and is brought back when the
+    # next epoch first waits for it.  The service has no monitor of its
+    # own in between, so the count is taken after the next epoch: one
+    # respawn either way
     it.reset()
     got_e2 = _ds_stream(it)
+    st = it.stats()
+    assert sum(w["respawns"] for w in st["workers"].values()) == 1, st
     it.close()
 
     assert len(got) == len(ref_e1)
