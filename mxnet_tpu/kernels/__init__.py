@@ -81,6 +81,23 @@ kernel that wins its cell is unconditional, ``ROADMAP.md`` Design 2):
   under a scalar decay, lax tier 25.4 / 59.3 ms, kernels 9.6 / 17.9
   (PERF.md, PR 30); 32 heads under a vector decay, lax tier 32.4 / 109.9
   ms, kernels 16.0 / 41.1 (PERF.md, PR 32).
+- ``gqa_attention`` — the causal grouped-query attention of ``GQAttention``
+  (:mod:`.flash_attention`): ``mxtpu_gqa_attention_fwd`` /
+  ``mxtpu_gqa_attention_bwd``, a key / value head and the query heads it
+  serves a grid step on (keys x stacked query rows) tiles, the score tile,
+  the running softmax and all three gradients' accumulators in VMEM, blocks
+  above the diagonal neither fetched nor computed.  Compiled in a program
+  lowered for a TPU when the positions are whole blocks of 128, the value
+  heads whole lane tiles, the query / key heads whole lane tiles or padded
+  to one by a third at most (192 -> 256) and a (row, key head)'s dk and dv
+  fit VMEM (reason ``aligned``); the lax tier otherwise (``shapes``) and
+  under :func:`auto_partitioned` (``mesh``).  Each lowering records a
+  ``kernel.route`` event (kernel ``gqa_attention``); the benchmark's
+  ``attn_kernel_share`` reads them.  On the v5e at the benchmark's shapes
+  (2 x 8,192 positions, bfloat16; forward / forward + backward): 32 heads
+  of 192 / 128, lax tier 45.9 / 149.5 ms, kernels 17.5 / 50.0; 16 query /
+  2 key heads of 256, 22.3 / 49.9 and 8.9 / 27.7; 8 / 2 of 128, 8.9 / 22.2
+  and 2.75 / 7.43 (PERF.md, PR 34).
 
 The plan-level passes live in :mod:`mxnet_tpu.mxfuse` (the
 match-and-rewrite framework over the executor's node plan); this

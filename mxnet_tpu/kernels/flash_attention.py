@@ -18,13 +18,32 @@ Tiers (package docstring):
   differentiable by jax (the scan transposes to the standard recompute
   backward), O(T) memory.
 - :func:`gqa_attention` — causal grouped-query attention (each
-  key/value head serves ``Hq // Hkv`` query heads, any head size) in
-  pure lax with its OWN backward (``jax.custom_vjp``: the saved
-  residuals are q, k, v, the output and one log-sum-exp a row; the
-  probabilities are recomputed a query block at a time), so neither
-  pass ever holds more than one (block x prefix) tile of scores and the
-  bf16 gradient is finite (masked scores are a large finite negative,
-  never ``-inf``).  The language models route here.
+  key/value head serves ``Hq // Hkv`` query heads, the value heads as
+  wide as they like).  The language models route here, and it has two
+  tiers of its own, chosen from what the trace can see
+  (:func:`_gqa_lax_reason`; each call records a ``kernel.route`` event:
+  kernel ``gqa_attention``, tier ``pallas`` / ``lax``, reason ``aligned``
+  / ``shapes`` / ``mesh``):
+
+  - compiled (:func:`gqa_attention_pallas`), in a program lowered for a
+    TPU: ``mxtpu_gqa_attention_fwd`` and ``mxtpu_gqa_attention_bwd``
+    under one ``jax.custom_vjp``.  A grid step meets a block of keys with
+    a block of query rows of all the heads its key head serves; score
+    tile, running softmax and — in the one backward kernel — dq of the
+    row block and dk, dv of the whole (row, key head) stay in VMEM; only
+    blocks at or below the diagonal are visited, only those it crosses
+    masked.  For positions in whole blocks of 128, value heads of whole
+    lane tiles and query / key heads that are (128, 256) or pad to one by
+    a third at most (192 -> 256, zeros);
+  - pure lax with its OWN backward (``jax.custom_vjp``: the saved
+    residuals are q, k, v, the output and one log-sum-exp a row; the
+    probabilities are recomputed a query block at a time), so neither
+    pass ever holds more than one (block x prefix) tile of scores: any
+    shape, any platform, a mesh, and the oracle of the compiled tier.
+
+  On either the bf16 gradient is finite (masked scores are a large
+  finite negative, never ``-inf``), contractions take the operands' dtype
+  into float32 and the softmax is float32.
 - :func:`flash_attention_pallas` — a ``pl.pallas_call`` kernel (grid
   over batch x heads x query blocks x key blocks, the running triple in
   VMEM scratch across the key axis) behind ``jax.custom_vjp``; the
@@ -41,6 +60,7 @@ tolerance in tests/test_kernels.py.
 from __future__ import annotations
 
 import functools
+import time
 
 import numpy as np
 
@@ -48,9 +68,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .delta_rule import _nt, _tn
+
 __all__ = ["flash_attention", "flash_attention_lax",
            "flash_attention_pallas", "online_update", "default_block",
-           "gqa_attention"]
+           "gqa_attention", "gqa_attention_pallas"]
 
 
 def default_block():
@@ -402,6 +424,336 @@ def _gqa_bwd(scale, block_q, res, g):
 _gqa.defvjp(_gqa_fwd, _gqa_bwd)
 
 
+# ---------------------------------------------------------------------------
+# causal grouped-query attention, compiled tier
+#
+# A grid step meets one block of query rows with one block of keys, for one
+# key / value head and the ``G`` query heads it serves, whose rows are
+# stacked head-major into one (G * bq) tall tile: K and V are fetched once
+# a group.  The steps of a (row, key head) walk the blocks at or below the
+# diagonal and no others (two int32 tables in SMEM name each step's
+# blocks; the index maps read them), so a block above the diagonal is
+# neither computed nor fetched; only the blocks that the diagonal crosses
+# take the mask.  Nothing quadratic leaves VMEM.
+# ---------------------------------------------------------------------------
+
+#: scores of a tile, (key rows) x (a group's query heads x a query block):
+#: 4 MiB of float32.  On the v5e at 2 x 8,192 positions (PERF.md, PR 34)
+#: forward + backward of the three language models' shapes ran within 8 %
+#: of one another on tiles of a quarter to twice this and best at it; at
+#: twice, eight query heads' backward took 40 % longer
+_GQA_TILE = 1 << 20
+
+#: VMEM a kernel may ask for: the backward holds a (row, key head)'s dk and
+#: dv whole (float32 accumulators and the double-buffered blocks they
+#: leave through); a v5e core has 128 MiB
+_GQA_VMEM = 100 << 20
+
+
+def _gqa_tiles(T, G):
+    """(query rows a head, key rows) of a grid step, or None: powers of
+    two that divide the positions — up to 1,024 query rows (a longer block
+    wastes more above the diagonal), stacked over the group to 2,048 rows
+    at most and whole lane tiles; then the key rows that fill the tile."""
+    def largest(sizes, fits):
+        return next((c for c in sizes if T % c == 0 and fits(c)), None)
+    bq = largest((1024, 512, 256, 128, 64, 32, 16),
+                 lambda c: G * c <= 2048 and G * c % 128 == 0)
+    bk = largest((1024, 512, 256, 128),
+                 lambda c: c == 128 or G * bq * c <= _GQA_TILE) if bq else None
+    return (bq, bk) if bk else None
+
+
+def _gqa_steps(T, bq, bk):
+    """The (query block, key block) of each grid step: row blocks in turn,
+    each with the key blocks that hold a key at or before its last row."""
+    qi, kj = [], []
+    for i in range(T // bq):
+        n = ((i + 1) * bq - 1) // bk + 1
+        qi += [i] * n
+        kj += range(n)
+    return np.asarray(qi, np.int32), np.asarray(kj, np.int32)
+
+
+def _stack(dst_ref, src_ref, G):
+    """(bq, G * d) heads side by side -> (G * bq, d) head-major rows."""
+    bq, d = src_ref.shape[0], src_ref.shape[1] // G
+    for g in range(G):
+        dst_ref[g * bq:(g + 1) * bq, :] = src_ref[:, g * d:(g + 1) * d]
+
+
+def _unstack(dst_ref, x, G):
+    """(G * bq, d) head-major rows -> (bq, G * d) heads side by side."""
+    bq, d = x.shape[0] // G, x.shape[1]
+    for g in range(G):
+        dst_ref[:, g * d:(g + 1) * d] = \
+            x[g * bq:(g + 1) * bq].astype(dst_ref.dtype)
+
+
+def _gqa_step(qi_ref, kj_ref, bq, bk):
+    """This grid step's blocks, whether it is the row block's last, and
+    whether the diagonal crosses it (some key after the block's first
+    row)."""
+    from jax.experimental import pallas as pl
+    step = pl.program_id(2)
+    i, j = qi_ref[step], kj_ref[step]
+    return i, j, (j + 1) * bk >= (i + 1) * bq, (j + 1) * bk - 1 > i * bq
+
+
+def _gqa_causal(shape, i, j, bq, bk):
+    """Which (key, stacked query row) pairs of a (bk, G * bq) tile see
+    each other: the key at or before the row."""
+    k_pos = j * bk + lax.broadcasted_iota(jnp.int32, shape, 0)
+    q_pos = i * bq + (lax.broadcasted_iota(jnp.int32, shape, 1) & (bq - 1))
+    return q_pos >= k_pos
+
+
+def _either(cond, fn):
+    """``fn(True)`` where ``cond``, ``fn(False)`` elsewhere."""
+    from jax.experimental import pallas as pl
+    pl.when(cond)(functools.partial(fn, True))
+    pl.when(jnp.logical_not(cond))(functools.partial(fn, False))
+
+
+def _gqa_fwd_kernel(G, scale, qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref,
+                    lse_ref, acc_ref, m_ref, l_ref, *qs_ref):
+    """q_ref (bq, G * D), k_ref (bk, D), v_ref (bk, Dv) -> o_ref (bq,
+    G * Dv), lse_ref (1, G * bq).  The tiles are (bk, G * bq): keys down
+    the rows, the group's stacked query rows along lanes, so a query row's
+    running max and sum are one lane each of a (1, G * bq) row and their
+    reductions run down the sublanes; the float32 accumulator is the
+    output's transpose, (Dv, G * bq).  All three live in scratch over a
+    row block's key blocks."""
+    from jax.experimental import pallas as pl
+    bq, bk = q_ref.shape[0], k_ref.shape[0]
+    i, j, last, crossed = _gqa_step(qi_ref, kj_ref, bq, bk)
+    rows_ref = qs_ref[0] if qs_ref else q_ref
+
+    @pl.when(j == 0)
+    def _():
+        if qs_ref:
+            _stack(rows_ref, q_ref, G)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def update(masked):
+        v = v_ref[...]
+        s = _nt(k_ref[...], rows_ref[...]) * scale          # (bk, M)
+        if masked:
+            s = jnp.where(_gqa_causal(s.shape, i, j, bq, bk), s, _MASKED)
+        m_run = m_ref[...]
+        m_new = jnp.maximum(m_run, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m_run - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + _tn(v, p.astype(v.dtype))
+        m_ref[...] = m_new
+    _either(crossed, update)
+
+    @pl.when(last)
+    def _():
+        l = l_ref[...]
+        _unstack(o_ref, (acc_ref[...] / l).T, G)
+        lse_ref[...] = m_ref[...] + jnp.log(l)
+
+
+def _gqa_bwd_kernel(G, scale, qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref,
+                    lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc,
+                    dk_acc, dv_acc, *stacked):
+    """One kernel for all three gradients, its tiles (bk, G * bq): keys
+    down the rows, the group's stacked query rows along lanes, where
+    lse_ref and delta_ref (1, G * bq) broadcast.  dq of a row block
+    accumulates over its key blocks; dk and dv of the whole (row, key
+    head) accumulate in float32 scratch over every step and leave once,
+    with the last."""
+    from jax.experimental import pallas as pl
+    bq, bk = q_ref.shape[0], k_ref.shape[0]
+    step = pl.program_id(2)
+    i, j, last, crossed = _gqa_step(qi_ref, kj_ref, bq, bk)
+    if stacked:
+        qs_ref, dos_ref = stacked
+    else:
+        qs_ref, dos_ref = q_ref, do_ref
+
+    @pl.when(step == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)
+    def _():
+        if stacked:
+            _stack(qs_ref, q_ref, G)
+            _stack(dos_ref, do_ref, G)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def update(masked):
+        q, do, k = qs_ref[...], dos_ref[...], k_ref[...]
+        s = _nt(k, q) * scale                               # (bk, M)
+        if masked:
+            s = jnp.where(_gqa_causal(s.shape, i, j, bq, bk), s, _MASKED)
+        p = jnp.exp(s - lse_ref[...])
+        dp = _nt(v_ref[...], do)
+        ds = (p * (dp - delta_ref[...]) * scale).astype(q.dtype)
+        rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        dv_acc[rows, :] += jnp.dot(p.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+        dk_acc[rows, :] += jnp.dot(ds, q,
+                                   preferred_element_type=jnp.float32)
+        dq_acc[...] += _tn(ds, k)
+    _either(crossed, update)
+
+    @pl.when(last)
+    def _():
+        _unstack(dq_ref, dq_acc[...], G)
+
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _pallas_gqa(scale, tiles, interpret):
+    """The attention as one ``custom_vjp`` over q (B, T, Hq, D), k (B, T,
+    Hkv, D) and v (B, T, Hkv, Dv), D and Dv whole lane tiles.  The kernels
+    read them row-major, (B, T, heads * width), a head's lanes a block."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    bq, bk = tiles
+    shape = jax.ShapeDtypeStruct
+
+    def dims(q, v):
+        (B, T, Hq, D), (Hkv, Dv) = q.shape, v.shape[2:]
+        return B, T, Hq, D, Hkv, Dv, Hq // Hkv, Hq // Hkv * bq
+
+    def call(kernel, name, q, v, ins, outs, out_shape, scratch):
+        """The kernel over the (row, key head, step) grid, as a function of
+        its operands; ``ins`` and ``outs`` name block specs, ``scratch``
+        lists (shape, dtype)."""
+        B, T, _, D, Hkv, Dv, G, M = dims(q, v)
+
+        def rows(width):                 # a query block of the G heads
+            return pl.BlockSpec((None, bq, G * width),
+                                lambda b, h, s, qi, kj: (b, qi[s], h))
+
+        def keys(width):
+            return pl.BlockSpec((None, bk, width),
+                                lambda b, h, s, qi, kj: (b, kj[s], h))
+
+        def whole(width):                # a (row, key head)'s every key
+            return pl.BlockSpec((None, T, width),
+                                lambda b, h, s, qi, kj: (b, 0, h))
+        specs = dict(
+            q=rows(D), o=rows(Dv), k=keys(D), v=keys(Dv), dk=whole(D),
+            dv=whole(Dv), row=pl.BlockSpec(
+                (None, None, None, 1, M),
+                lambda b, h, s, qi, kj: (b, h, qi[s], 0, 0)))
+        qi, kj = _gqa_steps(T, bq, bk)
+        return functools.partial(pl.pallas_call(
+            functools.partial(kernel, G, scale),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(B, Hkv, len(qi)),
+                in_specs=[specs[n] for n in ins],
+                out_specs=tuple(specs[n] for n in outs),
+                scratch_shapes=[pltpu.VMEM(*x) for x in scratch]),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_GQA_VMEM),
+            name=name, interpret=interpret), qi, kj)
+
+    def flat(x):
+        return x.reshape(x.shape[:2] + (-1,))
+    f32 = jnp.float32
+
+    # jitted, so that a model's layers of one shape trace and lower each
+    # kernel once
+    @jax.jit
+    def run_forward(q, k, v):
+        B, T, Hq, D, Hkv, Dv, G, M = dims(q, v)
+        o, lse = call(
+            _gqa_fwd_kernel, "mxtpu_gqa_attention_fwd", q, v,
+            "qkv", ("o", "row"),
+            (shape((B, T, Hq * Dv), q.dtype),
+             shape((B, Hkv, T // bq, 1, M), f32)),
+            [((Dv, M), f32), ((1, M), f32), ((1, M), f32)]
+            + [((M, D), q.dtype)] * (G > 1))(flat(q), flat(k), flat(v))
+        return o.reshape(B, T, Hq, Dv), lse
+
+    @jax.jit
+    def run_backward(q, k, v, o, lse, do):
+        B, T, _, D, Hkv, Dv, G, M = dims(q, v)
+        # rowsum(dO * O), laid out as the log-sum-exp is
+        delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1)
+        delta = jnp.transpose(delta.reshape(B, T // bq, bq, Hkv, G),
+                              (0, 3, 1, 4, 2)).reshape(lse.shape)
+        grads = call(
+            _gqa_bwd_kernel, "mxtpu_gqa_attention_bwd", q, v,
+            ("q", "k", "v", "o", "row", "row"), ("q", "dk", "dv"),
+            tuple(shape(flat(x).shape, x.dtype) for x in (q, k, v)),
+            [((M, D), f32), ((T, D), f32), ((T, Dv), f32)]
+            + [((M, D), q.dtype), ((M, Dv), do.dtype)] * (G > 1))(
+            flat(q), flat(k), flat(v), flat(do), lse, delta)
+        return tuple(g.reshape(x.shape) for g, x in zip(grads, (q, k, v)))
+
+    @jax.custom_vjp
+    def attend(q, k, v):
+        return run_forward(q, k, v)[0]
+
+    def attend_fwd(q, k, v):
+        o, lse = run_forward(q, k, v)
+        return o, (q, k, v, o, lse)
+
+    def attend_bwd(res, do):
+        return run_backward(*res, do)
+
+    attend.defvjp(attend_fwd, attend_bwd)
+    return attend
+
+
+def gqa_attention_pallas(q, k, v, scale=None, tiles=None, interpret=False):
+    """The compiled tier of :func:`gqa_attention` (same operands, same
+    result): :func:`_gqa_lax_reason` says which operands it takes.  Query
+    / key heads that are not whole lane tiles (latent attention's 192) are
+    padded with zeros to the next one, which leaves every score as it was.
+    ``tiles`` = (query rows a head, key rows) of a grid step, from the
+    shapes when not given."""
+    T, D = q.shape[1], q.shape[3]
+    scale = float(scale or 1.0 / np.sqrt(D))
+    if -D % 128:
+        q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, -D % 128),)) for x in (q, k))
+    tiles = tiles or _gqa_tiles(T, q.shape[2] // k.shape[2])
+    return _pallas_gqa(scale, tuple(tiles), bool(interpret))(q, k, v)
+
+
+def _gqa_lax_reason(q, v):
+    """Why these operands are not the compiled tier's, or None."""
+    from . import partitioned
+    (_, T, Hq, D), (Hkv, Dv) = q.shape, v.shape[2:]
+    if partitioned():
+        return "mesh"
+    # whole blocks of positions; value heads of whole lane tiles, query /
+    # key heads that pad to one by a third of their width at most; a (row,
+    # key head)'s dk and dv in VMEM, float32 accumulators and the blocks
+    # they leave through
+    pad = -D % 128
+    if _gqa_tiles(T, Hq // Hkv) is None or Dv % 128 or 3 * pad > D \
+            or (4 + 2 * q.dtype.itemsize) * T * (D + pad + Dv) \
+            > _GQA_VMEM // 2:
+        return "shapes"
+    return None
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_q"))
+def _gqa_branch(q, k, v, scale, block_q):
+    """The lax tier as the other platforms' branch of a program that takes
+    the kernels: jitted, so that a model's layers of one shape trace its
+    unrolled blocks, forward and backward, once and not once a layer."""
+    return _gqa(q, k, v, scale, block_q)
+
+
 def gqa_attention(q, k, v, scale=None, block_q=512):
     """Causal grouped-query attention.  q (B, T, Hq, D); k (B, T, Hkv, D)
     and v (B, T, Hkv, Dv) with ``Hq % Hkv == 0``: key/value head ``h``
@@ -409,13 +761,38 @@ def gqa_attention(q, k, v, scale=None, block_q=512):
     narrower (or wider) than the query / key heads: the result follows
     the value, (B, T, Hq, Dv), in q's dtype.
 
-    Query rows go in blocks of ``block_q``; block ``i`` meets only its
-    causal prefix of keys (a static slice), so the work is the lower
-    triangle plus half a block, and the largest tile either pass holds
-    is (B, Hq, block_q, T) scores.  Contractions take the operands'
-    dtype with float32 accumulation; the softmax is float32."""
+    Which tier runs follows from what the trace can see
+    (:func:`_gqa_lax_reason`): the compiled kernels in a program lowered
+    for a TPU, for whole blocks of positions and lane-aligned heads; the
+    lax tier on other platforms, for other shapes and in a program the
+    SPMD partitioner will split.  Each call records one ``kernel.route``
+    event in the program's recorder (``kernel`` = ``gqa_attention``, the
+    tier, the reason: ``aligned``, ``shapes``, ``mesh``) and counts
+    ``kernel.gqa_attention.<tier>``.
+
+    On the lax tier query rows go in blocks of ``block_q``; block ``i``
+    meets only its causal prefix of keys (a static slice), so the work is
+    the lower triangle plus half a block, and the largest tile either pass
+    holds is (B, Hq, block_q, T) scores.  On either tier contractions take
+    the operands' dtype with float32 accumulation; the softmax is
+    float32."""
+    from .. import profiler
+    from . import by_platform
     D = q.shape[-1]
     if q.shape[2] % k.shape[2]:
         raise ValueError("gqa_attention: %d query heads over %d key/value "
                          "heads" % (q.shape[2], k.shape[2]))
-    return _gqa(q, k, v, float(scale or 1.0 / np.sqrt(D)), int(block_q))
+    scale = float(scale or 1.0 / np.sqrt(D))
+    reason = _gqa_lax_reason(q, v)
+    tier = "lax" if reason else "pallas"
+    now = time.perf_counter_ns()
+    profiler.event("kernel.route", now, now, kernel="gqa_attention",
+                   tier=tier, reason=reason or "aligned")
+    profiler.count("kernel.gqa_attention." + tier)
+
+    if reason:
+        return _gqa(q, k, v, scale, int(block_q))
+    return by_platform(
+        functools.partial(gqa_attention_pallas, scale=scale),
+        functools.partial(_gqa_branch, scale=scale, block_q=int(block_q)),
+        q, k, v)

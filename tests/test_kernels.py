@@ -270,6 +270,128 @@ def test_full_attention_routes_to_flash(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# causal grouped-query attention: the compiled tier against the lax tier
+# ---------------------------------------------------------------------------
+
+def _gqa_operands(hq, hkv, d, dv, t, dtype, seed=6):
+    rs = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rs.randn(2, t, h, w).astype("f")).astype(dtype)
+                 for h, w in ((hq, d), (hkv, d), (hkv, dv)))
+
+
+def _gqa_both(fn, q, k, v):
+    """Output and the three gradients of a weighted sum of it."""
+    w = jnp.asarray(np.random.RandomState(7).randn(*q.shape[:3],
+                                                   v.shape[-1]), "f")
+    grads = jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+                     argnums=(0, 1, 2))(q, k, v)
+    return (fn(q, k, v),) + grads
+
+
+def _gqa_routes(since):
+    import time
+    from mxnet_tpu import profiler
+    return [r["ids"] for r in profiler.spans(since, time.perf_counter())
+            if r["name"] == "kernel.route"
+            and r["ids"].get("kernel") == "gqa_attention"]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("hq,hkv,d,dv,t,tiles", [
+    (4, 4, 192, 128, 384, None),          # latent attention: keys padded
+    (8, 2, 128, 128, 384, None),          # four query heads a key head
+    (8, 1, 256, 256, 384, None),          # eight, 256 wide
+    (8, 2, 128, 128, 512, (256, 128)),    # the diagonal crosses two blocks
+    (4, 2, 128, 128, 512, (64, 256)),     # a key block over four row blocks
+], ids=["mla_192_128", "g4_128", "g8_256", "tall_rows", "wide_keys"])
+def test_gqa_attention_compiled_tier_matches_the_lax_tier(
+        hq, hkv, d, dv, t, tiles, dtype):
+    """The kernels in the interpreter against ``gqa_attention``'s lax body,
+    output and all three gradients, over more than one query and key block
+    (blocks on the diagonal, below it and never visited above it): float32
+    at the documented tolerance; bfloat16 finite and, by norm, as close as
+    two bfloat16 programs are."""
+    q, k, v = _gqa_operands(hq, hkv, d, dv, t, dtype)
+    scale = float(d ** -0.5)
+    want = _gqa_both(lambda *a: FA._gqa(*a, scale, 128), q, k, v)
+    got = _gqa_both(lambda *a: FA.gqa_attention_pallas(
+        *a, tiles=tiles, interpret=True), q, k, v)
+    for i, (a, b) in enumerate(zip(got, want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        if dtype == "float32":
+            _close(a, b, dtype, grad=i > 0)
+            continue
+        a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+        assert np.isfinite(a).all()
+        assert np.linalg.norm(a - b) < 0.01 * np.linalg.norm(b)
+
+
+def test_gqa_attention_takes_the_compiled_tier_for_aligned_operands(
+        compiled_tier):
+    """Through ``gqa_attention`` as a program lowered for a TPU resolves
+    it: the kernels' result, one ``kernel.route`` event and the counter."""
+    import time
+    from mxnet_tpu import profiler
+    q, k, v = _gqa_operands(4, 2, 128, 128, 256, "float32")
+    before = profiler.counters().get("kernel.gqa_attention.pallas", 0)
+    since = time.perf_counter()
+    out = FA.gqa_attention(q, k, v)
+    assert _gqa_routes(since) == [{"kernel": "gqa_attention",
+                                   "tier": "pallas", "reason": "aligned"}]
+    assert profiler.counters()["kernel.gqa_attention.pallas"] == before + 1
+    _close(out, FA._gqa(q, k, v, 128 ** -0.5, 512), "float32")
+    assert "mxtpu_gqa_attention_fwd" in str(jax.make_jaxpr(
+        lambda *a: FA.gqa_attention(*a))(q, k, v))
+
+
+@pytest.mark.parametrize("hq,hkv,d,dv,t,mesh,reason", [
+    (4, 2, 128, 128, 200, False, "shapes"),   # positions: no whole block
+    (4, 2, 128, 64, 256, False, "shapes"),    # value heads of half a tile
+    (4, 2, 16, 128, 256, False, "shapes"),    # keys that pad eightfold
+    (4, 2, 128, 128, 256, True, "mesh"),
+], ids=["tail", "narrow_values", "narrow_keys", "mesh"])
+def test_gqa_attention_falls_back_to_the_lax_tier(compiled_tier, hq, hkv, d,
+                                                  dv, t, mesh, reason):
+    """What the kernels do not take keeps to the lax tier even where the
+    platform would take them, bit for bit, and says why."""
+    import contextlib
+    import time
+    from mxnet_tpu import profiler
+    from mxnet_tpu.kernels import auto_partitioned
+    q, k, v = _gqa_operands(hq, hkv, d, dv, t, "float32")
+    before = profiler.counters().get("kernel.gqa_attention.lax", 0)
+    since = time.perf_counter()
+    with auto_partitioned() if mesh else contextlib.nullcontext():
+        got = _gqa_both(FA.gqa_attention, q, k, v)
+        # (a fresh function: a trace is cached by the function traced)
+        text = str(jax.make_jaxpr(lambda *a: FA.gqa_attention(*a))(q, k, v))
+    assert "pallas_call" not in text
+    assert _gqa_routes(since) == [{"kernel": "gqa_attention", "tier": "lax",
+                                   "reason": reason}] * 3
+    assert profiler.counters()["kernel.gqa_attention.lax"] == before + 3
+    want = _gqa_both(lambda *a: FA._gqa(*a, float(d ** -0.5), 512), q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_mxlint_finds_the_gqa_attention_kernels_behind_their_vjp():
+    """``graph-pallas-no-vjp`` on a graph that holds the op with both
+    tiers traced (``lax.platform_dependent``): the kernels are there, and
+    they are behind their ``custom_vjp``."""
+    from mxnet_tpu.analysis import graph_lint
+    from mxnet_tpu.ops.contrib import gq_attention
+    args = _gqa_operands(4, 2, 128, 128, 256, "float32")
+
+    def graph(*a):
+        return gq_attention(*a)
+    report = graph_lint.lint_jit(graph, *args, expect_allgather=False,
+                                 min_donate_bytes=0)
+    assert "pallas_call" in str(jax.make_jaxpr(graph)(*args))
+    assert "graph-pallas-no-vjp" not in {f.rule for f in report.findings}, \
+        report.format_text()
+
+
+# ---------------------------------------------------------------------------
 # routing / registry
 # ---------------------------------------------------------------------------
 

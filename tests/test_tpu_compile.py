@@ -53,3 +53,94 @@ def test_delta_rule_kernels_compile_for_the_v5e(one_chip, rows, t, hk, hv, dk,
     stem = "mxtpu_delta_rule_channel_" if decay == "channel" \
         else "mxtpu_delta_rule_"
     assert compiled_kernels(text) == {stem + "fwd": 1, stem + "bwd": 1}
+
+
+@pytest.mark.parametrize("hq,hkv,d,dv", [
+    (32, 32, 192, 128),     # Kimi Linear's latent attention: keys padded
+    (16, 2, 256, 256),      # Qwen3-Next's: eight query heads a key head
+    (8, 2, 128, 128),       # ZAYA1's latent: four
+], ids=["kimi_linear_8k", "qwen3_next_8k", "zaya1_8k"])
+def test_gqa_attention_kernels_compile_for_the_v5e(one_chip, hq, hkv, d, dv):
+    """Forward and gradient of the compiled causal grouped-query attention
+    at the three language-model cells' shapes (2 x 8,192 positions,
+    bfloat16): one forward kernel, one backward kernel for dq, dk and dv,
+    within the VMEM they ask for."""
+    from mxnet_tpu.kernels import compiled_kernels
+    from mxnet_tpu.kernels.flash_attention import (_gqa_lax_reason,
+                                                   gqa_attention_pallas)
+    specs = tuple(jax.ShapeDtypeStruct((2, 8192, h, w), jnp.bfloat16,
+                                       sharding=one_chip)
+                  for h, w in ((hq, d), (hkv, d), (hkv, dv)))
+    assert _gqa_lax_reason(specs[0], specs[2]) is None
+
+    def grads(*a):
+        return jax.grad(lambda *x: jnp.sum(gqa_attention_pallas(*x).astype(
+            jnp.float32)), argnums=(0, 1, 2))(*a)
+    text = jax.jit(grads).lower(*specs).compile().as_text()
+    assert compiled_kernels(text) == {"mxtpu_gqa_attention_fwd": 1,
+                                      "mxtpu_gqa_attention_bwd": 1}
+
+
+def _attention_stage(model, seq_len):
+    """One attention stage of a language model at its published head
+    widths and grouping, few heads, a narrow stream."""
+    import mxnet_tpu as mx
+    x = mx.sym.Variable("data")
+    if model == "kimi_linear":          # 192-wide keys, 128-wide values
+        from mxnet_tpu.models.kimi_linear import _mla
+        return _mla(x, "l3_mla", seq_len, dict(
+            hidden_size=64, num_attention_heads=2, kv_lora_rank=32,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            rms_norm_eps=1e-5))
+    rope = dict(partial_rotary_factor=0.25, rope_theta=1e7,
+                rope_parameters={"hybrid": {"rope_theta": 5e6}})
+    if model == "qwen3_next":           # eight query heads a key head
+        from mxnet_tpu.models.qwen3_next import _gated_attention
+        return _gated_attention(x, "l3_attn", seq_len, dict(
+            rope, hidden_size=64, num_attention_heads=8,
+            num_key_value_heads=1, head_dim=256, rms_norm_eps=1e-6))
+    from mxnet_tpu.models.zaya import _cca  # four, 128 wide
+    return _cca(x, "l0_cca", seq_len, dict(
+        rope, hidden_size=64, num_attention_heads=4, num_key_value_heads=1,
+        head_dim=128, cca_time0=2, cca_time1=2, rms_norm_eps=1e-5))
+
+
+@pytest.mark.parametrize("model", ["kimi_linear", "qwen3_next", "zaya"])
+def test_a_tpu_lowering_of_each_attention_stage_takes_the_kernels(model):
+    """The models' own attention stages, rematerialised as the models
+    build them, in a bfloat16 training step: lowered for a TPU the step
+    holds the attention's two kernels, lowered for the CPU none; the one
+    ``GQAttention`` lowering says so in the recorder."""
+    import time
+    import numpy as np
+    import mxnet_tpu as mx
+    from mxnet_tpu import profiler
+    from mxnet_tpu.kernels import compiled_kernels
+    from mxnet_tpu.parallel import SPMDTrainer, default_mesh
+    seq_len, rows = 256, 2
+    with mx.AttrScope(mirror_stage="stage"):
+        stage = _attention_stage(model, seq_len)
+    net = mx.sym.LinearRegressionOutput(stage, mx.sym.Variable("label"),
+                                        name="out")
+    tr = SPMDTrainer(net, "sgd", {"learning_rate": 0.1, "rescale_grad": 1.0},
+                     mesh=default_mesh(devices=jax.devices()[:1]),
+                     compute_dtype="bfloat16")
+    try:
+        tr.bind([("data", (rows * seq_len, 64))],
+                [("label", (rows * seq_len, 64))])
+        tr.init_params(mx.initializer.Xavier())
+        args = tr._example_args(np.zeros((rows * seq_len, 64), "f"),
+                                np.zeros((rows * seq_len, 64), "f"))
+        since = time.perf_counter()
+        traced = tr._step_fn.trace(*args)
+        routes = [r["ids"] for r in profiler.spans(since, time.perf_counter())
+                  if r["name"] == "kernel.route"]
+        tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+        cpu = traced.lower(lowering_platforms=("cpu",)).as_text()
+    finally:
+        tr.close()
+    assert routes and all(r == {"kernel": "gqa_attention", "tier": "pallas",
+                                "reason": "aligned"} for r in routes), routes
+    assert set(compiled_kernels(tpu)) == {"mxtpu_gqa_attention_fwd",
+                                          "mxtpu_gqa_attention_bwd"}
+    assert compiled_kernels(cpu) == {}
