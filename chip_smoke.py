@@ -291,7 +291,7 @@ def phase_train(symbol, rec_prefix, image, batch, epochs, ckpt_dir=None,
     losses, stamps = [], [time.perf_counter()]
     seen = [0.0, 0]
     # the window opens once step total-3 has completed: the last three steps
-    trace = StepTraceCapture(trace_dir, total - 3, total) \
+    trace = StepTraceCapture(trace_dir, total - 3, total, trainer=trainer) \
         if trace_dir else None
 
     def on_batch(param):

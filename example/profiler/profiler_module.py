@@ -1,11 +1,14 @@
 """Per-op device profiling of a fused training step (reference
 example/profiler/*: profiler_executor.py / profiler_matmul.py).
 
-Trains a small CNN for a few steps under mx.profiler mode='all_xla',
-then prints mx.profiler.dumps(): per-graph-node device times recovered
-from XLA HLO metadata — forward rows under the layer name, backward
-rows as _backward_<name>, exactly the reference's per-op profile table
-(src/engine/profiler.cc) but over a FUSED XLA program.
+Trains a small CNN for a few fused steps under mx.profiler mode='all_xla',
+then prints mx.profiler.dumps(): per-graph-node device times — forward
+rows under the layer name, backward rows as _backward_<name>, the
+trainer's own work as step.update / step.guard / ..., exactly the
+reference's per-op profile table (src/engine/profiler.cc) but over a FUSED
+XLA program.  A TPU trace names device events by HLO instruction; the
+graph node is the instruction's op_name in the compiled step's text, which
+the trainer hands over (``step_text()``).
 
 Device-op events need a real accelerator backend; on cpu the script
 still writes the host-engine Chrome trace (profile.json).
@@ -21,6 +24,7 @@ sys.path.insert(0, os.path.join(
 
 import mxnet_tpu as mx
 from mxnet_tpu import profiler
+from mxnet_tpu.parallel import SPMDTrainer
 
 
 def main(steps=3, out_dir="/tmp/mxtpu_profile"):
@@ -35,26 +39,21 @@ def main(steps=3, out_dir="/tmp/mxtpu_profile"):
                                 name="fc1")
     net = mx.sym.SoftmaxOutput(net, name="softmax")
 
-    it = mx.io.NDArrayIter(np.random.rand(64, 3, 24, 24).astype("f"),
-                           np.random.randint(0, 10, 64).astype("f"),
-                           batch_size=32)
-    mod = mx.mod.Module(net)
-    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
-    mod.init_params(initializer=mx.initializer.Xavier())
-    mod.init_optimizer(optimizer="sgd")
-    b = next(iter(it))
-    mod.forward_backward(b)
-    mod.update()                      # compile outside the trace
+    batch = (np.random.rand(32, 3, 24, 24).astype("f"),
+             np.random.randint(0, 10, 32).astype("f"))
+    trainer = SPMDTrainer(net, "sgd", {"learning_rate": 0.1,
+                                       "rescale_grad": 1.0 / 32})
+    trainer.bind([("data", (32, 3, 24, 24))], [("softmax_label", (32,))])
+    trainer.init_params(mx.initializer.Xavier())
+    trainer.step(*batch)              # compile outside the trace
 
     profiler.profiler_set_config(
         mode="all_xla", filename=os.path.join(out_dir, "profile.json"),
         trace_dir=os.path.join(out_dir, "xla"))
     profiler.profiler_set_state("run")
     for _ in range(steps):
-        mod.forward_backward(b)
-        mod.update()
-    for v in mod.get_outputs():
-        v.wait_to_read()
+        outs = trainer.step(*batch)
+    jax.block_until_ready(outs)
     profiler.profiler_set_state("stop")
 
     os.makedirs(out_dir, exist_ok=True)
@@ -63,7 +62,8 @@ def main(steps=3, out_dir="/tmp/mxtpu_profile"):
         print("cpu backend: no device-op events; host trace written to",
               os.path.join(out_dir, "profile.json"))
         return None
-    table = profiler.dumps(trace_dir=os.path.join(out_dir, "xla"))
+    table = profiler.dumps(trace_dir=os.path.join(out_dir, "xla"),
+                           hlo_text=trainer.step_text())
     print(table)
     return table
 

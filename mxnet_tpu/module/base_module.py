@@ -312,7 +312,7 @@ class BaseModule(object):
             # MXTPU_PROFILE_DIR: capture a jax.profiler trace of steps
             # 10-15 of the first epoch (None when the env is unset)
             from .. import profiler as _profiler
-            trace = _profiler.StepTraceCapture.from_env()
+            trace = _profiler.StepTraceCapture.from_env(fused_trainer)
             _span = _profiler.span
             nstep = 0   # batches this fit() has taken: the spans' `step`
 

@@ -281,6 +281,7 @@ class SPMDTrainer(object):
         self.opt_state = None
         self._num_update = 0
         self._step_fn = None
+        self._last_batch = None           # the last stepped batch, as shapes
         self._eval_fn = None
         self._outputs = None
 
@@ -626,13 +627,27 @@ class SPMDTrainer(object):
         def cast(p):
             if compute_dtype is None:
                 return p
-            return {k: v.astype(compute_dtype)
-                    if jnp.issubdtype(v.dtype, jnp.floating) else v
-                    for k, v in p.items()}
+            with jax.named_scope("step.cast"):
+                return {k: v.astype(compute_dtype)
+                        if jnp.issubdtype(v.dtype, jnp.floating) else v
+                        for k, v in p.items()}
+
+        def gathered(params):
+            """The zero branch's view of the parameters: cast, pinned to
+            the shard, then widened to ``gathered_spec``."""
+            full, shards = {}, cast(params)
+            with jax.named_scope("step.cast"):
+                for k, v in shards.items():
+                    v = jax.lax.with_sharding_constraint(
+                        v, self._sharding(self._param_spec(k, v.shape)))
+                    full[k] = jax.lax.with_sharding_constraint(
+                        v, gathered_spec[k])
+            return full
 
         def step(params, aux, opt_state, extras, data, rng, lr, wd, t):
             raw_data = data  # pre-transform inputs (labels for metrics)
-            data = xform(data)
+            with jax.named_scope("step.input"):
+                data = xform(data)
             if zero:
                 # cast the dp-sharded f32 master to compute dtype BEFORE
                 # gathering, so the per-param AllGathers (which the
@@ -642,12 +657,7 @@ class SPMDTrainer(object):
                 # is pinned to the SHARD spec so the partitioner cannot
                 # hoist the gather above the convert (which would double
                 # the gathered bytes).
-                full = {}
-                for k, v in cast(params).items():
-                    v = jax.lax.with_sharding_constraint(
-                        v, self._sharding(self._param_spec(k, v.shape)))
-                    full[k] = jax.lax.with_sharding_constraint(
-                        v, gathered_spec[k])
+                full = gathered(params)
             else:
                 full = params
 
@@ -656,20 +666,25 @@ class SPMDTrainer(object):
                     p = cast(p)
                 merged = dict(data)
                 merged.update(p)
+                # no scope around the graph's evaluation: it would be the
+                # first jvp(...) part of every node's path, which is
+                # where a trace's reader looks for the node
                 outs, auxu = eval_fn(merged, aux, rng, True)
                 return tuple(outs), auxu
 
             outs, vjp_fn, auxu = jax.vjp(loss_fn, full, has_aux=True)
-            heads = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
+            with jax.named_scope("step.seed"):
+                heads = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
             grads, = vjp_fn(heads)
             if zero:
                 # constrain each gradient (still compute dtype) to its
                 # param's dp shard: GSPMD lowers the batch-psum + shard
                 # slice to a ReduceScatter issued as soon as the grad
                 # exists during backward
-                grads = {name: jax.lax.with_sharding_constraint(
-                    g, self._sharding(self._param_spec(name, g.shape)))
-                    for name, g in grads.items()}
+                with jax.named_scope("step.sync"):
+                    grads = {name: jax.lax.with_sharding_constraint(
+                        g, self._sharding(self._param_spec(name, g.shape)))
+                        for name, g in grads.items()}
             return self._step_tail(params, aux, opt_state, extras,
                                    raw_data, outs, auxu, grads,
                                    lr, wd, t)
@@ -679,13 +694,7 @@ class SPMDTrainer(object):
                 # same comm discipline as step(): cast the shard to
                 # compute dtype (pinned to shard space) BEFORE the
                 # gather, so eval AGs also move bf16 bytes
-                full = {}
-                for k, v in cast(params).items():
-                    v = jax.lax.with_sharding_constraint(
-                        v, self._sharding(self._param_spec(k, v.shape)))
-                    full[k] = jax.lax.with_sharding_constraint(
-                        v, gathered_spec[k])
-                params = full
+                params = gathered(params)
             elif compute_dtype is not None:
                 params = cast(params)
             merged = xform(data)
@@ -736,43 +745,48 @@ class SPMDTrainer(object):
         guard = self.step_guard
         metric_fn = self._metric_fn
         maxbad = self.max_consecutive_bad_steps
+        scope = jax.named_scope
         finite = None
-        counters = [outs[i] for i in self._counter_heads]
-        outs = self._outputs_only(outs)
+        with scope("step.counters"):
+            counters = [outs[i] for i in self._counter_heads]
+            outs = self._outputs_only(outs)
         if guard:
             # all-finite over every gradient, folded into the same XLA
             # program (one fused reduction tree) — the in-graph analog
             # of DynamicLossScale / Orbax-era skip-step guards
-            finite = jnp.asarray(True)
-            for name in self.param_names:
-                finite = jnp.logical_and(
-                    finite, jnp.all(jnp.isfinite(grads[name])))
-            if finite_reduce is not None:
-                finite = finite_reduce(finite)
+            with scope("step.guard"):
+                finite = jnp.asarray(True)
+                for name in self.param_names:
+                    finite = jnp.logical_and(
+                        finite, jnp.all(jnp.isfinite(grads[name])))
+                if finite_reduce is not None:
+                    finite = finite_reduce(finite)
         new_params, new_state = {}, {}
-        for name in self.param_names:
-            g = grads[name].astype(params[name].dtype)
-            w, s = self._apply_update(name, params[name], g,
-                                      opt_state[name], lr, wd, t)
-            if guard:
-                # non-finite step: params AND optimizer state pass
-                # through unchanged (selects fuse into the update)
-                w = jnp.where(finite, w, params[name])
-                s = tuple(jnp.where(finite, sn, so)
-                          for sn, so in zip(s, opt_state[name]))
-            new_params[name] = w
-            new_state[name] = s
         new_aux = dict(aux)
         new_aux.update(auxu)
+        with scope("step.update"):
+            for name in self.param_names:
+                g = grads[name].astype(params[name].dtype)
+                w, s = self._apply_update(name, params[name], g,
+                                          opt_state[name], lr, wd, t)
+                if guard:
+                    # non-finite step: params AND optimizer state pass
+                    # through unchanged (selects fuse into the update)
+                    w = jnp.where(finite, w, params[name])
+                    s = tuple(jnp.where(finite, sn, so)
+                              for sn, so in zip(s, opt_state[name]))
+                new_params[name] = w
+                new_state[name] = s
+            if guard:
+                # BN moving stats computed from a poisoned batch must not
+                # stick either
+                for name, v in auxu.items():
+                    new_aux[name] = jnp.where(finite, v, aux[name])
         new_extras = {}
-        if guard:
-            # BN moving stats computed from a poisoned batch must not
-            # stick either
-            for name, v in auxu.items():
-                new_aux[name] = jnp.where(finite, v, aux[name])
         if aux_reduce is not None:
-            for name in auxu:
-                new_aux[name] = aux_reduce(new_aux[name])
+            with scope("step.sync"):
+                for name in auxu:
+                    new_aux[name] = aux_reduce(new_aux[name])
         if guard:
             # in-graph skip accounting: totals accumulate, the
             # consecutive run resets on any good step, and ``trips``
@@ -785,16 +799,17 @@ class SPMDTrainer(object):
             # single device->host transfer, not three (three scalar
             # fetches were measurable per-step host work on the
             # dispatch-bound LSTM path over a high-RTT device link).
-            g = extras["guard"]
-            total, consec, trips = g[0], g[1], g[2]
-            new_consec = jnp.where(finite, jnp.zeros_like(consec),
-                                   consec + 1)
-            if maxbad > 0:
-                trips = trips + (new_consec == maxbad).astype(
-                    trips.dtype)
-            new_extras["guard"] = jnp.stack(
-                [jnp.where(finite, total, total + 1), new_consec,
-                 trips])
+            with scope("step.guard"):
+                g = extras["guard"]
+                total, consec, trips = g[0], g[1], g[2]
+                new_consec = jnp.where(finite, jnp.zeros_like(consec),
+                                       consec + 1)
+                if maxbad > 0:
+                    trips = trips + (new_consec == maxbad).astype(
+                        trips.dtype)
+                new_extras["guard"] = jnp.stack(
+                    [jnp.where(finite, total, total + 1), new_consec,
+                     trips])
             # the same 12 bytes once more, as an output that is no
             # carry: the next step donates "guard", this one the host
             # can still read after it has dispatched that step
@@ -804,15 +819,16 @@ class SPMDTrainer(object):
             # outputs and (pre-transform) labels; a guard-skipped
             # step contributes nothing — EXACT parity with the
             # blocking host path, which drops skipped steps too
-            msum, mcnt = extras["metric"]
-            ds, dc = metric_fn(list(outs), raw_data)
-            if metric_reduce is not None:
-                ds = metric_reduce(ds)
-                dc = metric_reduce(dc)
-            if guard:
-                ds = jnp.where(finite, ds, jnp.zeros_like(ds))
-                dc = jnp.where(finite, dc, jnp.zeros_like(dc))
-            new_extras["metric"] = (msum + ds, mcnt + dc)
+            with scope("step.metric"):
+                msum, mcnt = extras["metric"]
+                ds, dc = metric_fn(list(outs), raw_data)
+                if metric_reduce is not None:
+                    ds = metric_reduce(ds)
+                    dc = metric_reduce(dc)
+                if guard:
+                    ds = jnp.where(finite, ds, jnp.zeros_like(ds))
+                    dc = jnp.where(finite, dc, jnp.zeros_like(dc))
+                new_extras["metric"] = (msum + ds, mcnt + dc)
         if counters:
             new_extras["counters"] = counters
         return new_params, new_aux, new_state, new_extras, list(outs)
@@ -864,18 +880,21 @@ class SPMDTrainer(object):
 
         def step(params, aux, opt_state, extras, data, rng, lr, wd, t):
             raw_data = data
-            data = xform(data)
+            with jax.named_scope("step.input"):
+                data = xform(data)
             if manual:
                 # decorrelate per-device stochastic draws (Dropout):
                 # each dp shard folds its axis index so masks are
                 # independent across the global batch, deterministic
                 # per seed
-                rng = jax.random.fold_in(rng, jax.lax.axis_index(axis))
+                with jax.named_scope("step.seed"):
+                    rng = jax.random.fold_in(rng, jax.lax.axis_index(axis))
 
             def loss_fn(p):
                 cp = cast(p)
                 full = dict(cp)
-                full.update(gather_grouped({n: cp[n] for n in grouped}))
+                with jax.named_scope("step.sync"):
+                    full.update(gather_grouped({n: cp[n] for n in grouped}))
                 merged = dict(data)
                 merged.update(full)
                 outs, auxu = eval_fn(merged, aux, rng, True)
@@ -883,19 +902,22 @@ class SPMDTrainer(object):
 
             loss_ck = jax.checkpoint(loss_fn, policy=policy)
             outs, vjp_fn, auxu = jax.vjp(loss_ck, params, has_aux=True)
-            heads = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
+            with jax.named_scope("step.seed"):
+                heads = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
             grads, = vjp_fn(heads)
-            if manual:
-                # grouped params arrived REDUCE-SCATTERED (all_gather's
-                # transpose); ungrouped (replicated) params hold local
-                # partials — psum them (tiny residue: indivisible dims)
-                grads = {n: (g if n in grouped
-                             else jax.lax.psum(g, axis))
-                         for n, g in grads.items()}
-            else:
-                grads = {n: jax.lax.with_sharding_constraint(
-                    g, self._sharding(self._param_spec(n, g.shape)))
-                    for n, g in grads.items()}
+            with jax.named_scope("step.sync"):
+                if manual:
+                    # grouped params arrived REDUCE-SCATTERED
+                    # (all_gather's transpose); ungrouped (replicated)
+                    # params hold local partials — psum them (tiny
+                    # residue: indivisible dims)
+                    grads = {n: (g if n in grouped
+                                 else jax.lax.psum(g, axis))
+                             for n, g in grads.items()}
+                else:
+                    grads = {n: jax.lax.with_sharding_constraint(
+                        g, self._sharding(self._param_spec(n, g.shape)))
+                        for n, g in grads.items()}
             return self._step_tail(
                 params, aux, opt_state, extras, raw_data, outs, auxu,
                 grads, lr, wd, t,
@@ -1121,6 +1143,9 @@ class SPMDTrainer(object):
         the mesh, the key, the schedule's scalars, the accumulators."""
         from .. import random as _random
         data = self._resolve_batch(batch_arrays)
+        # what step_text() lowers the step for: shapes, not the arrays
+        self._last_batch = [(k, v.shape, v.dtype, v.sharding)
+                            for k, v in data.items()]
         self._num_update += 1
         lr = self.optimizer.lr if self.optimizer.lr_scheduler is None else \
             self.optimizer.lr_scheduler(self._num_update)
@@ -1795,15 +1820,32 @@ class SPMDTrainer(object):
         args = self._example_args(*batch_arrays)
         return self._lint_args(args, min_donate_bytes=min_donate_bytes)
 
-    def _example_args(self, *batch_arrays):
+    def step_text(self):
+        """The compiled step's text (HLO) for the shapes of the last batch
+        stepped.  A device trace names its events by HLO instruction; the
+        instruction's ``op_name`` — the path of ``jax.named_scope``s it
+        was traced under: graph node, ``mirror_stage``, ``step.*`` — is
+        only here (``profiler.get_op_stats(trace_dir, hlo_text=...)``).
+        Costs one more trace + lowering of the step; the compile is the
+        compile cache's where that is on.  None before the first step."""
+        if self._last_batch is None:
+            return None
+        args = self._example_args(data={
+            k: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for k, shape, dtype, sharding in self._last_batch})
+        return self._step_fn.lower(*args).compile().as_text()
+
+    def _example_args(self, *batch_arrays, data=None):
         """The fully assembled argument tuple ``_step_fn`` would see for
-        one batch — what ``analyze`` lints, and what a caller lowers for
+        one batch (or for ``data``, the batch as shapes) — what
+        ``analyze`` lints, and what a caller lowers for
         ``memory_analysis`` without dispatching a step."""
         from .. import random as _random
         if self._step_fn is None or self.params is None:
             raise MXNetError(
                 "SPMDTrainer.analyze: bind() and init_params() first")
-        data = self._eval_batch(batch_arrays)
+        if data is None:
+            data = self._eval_batch(batch_arrays)
         extras = {}
         if self.step_guard:
             extras["guard"] = self._guard_acc if self._guard_acc \

@@ -12,7 +12,10 @@ Three collectors, one Chrome ``traceEvents`` dump in the reference's format
   (mode='all_xla', or ``MXTPU_PROFILE_DIR`` under ``fit``) — viewable in
   TensorBoard/Perfetto, the TPU analog of the reference's per-kernel GPU
   stats.  :func:`idle_gaps` lays the spans over such a trace and says what
-  the host was doing while the device sat idle.
+  the host was doing while the device sat idle; :func:`get_op_stats` /
+  :func:`dumps` put the device's busy time down to graph nodes,
+  ``mirror_stage``s (forward, rematerialised, backward) and the fused
+  step's own ``step.*`` scopes.
 
 The span recorder is always on and bounded: the last ``RING`` spans stay in
 memory, so a live job can be asked for its last minute.  A step of ``fit``
@@ -36,9 +39,11 @@ Env parity: MXNET_PROFILER_AUTOSTART=1 starts profiling at import
 from __future__ import annotations
 
 import collections
+import gzip
 import itertools
 import json
 import os
+import re
 import threading
 import time
 
@@ -223,20 +228,24 @@ class StepTraceCapture(object):
 
     #: the spans of the traced window, written beside the trace by stop()
     SPANS_FILE = "mxnet_tpu_spans.trace.json"
+    #: the compiled step's text, written there too when ``trainer`` is
+    #: given: where :func:`get_op_stats` finds each device event's scope
+    STEP_FILE = "mxnet_tpu_step.hlo.txt"
 
-    def __init__(self, directory, start_step=10, stop_step=15):
+    def __init__(self, directory, start_step=10, stop_step=15, trainer=None):
         self.directory = os.fspath(directory)
         self.start_step = int(start_step)
         self.stop_step = int(stop_step)
+        self.trainer = trainer      # an SPMDTrainer (its ``step_text``)
         self._active = False
         self._done = False
         self._since = None
 
     @classmethod
-    def from_env(cls):
+    def from_env(cls, trainer=None):
         """A capture configured from MXTPU_PROFILE_DIR, or None."""
         directory = get_env(ENV_PROFILE_DIR)
-        return cls(directory) if directory else None
+        return cls(directory, trainer=trainer) if directory else None
 
     def on_batch(self, nbatch):
         if self._done:
@@ -270,6 +279,10 @@ class StepTraceCapture(object):
                                 lambda r: r["unix_ns"] / 1e3)
         with open(os.path.join(self.directory, self.SPANS_FILE), "w") as f:
             json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
+        text = self.trainer.step_text() if self.trainer is not None else None
+        if text:
+            with open(os.path.join(self.directory, self.STEP_FILE), "w") as f:
+                f.write(text)
         import logging
         logging.getLogger(__name__).info(
             "StepTraceCapture: wrote steps %d-%d trace to %s",
@@ -349,19 +362,17 @@ def _newest_profile_file(trace_dir, suffix):
     return max(cands, key=os.path.getmtime)
 
 
-def _latest_device_trace(trace_dir=None):
-    """Newest ``.trace.json.gz`` (already Chrome traceEvents format) under
-    the configured trace directory: profile with mode='all_xla' first."""
-    return _newest_profile_file(
-        trace_dir or _config["trace_dir"]
-        or os.path.splitext(_config["filename"])[0] + "_xla",
-        ".trace.json.gz")
+def _recorded_path(stats):
+    """The scope path XLA recorded on a device event, or None (a TPU trace
+    of this jax records none: the path is in the compiled step's text)."""
+    return stats.get("tf_op") or stats.get("op_name") or None
 
 
 def _device_lines(trace_dir):
     """(profile_start_time in Unix ns, {device plane: {line name:
-    [(start_ns, duration_ns, name)]}}) of the newest ``.xplane.pb`` under
-    ``trace_dir``; event starts count from profile_start_time."""
+    [(start_ns, duration_ns, name, recorded path)]}}) of the newest
+    ``.xplane.pb`` under ``trace_dir``; event starts count from
+    profile_start_time."""
     data = jax.profiler.ProfileData.from_file(
         _newest_profile_file(trace_dir, ".xplane.pb"))
     start, devices = None, {}
@@ -370,21 +381,19 @@ def _device_lines(trace_dir):
             start = dict(plane.stats).get("profile_start_time")
         elif plane.name.startswith("/device:TPU:"):
             devices[plane.name] = {
-                line.name: [(e.start_ns, e.duration_ns, e.name)
+                line.name: [(e.start_ns, e.duration_ns, e.name,
+                             _recorded_path(dict(e.stats)))
                             for e in line.events] for line in plane.lines}
     return start, devices
 
 
-def _attribute_gaps(start_ns, devices, records, top=10):
-    """:func:`idle_gaps` on plain data: ``devices`` as
-    :func:`_device_lines` gives them, ``records`` as :func:`spans`."""
-    if start_ns is None:
-        raise MXNetError("the trace has no profile_start_time: its events "
-                         "cannot be put on the spans' clock")
+def _busiest(devices):
+    """(plane name, merged busy intervals [[start, end]]) of the device
+    whose ``XLA Ops`` cover the most time."""
     busy = {}
     for name, lines in devices.items():
         merged = []
-        for s, d, _ in sorted(lines.get("XLA Ops", ())):
+        for s, d in sorted(e[:2] for e in lines.get("XLA Ops", ())):
             if merged and s <= merged[-1][1]:
                 merged[-1][1] = max(merged[-1][1], s + d)
             else:
@@ -394,8 +403,16 @@ def _attribute_gaps(start_ns, devices, records, top=10):
     if not busy:
         raise MXNetError("the trace holds no device plane with XLA Ops: %r"
                          % sorted(devices))
-    device, merged = max(busy.items(),
-                         key=lambda kv: sum(e - s for s, e in kv[1]))
+    return max(busy.items(), key=lambda kv: sum(e - s for s, e in kv[1]))
+
+
+def _attribute_gaps(start_ns, devices, records, top=10):
+    """:func:`idle_gaps` on plain data: ``devices`` as
+    :func:`_device_lines` gives them, ``records`` as :func:`spans`."""
+    if start_ns is None:
+        raise MXNetError("the trace has no profile_start_time: its events "
+                         "cannot be put on the spans' clock")
+    device, merged = _busiest(devices)
     # whole nanoseconds: a float holds Unix nanoseconds to 256 of them
     gaps = [(start_ns + round(a[1]), start_ns + round(b[0]))
             for a, b in zip(merged, merged[1:])]
@@ -453,89 +470,235 @@ def idle_gaps(trace_dir, top=10):
     return _attribute_gaps(start_ns, devices, records, top)
 
 
-def _scope_of(event):
-    """Graph-node name for one device HLO event.
+# -- the op table ------------------------------------------------------------
 
-    XLA stamps the jax named_scope path into the event's ``tf_op``
-    metadata (e.g. ``jit(step)/conv2/conv_general_dilated:``); the
-    executor wraps every symbol node in named_scope(node.name), so the
-    middle path segments ARE graph node names.  Events without tf_op
-    (DMA copies, infeed) fall back to their HLO category."""
-    args = event.get("args") or {}
-    tf_op = args.get("tf_op", "")
-    parts = [p for p in tf_op.rstrip(":").split("/") if p]
-    if parts and parts[0].startswith("jit("):
-        parts = parts[1:]
-    if len(parts) >= 2:
-        name = "/".join(parts[:-1])     # named-scope path, primitive off
-    elif parts:
-        name = parts[0]
-    else:
-        return args.get("hlo_category", event.get("name", "?"))
-    # autodiff wrappers -> the reference's fwd/bwd naming: jvp(conv1) is
-    # the forward op, transpose(jvp(conv1)) its backward
-    # (_backward_Convolution in the reference's profile)
-    import re
-    m = re.fullmatch(r"transpose\(jvp\((.+)\)\)", name)
-    if m:
-        return "_backward_" + m.group(1)
-    m = re.fullmatch(r"jvp\((.+)\)", name)
-    if m:
-        return m.group(1)
-    return name
+_PASSES = {"forward": "", "remat": "_remat_", "backward": "_backward_"}
+# parts of an op_name path that jax writes for its own transformations
+_JAX_PARTS = ("", "checkpoint", "rematted_computation", "shard_map")
+_HLO_LINE = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?op_name="([^"]+)"', re.M)
+_INSTRUCTION = re.compile(r"^%?([\w.\-]+)")
 
 
-def get_op_stats(trace_dir=None):
-    """Per-graph-node device-time stats from the newest XLA trace:
-    {name: {"count": n, "total_us": t, "avg_us": a, "min_us": m,
-    "max_us": M}}.  Works on fused (jit) programs — the reference's
-    per-op profile needed per-op engine dispatch; here HLO metadata
-    attributes fused-program time back to symbol nodes."""
-    import gzip
-    import json
-    path = _latest_device_trace(trace_dir)
+def _parse_path(path):
+    """``(names, pass, staged)`` of one ``op_name`` path.
+
+    The executor wraps every graph node in ``jax.named_scope(node.name)``,
+    every ``mirror_stage`` in one of the stage's name around its
+    checkpoint, and the trainer its own work in ``step.*``; autodiff wraps
+    the outermost scope: ``jit(step)/jvp(l1_kda)/l1_kda_proj/dot_general``
+    is a stage's forward, ``.../transpose(jvp(l1_kda))/jvp(l1_kda)/
+    checkpoint/l1_kda_proj/transpose`` its backward and ``.../checkpoint/
+    rematted_computation/l1_kda_proj/sub`` its rematerialised forward;
+    ``jvp(conv1)`` / ``transpose(jvp(conv1))`` are an unstaged node's.
+    ``names`` are the scopes somebody wrote, outermost first: the parts
+    without the first (``jit(...)``), jax's own and the primitive — which
+    stands in where there is no scope; ``staged`` says that a checkpoint
+    sits directly under the first of them."""
+    parts = [p for p in path.rstrip(":").split("/") if p][1:]
+    names, backward, remat, staged = [], False, False, False
+    for part in parts[:-1]:
+        while part.endswith(")") and part.startswith(("transpose(", "jvp(")):
+            backward = backward or part.startswith("transpose(")
+            part = part[part.index("(") + 1:-1]
+        remat = remat or part == "rematted_computation"
+        staged = staged or (part == "checkpoint" and len(names) == 1)
+        if part not in _JAX_PARTS and part not in names[-1:]:
+            names.append(part)
+    return (names or parts[-1:],
+            "remat" if remat else "backward" if backward else "forward",
+            staged)
+
+
+def _instruction_paths(hlo_text):
+    """{instruction name: op_name path} of a compiled module's text (a
+    fusion's line carries its root's path)."""
+    paths = {}
+    for name, path in _HLO_LINE.findall(hlo_text or ""):
+        paths.setdefault(name, path)
+    return paths
+
+
+def _self_times(events):
+    """[(event, self ns)] in order of start: an event's duration less that
+    of the events nested directly inside it (a ``while`` spans its body's
+    operations; events of one line nest or are disjoint)."""
+    out, stack = [], []
+    for e in sorted(events, key=lambda e: (e[0], -e[1])):
+        while stack and e[0] >= stack[-1][0][0] + stack[-1][0][1]:
+            stack.pop()
+        if stack:
+            stack[-1][1] -= e[1]
+        stack.append([e, e[1]])
+        out.append(stack[-1])
+    return [(e, max(t, 0)) for e, t in out]
+
+
+def _op_table(events, hlo_text=None):
+    """:func:`get_op_stats` on plain data: the ``XLA Ops`` events of one
+    device as :func:`_device_lines` gives them."""
+    paths = _instruction_paths(hlo_text)
+    timed = []
+    for event, self_ns in _self_times(events):
+        instruction = _INSTRUCTION.match(event[2])
+        instruction = instruction.group(1) if instruction else "?"
+        path = event[3] or paths.get(instruction)
+        timed.append((instruction, path, self_ns / 1e3))
+    # a path the program wrote starts with the jitted function; XLA puts
+    # names of its own in a path's place (ragged-dot-none, a parameter's)
+    parsed = {p: _parse_path(p) for _, p, _ in timed
+              if p and p.startswith("jit(")}
+    stages = {names[0] for names, _, staged in parsed.values() if staged}
+
+    rows, near = {}, ""
+    for instruction, path, us in timed:
+        if path in parsed:
+            names, which, _ = parsed[path]
+            stage = names[0] if names[0] in stages else ""
+            node = names[0] if not stage else \
+                names[1] if len(names) > 1 else ""
+            near = _PASSES[which] + (stage or node)
+            name = near + ("/" + node if stage and node else "")
+            fields = {"stage": stage, "pass": which, "node": node}
+        else:
+            # the compiler's own: a copy, a slice, a clone, or a name it
+            # put in the path's place (ragged-dot-none)
+            name = path or "hlo:" + re.sub(r"(\.\d+|\.clone)+$", "",
+                                           instruction)
+            fields = {"near": {}}
+        row = rows.setdefault(name, dict(
+            fields, count=0, total_us=0.0, min_us=float("inf"), max_us=0.0))
+        row["count"] += 1
+        row["total_us"] += us
+        row["min_us"] = min(row["min_us"], us)
+        row["max_us"] = max(row["max_us"], us)
+        if "near" in row:
+            row["near"][near] = row["near"].get(near, 0.0) + us
+    for row in rows.values():
+        for key in ("total_us", "min_us", "max_us"):
+            row[key] = round(row[key], 3)
+        row["avg_us"] = round(row["total_us"] / row["count"], 3)
+        if "near" in row:
+            row["near"] = {k: round(v, 3) for k, v in row["near"].items()}
+    return rows
+
+
+def _recorded_lines(path):
+    """A trace kept as a plain structure (what ``benchmark/run.py
+    --keep-trace`` writes, and the tests' recorded cuts): ``{"planes":
+    [{"name", "lines": [{"name", "events": [[name, start_ns, duration_ns,
+    {stat: value}]]}]}]}``, gzipped JSON -> what :func:`_device_lines`
+    gives."""
     with gzip.open(path, "rt") as f:
-        data = json.load(f)
-    stats = {}
-    for ev in data.get("traceEvents", []):
-        args = ev.get("args") or {}
-        if "device_duration_ps" not in args:
-            continue    # host-side event
-        if "tf_op" not in args and "hlo_category" not in args:
-            continue    # step marker / whole-module span, not an HLO op
-        us = int(args["device_duration_ps"]) / 1e6
-        s = stats.setdefault(_scope_of(ev), {
-            "count": 0, "total_us": 0.0,
-            "min_us": float("inf"), "max_us": 0.0})
-        s["count"] += 1
-        s["total_us"] += us
-        s["min_us"] = min(s["min_us"], us)
-        s["max_us"] = max(s["max_us"], us)
-    for s in stats.values():
-        s["total_us"] = round(s["total_us"], 3)
-        s["min_us"] = round(s["min_us"], 3)
-        s["max_us"] = round(s["max_us"], 3)
-        s["avg_us"] = round(s["total_us"] / s["count"], 3)
-    return stats
+        planes = json.load(f)["planes"]
+    return {plane["name"]: {
+        line["name"]: [(e[1], e[2], e[0], _recorded_path(e[3]))
+                       for e in line["events"]] for line in plane["lines"]}
+        for plane in planes if plane["name"].startswith("/device:TPU:")}
 
 
-def dumps(reset=False, trace_dir=None):
-    """Per-op device-time table from the newest XLA trace (reference
-    mx.profiler.dumps / profiler.cc:134-216 per-op stats, over the FUSED
-    program).  ``reset`` is accepted for API parity (traces are
-    per-start_trace already)."""
+def get_op_stats(trace_dir=None, hlo_text=None):
+    """Device time by graph node from a ``jax.profiler`` trace of the chip:
+    ``{name: {"count", "total_us", "avg_us", "min_us", "max_us", ...}}``
+    (the reference's per-op profile, src/engine/profiler.cc:134-216, over
+    a FUSED program).
+
+    Read: the newest ``.xplane.pb`` under ``trace_dir`` (default: where
+    ``mode='all_xla'`` traces; a file is taken as a trace kept by
+    ``benchmark/run.py --keep-trace``), the busiest device's ``XLA Ops``
+    line, each event's SELF time.  An event's scope path is the one XLA
+    recorded on it, else — a TPU trace of this jax names events by HLO
+    instruction and records none — the instruction's ``op_name`` in
+    ``hlo_text``, the compiled step's text (``SPMDTrainer.step_text()``;
+    default: ``mxnet_tpu_step.hlo.txt`` in the directory, which
+    ``StepTraceCapture`` writes, or ``<file>.hlo.txt``).  Without either
+    every row is the compiler's own.
+
+    Rows are by (stage, pass, node), each also a field of the row: a
+    ``mirror_stage``'s node is ``l1_kda/l1_kda_conv_q``, rematerialised
+    ``_remat_l1_kda/l1_kda_conv_q``, backward ``_backward_l1_kda/...``; a
+    node under no stage ``conv1`` / ``_backward_conv1``; the trainer's own
+    work ``step.update``, ``step.guard``, ...  Events whose path the
+    program did not write — ``hlo:copy``, ``hlo:slice-done``,
+    ``ragged-dot-none``, clones — have no stage; their ``near`` is
+    ``{stage and pass of the last event WITH a path before them on the
+    line: us}``.  That is adjacency on the device's timeline, not the
+    compiler's word: such an event may serve a later stage or none."""
+    trace = trace_dir or _config["trace_dir"] \
+        or os.path.splitext(_config["filename"])[0] + "_xla"
+    if os.path.isdir(trace) or not os.path.exists(trace):
+        devices = _device_lines(trace)[1]
+        text = os.path.join(trace, StepTraceCapture.STEP_FILE)
+    else:
+        devices, text = _recorded_lines(trace), trace + ".hlo.txt"
+    if hlo_text is None and os.path.exists(text):
+        with open(text) as f:
+            hlo_text = f.read()
+    return _op_table(devices[_busiest(devices)[0]]["XLA Ops"], hlo_text)
+
+
+def dumps(reset=False, trace_dir=None, hlo_text=None):
+    """:func:`get_op_stats` as a table (reference mx.profiler.dumps):
+    every stage with its three passes' subtotals above its nodes, the
+    nodes under no stage and the ``step.*`` scopes a line each, then the
+    compiler's own events grouped by ``near`` (of a group, the names that
+    hold at least a hundredth of it).  ``reset`` is accepted for API parity
+    (traces are per-start_trace already)."""
     del reset
-    stats = get_op_stats(trace_dir)
-    order = sorted(stats.items(), key=lambda kv: -kv[1]["total_us"])
-    w = max([len("Name")] + [len(k) for k, _ in order]) + 2
-    lines = ["Profile Statistics (device time, fused program)",
+    stats = get_op_stats(trace_dir, hlo_text)
+    width = max([len("Name")] + [len(k) + 2 for k in stats]) + 2
+    lines = ["Profile Statistics (device self time, fused program): "
+             "%.3f us in %d rows" % (
+                 sum(s["total_us"] for s in stats.values()), len(stats)),
              "%-*s %10s %12s %12s %12s %12s" % (
-                 w, "Name", "Count", "Total-us", "Min-us", "Max-us",
+                 width, "Name", "Count", "Total-us", "Min-us", "Max-us",
                  "Avg-us")]
-    for name, s in order:
+
+    def line(name, s, indent=""):
         lines.append("%-*s %10d %12.3f %12.3f %12.3f %12.3f" % (
-            w, name, s["count"], s["total_us"], s["min_us"], s["max_us"],
-            s["avg_us"]))
+            width, indent + name, s["count"], s["total_us"], s["min_us"],
+            s["max_us"], s["avg_us"]))
+
+    def total(rows):
+        return sum(s["total_us"] for _, s in rows)
+
+    def by_time(rows):
+        return sorted(rows, key=lambda kv: -kv[1]["total_us"])
+
+    pathed = [(k, s) for k, s in stats.items() if "near" not in s]
+    stages = {}
+    for k, s in pathed:
+        if s["stage"]:
+            stages.setdefault(s["stage"], []).append((k, s))
+    for stage, rows in sorted(stages.items(), key=lambda kv: -total(kv[1])):
+        lines.append("stage %s: %.3f us" % (stage, total(rows)))
+        for which, prefix in _PASSES.items():
+            part = [(k, s) for k, s in rows if s["pass"] == which]
+            if part:
+                lines.append("  %-*s %10d %12.3f" % (
+                    width - 2, prefix + stage + " (%s)" % which,
+                    sum(s["count"] for _, s in part), total(part)))
+                for k, s in by_time(part):
+                    line(k, s, "    ")
+    lines.append("under no stage")
+    for k, s in by_time((k, s) for k, s in pathed if not s["stage"]):
+        line(k, s, "  ")
+    own = [(k, s) for k, s in stats.items() if "near" in s]
+    if own:
+        lines.append("the compiler's own, by what ran before them on the "
+                     "device (adjacency, not attribution): %.3f us"
+                     % total(own))
+        groups = {}
+        for k, s in own:
+            for where, us in s["near"].items():
+                groups.setdefault(where, {})[k] = us
+        for where, members in sorted(groups.items(),
+                                     key=lambda kv: -sum(kv[1].values())):
+            lines.append("  near %s: %.3f us" % (where or "(nothing)",
+                                                 sum(members.values())))
+            for k, us in sorted(members.items(), key=lambda kv: -kv[1]):
+                if us >= 0.01 * sum(members.values()):
+                    lines.append("    %-*s %23.3f" % (width - 4, k, us))
     return "\n".join(lines) + "\n"
 
 

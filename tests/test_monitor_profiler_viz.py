@@ -94,12 +94,15 @@ def test_per_op_stats_over_fused_program(tmp_path):
     """Per-op device times from a FUSED (jit) training step: HLO op_name
     metadata (stamped by the executor's named_scope per symbol node) maps
     device events back to graph node names — the reference's per-op
-    profile (src/engine/profiler.cc:134-216) over an XLA program.
+    profile (src/engine/profiler.cc:134-216) over an XLA program.  The
+    chip's trace names events by HLO instruction, so the names come from
+    the compiled step's text (``SPMDTrainer.step_text``).
     Device-side HLO events only exist on a real accelerator backend."""
     import jax
     if jax.default_backend() == "cpu":
         pytest.skip("XLA device-op trace events need a TPU backend")
     from mxnet_tpu import profiler
+    from mxnet_tpu.parallel import SPMDTrainer
     import numpy as np
 
     data = mx.sym.Variable("data")
@@ -109,32 +112,29 @@ def test_per_op_stats_over_fused_program(tmp_path):
     net = mx.sym.FullyConnected(mx.sym.Flatten(net), num_hidden=10,
                                 name="fc1")
     net = mx.sym.SoftmaxOutput(net, name="softmax")
-    mod = mx.mod.Module(net)
-    it = mx.io.NDArrayIter(np.random.rand(64, 3, 16, 16).astype("f"),
-                           np.random.randint(0, 10, 64).astype("f"),
-                           batch_size=32)
+    trainer = SPMDTrainer(net, "sgd", {"learning_rate": 0.1,
+                                       "rescale_grad": 1.0 / 32})
+    trainer.bind([("data", (32, 3, 16, 16))], [("softmax_label", (32,))])
+    trainer.init_params(mx.initializer.Xavier())
+    batch = (np.random.rand(32, 3, 16, 16).astype("f"),
+             np.random.randint(0, 10, 32).astype("f"))
     profiler.profiler_set_config(
         mode="all_xla", filename=str(tmp_path / "prof.json"),
         trace_dir=str(tmp_path / "xla"))
-    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
-    mod.init_params(initializer=mx.initializer.Xavier())
-    mod.init_optimizer(optimizer="sgd")
-    b = next(iter(it))
-    mod.forward_backward(b)
-    mod.update()          # compile outside the trace
+    trainer.step(*batch)          # compile outside the trace
     profiler.profiler_set_state("run")
     for _ in range(3):
-        mod.forward_backward(b)
-        mod.update()
-    for v in mod.get_outputs():
-        v.wait_to_read()
+        outs = trainer.step(*batch)
+    jax.block_until_ready(outs)
     profiler.profiler_set_state("stop")
 
-    stats = profiler.get_op_stats(str(tmp_path / "xla"))
+    text = trainer.step_text()
+    stats = profiler.get_op_stats(str(tmp_path / "xla"), hlo_text=text)
     names = set(stats)
     # forward and backward of named layers appear with device times
     assert any(n.startswith("conv1") or n == "conv1" for n in names), names
     assert "_backward_conv1" in names, names
-    assert all(s["total_us"] > 0 for s in stats.values())
-    table = profiler.dumps(trace_dir=str(tmp_path / "xla"))
+    assert "step.update" in names, names
+    assert stats["_backward_conv1"]["total_us"] > 0
+    table = profiler.dumps(trace_dir=str(tmp_path / "xla"), hlo_text=text)
     assert "Profile Statistics" in table and "_backward_conv1" in table

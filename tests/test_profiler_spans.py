@@ -315,7 +315,13 @@ class _FakeTrace(object):
 def test_step_trace_capture_traces_the_device_only_and_writes_its_spans(
         tmp_path, monkeypatch):
     fake = _FakeTrace(monkeypatch)
-    capture = profiler.StepTraceCapture(str(tmp_path / "tr"), 2, 3)
+
+    class Trainer(object):
+        def step_text(self):
+            return 'HloModule jit_step\n %f.1 = ... op_name="jit(step)/a/b"'
+
+    capture = profiler.StepTraceCapture(str(tmp_path / "tr"), 2, 3,
+                                        trainer=Trainer())
     with profiler.span("t.before_window"):
         pass
     for nbatch in range(6):
@@ -334,6 +340,28 @@ def test_step_trace_capture_traces_the_device_only_and_writes_its_spans(
     assert "t.before_window" not in [e["name"] for e in events]
     # Unix microseconds
     assert abs(events[0]["ts"] * 1e3 - time.time_ns()) < 60e9
+    # the compiled step's text beside them, for the op table's paths
+    with open(os.path.join(directory, capture.STEP_FILE)) as f:
+        assert f.read() == Trainer().step_text()
+    assert capture.STEP_FILE == "mxnet_tpu_step.hlo.txt"
+
+
+def test_step_trace_capture_writes_no_step_text_without_a_trainer(
+        tmp_path, monkeypatch):
+    """Nobody handed it a trainer, or the trainer has stepped no batch."""
+    _FakeTrace(monkeypatch)
+
+    class Unstepped(object):
+        def step_text(self):
+            return None
+
+    for trainer in (None, Unstepped()):
+        capture = profiler.StepTraceCapture(str(tmp_path), 0, 0,
+                                            trainer=trainer)
+        capture.on_batch(0)
+        capture.stop()
+        assert os.path.exists(tmp_path / capture.SPANS_FILE)
+        assert not os.path.exists(tmp_path / capture.STEP_FILE)
 
 
 # -- one clock with the device trace --------------------------------------------
