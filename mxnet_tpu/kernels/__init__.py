@@ -99,6 +99,25 @@ kernel that wins its cell is unconditional, ``ROADMAP.md`` Design 2):
   2 key heads of 256, 22.3 / 49.9 and 8.9 / 27.7; 8 / 2 of 128, 8.9 / 22.2
   and 2.75 / 7.43 (PERF.md, PR 34).
 
+- ``causal_conv`` — the depthwise causal convolution of ``CausalConv1D``
+  (:mod:`.causal_conv`): ``mxtpu_causal_conv_fwd`` / ``mxtpu_causal_conv_bwd``,
+  a (positions x channels) tile of one row a grid step with the 16 positions
+  beside it as a second block of the same operand, widened in VMEM; taps,
+  float32 sum and ``silu`` in the lax tier's order, ``dx`` the flipped
+  convolution over the recomputed pre-activation, ``dw`` added up in a
+  float32 block that never leaves VMEM; residuals the inputs.  Compiled in a
+  program lowered for a TPU when the channels are whole lane tiles, the
+  positions whole blocks of 32, the taps 2 to 8, ``act_type`` None or
+  ``silu`` and the data bfloat16 or float32 (reason ``aligned``); the lax
+  tier otherwise (``shapes``) and under :func:`auto_partitioned` (``mesh``).
+  Each lowering records a ``kernel.route`` event (kernel ``causal_conv``);
+  the benchmark's ``causal_conv_kernel_share`` reads them.  On the v5e at
+  the benchmark's shapes (2 x 8,192 positions, bfloat16; forward / forward
+  + backward): 4,096 channels at 4 taps with ``silu``, lax tier 2.31 / 7.55
+  ms, kernels 0.50 / 1.40; 8,192 channels, 4.97 / 14.92 and 0.96 / 2.72;
+  1,280 channels at 2 taps, plain, 0.24 / 0.80 and 0.22 / 0.48 (PERF.md,
+  PR 36).
+
 The plan-level passes live in :mod:`mxnet_tpu.mxfuse` (the
 match-and-rewrite framework over the executor's node plan); this
 registry routes them exactly like the kernel bodies.

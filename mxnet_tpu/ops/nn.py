@@ -519,7 +519,13 @@ def causal_conv1d(data, weight, kernel=4, act_type=None, num_group=0):
     positions, channels).  Depthwise (``num_group`` 0, the default):
     weight (channels, kernel),
     ``y[t] = sum_j weight[:, j] * x[t - (kernel-1) + j]`` with zeros before
-    a row's start; the taps are summed in float32.  Grouped (``num_group``
+    a row's start; the taps are summed in float32
+    (:func:`mxnet_tpu.kernels.causal_conv.causal_conv`: in a program lowered
+    for a TPU the compiled kernels, for channels in multiples of 128,
+    positions in multiples of 32, 2 to 8 taps, ``act_type`` None or
+    ``silu`` and bfloat16 or float32 data; the lax tier on other platforms,
+    for other operands and under a mesh the partitioner splits — the same
+    arithmetic either way).  Grouped (``num_group``
     g > 0): weight (channels, channels / g, kernel), output channel ``o``
     mixing the channels of its own group over the taps,
     ``y[t][o] = sum_j sum_{i in group(o)} weight[o, i, j] * x[t - (kernel-1)
@@ -527,16 +533,13 @@ def causal_conv1d(data, weight, kernel=4, act_type=None, num_group=0):
     on operands of the data's dtype.  ``act_type`` as in ``Activation``,
     taken in float32."""
     k, g = int(kernel), int(num_group)
-    if g:
-        y = lax.conv_general_dilated(
-            data, weight.astype(data.dtype), window_strides=(1,),
-            padding=[(k - 1, 0)], dimension_numbers=("NWC", "OIW", "NWC"),
-            feature_group_count=g)
-    else:
-        t = data.shape[1]
-        x = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
-        w = weight.astype(jnp.float32)
-        y = sum(x[:, j:j + t] * w[:, j] for j in range(k))
+    if not g:
+        from ..kernels.causal_conv import causal_conv
+        return causal_conv(data, weight, k, act_type)
+    y = lax.conv_general_dilated(
+        data, weight.astype(data.dtype), window_strides=(1,),
+        padding=[(k - 1, 0)], dimension_numbers=("NWC", "OIW", "NWC"),
+        feature_group_count=g)
     if act_type:
         y = activation(y.astype(jnp.float32), act_type)
     return y.astype(data.dtype)

@@ -27,9 +27,9 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu.kernels import (bn_act as BA, flash_attention as FA,
-                               lstm_cell as LC, enabled_kernels,
-                               fused_enabled)
+from mxnet_tpu.kernels import (bn_act as BA, causal_conv as CC,
+                               flash_attention as FA, lstm_cell as LC,
+                               enabled_kernels, fused_enabled)
 from mxnet_tpu.ops import nn as NN
 
 #: documented Pallas-interpret tolerances per dtype (forward; gradients
@@ -288,12 +288,12 @@ def _gqa_both(fn, q, k, v):
     return (fn(q, k, v),) + grads
 
 
-def _gqa_routes(since):
+def _gqa_routes(since, kernel="gqa_attention"):
     import time
     from mxnet_tpu import profiler
     return [r["ids"] for r in profiler.spans(since, time.perf_counter())
             if r["name"] == "kernel.route"
-            and r["ids"].get("kernel") == "gqa_attention"]
+            and r["ids"].get("kernel") == kernel]
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -387,6 +387,151 @@ def test_mxlint_finds_the_gqa_attention_kernels_behind_their_vjp():
     report = graph_lint.lint_jit(graph, *args, expect_allgather=False,
                                  min_donate_bytes=0)
     assert "pallas_call" in str(jax.make_jaxpr(graph)(*args))
+    assert "graph-pallas-no-vjp" not in {f.rule for f in report.findings}, \
+        report.format_text()
+
+
+# ---------------------------------------------------------------------------
+# depthwise causal convolution: the compiled tier against the lax tier
+# ---------------------------------------------------------------------------
+
+def _conv_operands(t, c, k, dtype, rows=2, seed=8):
+    rs = np.random.RandomState(seed)
+    return (jnp.asarray(rs.randn(rows, t, c).astype("f")).astype(dtype),
+            jnp.asarray(0.5 * rs.randn(c, k).astype("f")).astype(dtype),
+            jnp.asarray(rs.randn(rows, t, c).astype("f")).astype(dtype))
+
+
+def _conv_both(fn, x, w, dy):
+    """y, dx and dw under the cotangent ``dy``."""
+    y, vjp = jax.vjp(fn, x, w)
+    return (y,) + vjp(dy)
+
+
+def _conv_routes(since):
+    return _gqa_routes(since, kernel="causal_conv")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("act", [None, "silu"])
+@pytest.mark.parametrize("taps", [2, 4])
+def test_causal_conv_compiled_tier_matches_the_lax_tier(taps, act, dtype):
+    """The kernels in the interpreter against the lax tier, y, dx and dw,
+    over three position blocks and two channel blocks of two rows (the
+    taps reach across a block's boundary, both ways): float32 to 1e-5 of
+    the largest entry, bfloat16 within the rounding of one store."""
+    x, w, dy = _conv_operands(96, 256, taps, dtype)
+    want = _conv_both(lambda *a: CC.causal_conv_lax(*a, taps, act), x, w, dy)
+    got = _conv_both(lambda *a: CC.causal_conv_pallas(
+        *a, taps, act, tiles=(32, 128), interpret=True), x, w, dy)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        a, b = (np.asarray(v.astype(jnp.float32)) for v in (a, b))
+        tol = 1e-5 if dtype == "float32" else 2.0 ** -8
+        np.testing.assert_allclose(a, b, rtol=tol,
+                                   atol=tol * np.abs(b).max())
+
+
+@pytest.mark.parametrize("act", [None, "silu"])
+def test_causal_conv_kernels_keep_a_row_to_itself(act):
+    """Zeros before a row's start for y and past its end for dx, whatever
+    the rows beside it hold: the second row of a batch of two reads as it
+    does alone, and a position hears of nothing after it."""
+    x, w, dy = _conv_operands(64, 128, 4, "float32")
+
+    def run(x, dy):
+        return _conv_both(lambda *a: CC.causal_conv_pallas(
+            *a, 4, act, tiles=(32, 128), interpret=True), x, w, dy)
+    y, dx, _ = run(x, dy)
+    loud = x.at[0].set(1e4), dy.at[0].set(1e4)
+    for got, alone in zip(run(*loud)[:2], run(x[1:], dy[1:])[:2]):
+        np.testing.assert_array_equal(got[1:], alone)
+    # y[t] reads x[t-3 .. t] and dx[t] reads dy[t .. t+3], of its own row
+    later = run(x.at[:, 40:].set(7.0), dy)[0]
+    np.testing.assert_array_equal(later[:, :40], y[:, :40])
+    earlier = run(x, dy.at[:, :40].set(7.0))[1]
+    np.testing.assert_array_equal(earlier[:, 40:], dx[:, 40:])
+    np.testing.assert_array_equal(
+        y[:, 0], np.asarray(CC.causal_conv_lax(x[:, :1], w, 4, act))[:, 0])
+
+
+def test_causal_conv_takes_the_compiled_tier_for_aligned_operands(
+        compiled_tier):
+    """Through the op as a program lowered for a TPU resolves it: the
+    kernels' result, one ``kernel.route`` event and the counter."""
+    import time
+    from mxnet_tpu import profiler
+    x, w, _ = _conv_operands(64, 256, 4, "float32")
+    before = profiler.counters().get("kernel.causal_conv.pallas", 0)
+    since = time.perf_counter()
+    out = NN.causal_conv1d(x, w, kernel=4, act_type="silu")
+    assert _conv_routes(since) == [{"kernel": "causal_conv",
+                                    "tier": "pallas", "reason": "aligned"}]
+    assert profiler.counters()["kernel.causal_conv.pallas"] == before + 1
+    _close(out, CC.causal_conv_lax(x, w, 4, "silu"), "float32")
+    assert "mxtpu_causal_conv_fwd" in str(jax.make_jaxpr(
+        lambda *a: NN.causal_conv1d(*a, kernel=4, act_type="silu"))(x, w))
+
+
+@pytest.mark.parametrize("t,c,taps,act,mesh,reason", [
+    (64, 100, 4, "silu", False, "shapes"),    # channels: no whole lane tile
+    (70, 128, 4, "silu", False, "shapes"),    # a ragged row
+    (64, 128, 4, "tanh", False, "shapes"),    # an activation not the kernels'
+    (64, 128, 9, None, False, "shapes"),      # more taps than a halo holds
+    (64, 128, 4, "silu", True, "mesh"),
+], ids=["channels", "ragged", "tanh", "taps", "mesh"])
+def test_causal_conv_falls_back_to_the_lax_tier(compiled_tier, t, c, taps,
+                                                act, mesh, reason):
+    """What the kernels do not take keeps to the lax tier even where the
+    platform would take them, bit for bit, and says why."""
+    import contextlib
+    import time
+    from mxnet_tpu import profiler
+    from mxnet_tpu.kernels import auto_partitioned
+    x, w, dy = _conv_operands(t, c, taps, "float32")
+
+    def op(x, w):
+        return NN.causal_conv1d(x, w, kernel=taps, act_type=act)
+    before = profiler.counters().get("kernel.causal_conv.lax", 0)
+    since = time.perf_counter()
+    with auto_partitioned() if mesh else contextlib.nullcontext():
+        got = _conv_both(op, x, w, dy)
+        # (a fresh function: a trace is cached by the function traced)
+        text = str(jax.make_jaxpr(lambda *a: op(*a))(x, w))
+    assert "pallas_call" not in text
+    assert _conv_routes(since) == [{"kernel": "causal_conv", "tier": "lax",
+                                    "reason": reason}] * 2
+    assert profiler.counters()["kernel.causal_conv.lax"] == before + 2
+    want = _conv_both(lambda *a: CC.causal_conv_lax(*a, taps, act), x, w, dy)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_causal_conv_on_the_cpu_is_the_lax_tier_bit_for_bit():
+    """Routed to the kernels, lowered for the CPU: ``platform_dependent``
+    takes the lax branch, which is the op as it was."""
+    x, w, dy = _conv_operands(64, 256, 4, "bfloat16")
+    got = _conv_both(lambda *a: NN.causal_conv1d(
+        *a, kernel=4, act_type="silu"), x, w, dy)
+    want = _conv_both(lambda *a: CC.causal_conv_lax(*a, 4, "silu"), x, w, dy)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
+
+
+def test_mxlint_finds_the_causal_conv_kernels_behind_their_vjp():
+    """``graph-pallas-no-vjp`` on a graph that holds the op with both tiers
+    traced: the kernels are there, and they are behind their
+    ``custom_vjp``."""
+    from mxnet_tpu.analysis import graph_lint
+    x, w, _ = _conv_operands(64, 128, 4, "float32")
+
+    def graph(*a):
+        return NN.causal_conv1d(*a, kernel=4, act_type="silu")
+    report = graph_lint.lint_jit(graph, x, w, expect_allgather=False,
+                                 min_donate_bytes=0)
+    assert "pallas_call" in str(jax.make_jaxpr(graph)(x, w))
     assert "graph-pallas-no-vjp" not in {f.rule for f in report.findings}, \
         report.format_text()
 

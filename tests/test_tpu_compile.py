@@ -81,11 +81,9 @@ def test_gqa_attention_kernels_compile_for_the_v5e(one_chip, hq, hkv, d, dv):
                                       "mxtpu_gqa_attention_bwd": 1}
 
 
-def _attention_stage(model, seq_len):
+def _attention_stage(model, x, seq_len):
     """One attention stage of a language model at its published head
     widths and grouping, few heads, a narrow stream."""
-    import mxnet_tpu as mx
-    x = mx.sym.Variable("data")
     if model == "kimi_linear":          # 192-wide keys, 128-wide values
         from mxnet_tpu.models.kimi_linear import _mla
         return _mla(x, "l3_mla", seq_len, dict(
@@ -105,21 +103,19 @@ def _attention_stage(model, seq_len):
         head_dim=128, cca_time0=2, cca_time1=2, rms_norm_eps=1e-5))
 
 
-@pytest.mark.parametrize("model", ["kimi_linear", "qwen3_next", "zaya"])
-def test_a_tpu_lowering_of_each_attention_stage_takes_the_kernels(model):
-    """The models' own attention stages, rematerialised as the models
-    build them, in a bfloat16 training step: lowered for a TPU the step
-    holds the attention's two kernels, lowered for the CPU none; the one
-    ``GQAttention`` lowering says so in the recorder."""
+def _lower_stage(stage):
+    """A stage, rematerialised as the models build theirs, in a bfloat16
+    training step over (2 x 256 positions, 64 wide): the ``kernel.route``
+    events of its trace and the step's text lowered for a TPU and for the
+    CPU."""
     import time
     import numpy as np
     import mxnet_tpu as mx
     from mxnet_tpu import profiler
-    from mxnet_tpu.kernels import compiled_kernels
     from mxnet_tpu.parallel import SPMDTrainer, default_mesh
     seq_len, rows = 256, 2
     with mx.AttrScope(mirror_stage="stage"):
-        stage = _attention_stage(model, seq_len)
+        stage = stage(mx.sym.Variable("data"), seq_len)
     net = mx.sym.LinearRegressionOutput(stage, mx.sym.Variable("label"),
                                         name="out")
     tr = SPMDTrainer(net, "sgd", {"learning_rate": 0.1, "rescale_grad": 1.0},
@@ -135,12 +131,86 @@ def test_a_tpu_lowering_of_each_attention_stage_takes_the_kernels(model):
         traced = tr._step_fn.trace(*args)
         routes = [r["ids"] for r in profiler.spans(since, time.perf_counter())
                   if r["name"] == "kernel.route"]
-        tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
-        cpu = traced.lower(lowering_platforms=("cpu",)).as_text()
+        return (routes, traced.lower(lowering_platforms=("tpu",)).as_text(),
+                traced.lower(lowering_platforms=("cpu",)).as_text())
     finally:
         tr.close()
+
+
+@pytest.mark.parametrize("model", ["kimi_linear", "qwen3_next", "zaya"])
+def test_a_tpu_lowering_of_each_attention_stage_takes_the_kernels(model):
+    """The models' own attention stages in a bfloat16 training step:
+    lowered for a TPU the step holds the attention's two kernels, lowered
+    for the CPU none; the one ``GQAttention`` lowering says so in the
+    recorder."""
+    from mxnet_tpu.kernels import compiled_kernels
+    routes, tpu, cpu = _lower_stage(
+        lambda x, seq_len: _attention_stage(model, x, seq_len))
+    routes = [r for r in routes if r["kernel"] == "gqa_attention"]
     assert routes and all(r == {"kernel": "gqa_attention", "tier": "pallas",
                                 "reason": "aligned"} for r in routes), routes
-    assert set(compiled_kernels(tpu)) == {"mxtpu_gqa_attention_fwd",
-                                          "mxtpu_gqa_attention_bwd"}
+    assert {k for k in compiled_kernels(tpu) if "gqa" in k} == {
+        "mxtpu_gqa_attention_fwd", "mxtpu_gqa_attention_bwd"}
+    assert compiled_kernels(cpu) == {}
+
+
+@pytest.mark.parametrize("c,taps,act", [
+    (4096, 4, "silu"),      # a Kimi Delta Attention stage's q, k and v
+    (8192, 4, "silu"),      # Qwen3-Next's q, k and v in one stream
+    (1280, 2, None),        # ZAYA1's latent queries and keys
+], ids=["kimi_linear_8k", "qwen3_next_8k", "zaya1_8k"])
+def test_causal_conv_kernels_compile_for_the_v5e(one_chip, c, taps, act):
+    """Forward and both gradients of the compiled depthwise causal
+    convolution at the three language-model cells' shapes (2 x 8,192
+    positions, bfloat16): one kernel each way, within the VMEM they ask
+    for."""
+    from mxnet_tpu.kernels import compiled_kernels
+    from mxnet_tpu.kernels.causal_conv import (_lax_reason,
+                                               causal_conv_pallas)
+    x = jax.ShapeDtypeStruct((2, 8192, c), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((c, taps), jnp.bfloat16, sharding=one_chip)
+    assert _lax_reason(x, taps, act) is None
+
+    def both(x, w, dy):         # (the gradients alone need no forward)
+        y, vjp = jax.vjp(lambda *a: causal_conv_pallas(*a, taps, act), x, w)
+        return (y,) + vjp(dy)
+    text = jax.jit(both).lower(x, w, x).compile().as_text()
+    assert compiled_kernels(text) == {"mxtpu_causal_conv_fwd": 1,
+                                      "mxtpu_causal_conv_bwd": 1}
+
+
+def _mixer_stage(model, x, seq_len):
+    """The stage of a language model that holds its short convolutions, at
+    its published head widths and taps, few heads, a narrow stream."""
+    if model == "kimi_linear":          # three convolutions, 4 taps, silu
+        from mxnet_tpu.models.kimi_linear import _kda
+        return _kda(x, "l0_kda", seq_len, dict(
+            hidden_size=64, rms_norm_eps=1e-5, linear_attn_config=dict(
+                num_heads=2, head_dim=128, short_conv_kernel_size=4)))
+    if model == "qwen3_next":           # one, over q, k and v
+        from mxnet_tpu.models.qwen3_next import _gated_delta_net
+        return _gated_delta_net(x, "l0_gdn", seq_len, dict(
+            hidden_size=64, linear_num_key_heads=1, linear_num_value_heads=2,
+            linear_key_head_dim=128, linear_value_head_dim=128,
+            linear_conv_kernel_dim=4, rms_norm_eps=1e-6))
+    return _attention_stage(model, x, seq_len)  # one, 2 taps, plain
+
+
+@pytest.mark.parametrize("model,convs", [("kimi_linear", 3),
+                                         ("qwen3_next", 1), ("zaya", 1)])
+def test_a_tpu_lowering_of_each_mixer_stage_takes_the_convolution_kernels(
+        model, convs):
+    """A KDA, a DeltaNet and a CCA stage in a bfloat16 training step:
+    every depthwise ``CausalConv1D`` of the stage says ``pallas`` in the
+    recorder, and lowered for a TPU the step holds the convolution's two
+    kernels, lowered for the CPU none."""
+    from mxnet_tpu.kernels import compiled_kernels
+    routes, tpu, cpu = _lower_stage(
+        lambda x, seq_len: _mixer_stage(model, x, seq_len))
+    routes = [r for r in routes if r["kernel"] == "causal_conv"]
+    assert routes and len(routes) % convs == 0 and all(
+        r == {"kernel": "causal_conv", "tier": "pallas", "reason": "aligned"}
+        for r in routes), routes
+    assert {"mxtpu_causal_conv_fwd", "mxtpu_causal_conv_bwd"} <= set(
+        compiled_kernels(tpu))
     assert compiled_kernels(cpu) == {}
