@@ -43,7 +43,10 @@ Tiers (package docstring):
 
   On either the bf16 gradient is finite (masked scores are a large
   finite negative, never ``-inf``), contractions take the operands' dtype
-  into float32 and the softmax is float32.
+  into float32 and the softmax is float32.  Either takes a ``window``
+  (sliding-window attention: a row sees that many keys up to its own): the
+  compiled schedule then skips the key blocks outside it, the lax tier
+  slices them off, and unset it is the program it was.
 - :func:`flash_attention_pallas` — a ``pl.pallas_call`` kernel (grid
   over batch x heads x query blocks x key blocks, the running triple in
   VMEM scratch across the key axis) behind ``jax.custom_vjp``; the
@@ -341,17 +344,53 @@ def _after(x, done):
     return x if done is None else lax.optimization_barrier((x, done))[0]
 
 
-def _gqa_scores(qi, k, i, bq, scale):
-    """Scores of query block ``i`` against its causal key prefix, f32:
-    qi (B, bq, Hkv, G, D), k (B, Lk, Hkv, D) -> (B, Hkv, G, bq, Lk)."""
+def _gqa_window(window, T):
+    """``window`` as the tiers take it: None where every row sees its
+    whole causal prefix (0, None, or ``window`` keys and more than there
+    are positions), else the count of keys a row sees, its own
+    included."""
+    window = int(window or 0)
+    if window < 0:
+        raise ValueError("gqa_attention: window %d" % window)
+    return window if 0 < window < T else None
+
+
+def _gqa_span(i, bq, T, window):
+    """Rows [lo, hi) of query block ``i`` and the first key its first row
+    sees: the block meets keys [first, hi)."""
+    lo, hi = i * bq, min((i + 1) * bq, T)
+    return lo, hi, 0 if window is None else max(0, lo - window + 1)
+
+
+def _gqa_scores(qi, k, i, bq, scale, first=0, window=None):
+    """Scores of query block ``i`` against the keys it meets — its causal
+    prefix, from key ``first`` on —, f32: qi (B, bq, Hkv, G, D), k (B, Lk,
+    Hkv, D) -> (B, Hkv, G, bq, Lk).  With a ``window`` the keys more than
+    ``window - 1`` before a row are masked too, where the block's last row
+    has any."""
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qi, k,
                    preferred_element_type=jnp.float32) * scale
     q_pos = i * bq + lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
     k_pos = lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
-    return jnp.where(q_pos >= k_pos, s, _MASKED)
+    if first:
+        k_pos = k_pos + first
+    seen = q_pos >= k_pos
+    if window is not None and i * bq + s.shape[-2] > window:
+        seen = seen & (k_pos > q_pos - window)
+    return jnp.where(seen, s, _MASKED)
 
 
-def _gqa_fwd_blocks(q, k, v, scale, block_q):
+def _gqa_lax_steps(T, bq, window):
+    """The (row block, key tile of ``bq`` keys) pairs the lax tier's
+    slices touch: what its ``kernel.route`` event calls steps."""
+    steps = 0
+    for i in range(-(-T // bq)):
+        _, hi, first = _gqa_span(i, bq, T, window)
+        steps += -(-hi // bq) - first // bq
+    return steps
+
+
+def _gqa_fwd_blocks(q, k, v, scale, block_q, window=None):
     B, T, Hq, D = q.shape
     Hkv = k.shape[2]
     bq, nq = _gqa_blocks(T, block_q)
@@ -359,31 +398,31 @@ def _gqa_fwd_blocks(q, k, v, scale, block_q):
     chained = _gqa_chained(B, Hq, T)
     outs, lses = [], []
     for i in range(nq):
-        lo, hi = i * bq, min((i + 1) * bq, T)
+        lo, hi, first = _gqa_span(i, bq, T, window)
         qi = _after(q5[:, lo:hi], outs[-1] if chained and outs else None)
-        s = _gqa_scores(qi, k[:, :hi], i, bq, scale)
+        s = _gqa_scores(qi, k[:, first:hi], i, bq, scale, first, window)
         m = jnp.max(s, axis=-1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
-        o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v[:, :hi],
-                       preferred_element_type=jnp.float32)
+        o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype),
+                       v[:, first:hi], preferred_element_type=jnp.float32)
         outs.append(o / jnp.moveaxis(l, (1, 2, 3), (2, 3, 1)))
         lses.append((m + jnp.log(l))[..., 0])           # (B, Hkv, G, bq)
     out = jnp.concatenate(outs, axis=1).reshape(B, T, Hq, v.shape[-1])
     return out.astype(q.dtype), jnp.concatenate(lses, axis=-1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _gqa(q, k, v, scale, block_q):
-    return _gqa_fwd_blocks(q, k, v, scale, block_q)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _gqa(q, k, v, scale, block_q, window=None):
+    return _gqa_fwd_blocks(q, k, v, scale, block_q, window)[0]
 
 
-def _gqa_fwd(q, k, v, scale, block_q):
-    out, lse = _gqa_fwd_blocks(q, k, v, scale, block_q)
+def _gqa_fwd(q, k, v, scale, block_q, window=None):
+    out, lse = _gqa_fwd_blocks(q, k, v, scale, block_q, window)
     return out, (q, k, v, out, lse)
 
 
-def _gqa_bwd(scale, block_q, res, g):
+def _gqa_bwd(scale, block_q, window, res, g):
     q, k, v, out, lse = res
     B, T, Hq, D = q.shape
     Hkv = k.shape[2]
@@ -400,22 +439,22 @@ def _gqa_bwd(scale, block_q, res, g):
     chained = _gqa_chained(B, Hq, T)
     dqs = []
     for i in range(nq):
-        lo, hi = i * bq, min((i + 1) * bq, T)
+        lo, hi, first = _gqa_span(i, bq, T, window)
         qi, gi = q5[:, lo:hi], g5[:, lo:hi]
         qi = _after(qi, dqs[-1] if chained and dqs else None)
-        s = _gqa_scores(qi, k[:, :hi], i, bq, scale)
+        s = _gqa_scores(qi, k[:, first:hi], i, bq, scale, first, window)
         p = jnp.exp(s - lse[..., lo:hi, None])
-        dp = jnp.einsum("bqhgd,bkhd->bhgqk", gi, v[:, :hi],
+        dp = jnp.einsum("bqhgd,bkhd->bhgqk", gi, v[:, first:hi],
                         preferred_element_type=jnp.float32)
         ds = (p * (dp - delta[..., lo:hi, None]) * scale).astype(q.dtype)
         pb = p.astype(q.dtype)
-        dv = dv.at[:, :hi].add(jnp.einsum(
+        dv = dv.at[:, first:hi].add(jnp.einsum(
             "bhgqk,bqhgd->bkhd", pb, gi,
             preferred_element_type=jnp.float32))
-        dk = dk.at[:, :hi].add(jnp.einsum(
+        dk = dk.at[:, first:hi].add(jnp.einsum(
             "bhgqk,bqhgd->bkhd", ds, qi,
             preferred_element_type=jnp.float32))
-        dqs.append(jnp.einsum("bhgqk,bkhd->bqhgd", ds, k[:, :hi],
+        dqs.append(jnp.einsum("bhgqk,bkhd->bqhgd", ds, k[:, first:hi],
                               preferred_element_type=jnp.float32))
     dq = jnp.concatenate(dqs, axis=1).reshape(q.shape)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -464,14 +503,17 @@ def _gqa_tiles(T, G):
     return (bq, bk) if bk else None
 
 
-def _gqa_steps(T, bq, bk):
+def _gqa_steps(T, bq, bk, window=None):
     """The (query block, key block) of each grid step: row blocks in turn,
-    each with the key blocks that hold a key at or before its last row."""
+    each with the key blocks that hold a key at or before its last row —
+    and, with a ``window``, none wholly before the first key its first row
+    sees: exactly the tiles that hold a pair that see each other."""
     qi, kj = [], []
     for i in range(T // bq):
+        first = _gqa_span(i, bq, T, window)[2] // bk
         n = ((i + 1) * bq - 1) // bk + 1
-        qi += [i] * n
-        kj += range(n)
+        qi += [i] * (n - first)
+        kj += range(first, n)
     return np.asarray(qi, np.int32), np.asarray(kj, np.int32)
 
 
@@ -490,22 +532,42 @@ def _unstack(dst_ref, x, G):
             x[g * bq:(g + 1) * bq].astype(dst_ref.dtype)
 
 
-def _gqa_step(qi_ref, kj_ref, bq, bk):
-    """This grid step's blocks, whether it is the row block's last, and
-    whether the diagonal crosses it (some key after the block's first
-    row)."""
+def _gqa_first(i, j, bq, bk, window=None):
+    """Whether key block ``j`` is the first that row block ``i`` meets."""
+    if window is None:
+        return j == 0
+    return j == jnp.maximum(i * bq - (window - 1), 0) // bk
+
+
+def _gqa_edges(i, j, bq, bk, window=None):
+    """Of the tile that meets row block ``i`` with key block ``j``: whether
+    it is the row block's last, and whether an edge crosses it — the
+    diagonal (some key after the block's first row) or, with a ``window``,
+    the lower edge (some key ``window`` or more before the block's last
+    row).  Only a crossed tile takes the mask."""
+    last, crossed = (j + 1) * bk >= (i + 1) * bq, (j + 1) * bk - 1 > i * bq
+    if window is not None:
+        crossed = crossed | (j * bk + window < (i + 1) * bq)
+    return last, crossed
+
+
+def _gqa_step(qi_ref, kj_ref, bq, bk, window=None):
+    """This grid step's blocks and what :func:`_gqa_edges` says of them."""
     from jax.experimental import pallas as pl
     step = pl.program_id(2)
     i, j = qi_ref[step], kj_ref[step]
-    return i, j, (j + 1) * bk >= (i + 1) * bq, (j + 1) * bk - 1 > i * bq
+    return (i, j) + _gqa_edges(i, j, bq, bk, window)
 
 
-def _gqa_causal(shape, i, j, bq, bk):
+def _gqa_causal(shape, i, j, bq, bk, window=None):
     """Which (key, stacked query row) pairs of a (bk, G * bq) tile see
-    each other: the key at or before the row."""
+    each other: the key at or before the row and, with a ``window``, fewer
+    than ``window`` before it."""
     k_pos = j * bk + lax.broadcasted_iota(jnp.int32, shape, 0)
     q_pos = i * bq + (lax.broadcasted_iota(jnp.int32, shape, 1) & (bq - 1))
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return (q_pos >= k_pos) & (k_pos > q_pos - window)
 
 
 def _either(cond, fn):
@@ -515,21 +577,24 @@ def _either(cond, fn):
     pl.when(jnp.logical_not(cond))(functools.partial(fn, False))
 
 
-def _gqa_fwd_kernel(G, scale, qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref,
-                    lse_ref, acc_ref, m_ref, l_ref, *qs_ref):
+def _gqa_fwd_kernel(G, scale, window, qi_ref, kj_ref, q_ref, k_ref, v_ref,
+                    o_ref, lse_ref, acc_ref, m_ref, l_ref, *qs_ref):
     """q_ref (bq, G * D), k_ref (bk, D), v_ref (bk, Dv) -> o_ref (bq,
     G * Dv), lse_ref (1, G * bq).  The tiles are (bk, G * bq): keys down
     the rows, the group's stacked query rows along lanes, so a query row's
     running max and sum are one lane each of a (1, G * bq) row and their
     reductions run down the sublanes; the float32 accumulator is the
     output's transpose, (Dv, G * bq).  All three live in scratch over a
-    row block's key blocks."""
+    row block's key blocks.  (Under a ``window`` a row may meet no key it
+    sees in its row block's first tiles: its max stays ``_MASKED`` there,
+    and the first key it does see rescales what those tiles left by
+    exp(-1e30) = 0.)"""
     from jax.experimental import pallas as pl
     bq, bk = q_ref.shape[0], k_ref.shape[0]
-    i, j, last, crossed = _gqa_step(qi_ref, kj_ref, bq, bk)
+    i, j, last, crossed = _gqa_step(qi_ref, kj_ref, bq, bk, window)
     rows_ref = qs_ref[0] if qs_ref else q_ref
 
-    @pl.when(j == 0)
+    @pl.when(_gqa_first(i, j, bq, bk, window))
     def _():
         if qs_ref:
             _stack(rows_ref, q_ref, G)
@@ -541,7 +606,8 @@ def _gqa_fwd_kernel(G, scale, qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref,
         v = v_ref[...]
         s = _nt(k_ref[...], rows_ref[...]) * scale          # (bk, M)
         if masked:
-            s = jnp.where(_gqa_causal(s.shape, i, j, bq, bk), s, _MASKED)
+            s = jnp.where(_gqa_causal(s.shape, i, j, bq, bk, window), s,
+                          _MASKED)
         m_run = m_ref[...]
         m_new = jnp.maximum(m_run, jnp.max(s, axis=0, keepdims=True))
         alpha = jnp.exp(m_run - m_new)
@@ -558,19 +624,20 @@ def _gqa_fwd_kernel(G, scale, qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref,
         lse_ref[...] = m_ref[...] + jnp.log(l)
 
 
-def _gqa_bwd_kernel(G, scale, qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref,
-                    lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc,
-                    dk_acc, dv_acc, *stacked):
+def _gqa_bwd_kernel(G, scale, window, qi_ref, kj_ref, q_ref, k_ref, v_ref,
+                    do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                    dq_acc, dk_acc, dv_acc, *stacked):
     """One kernel for all three gradients, its tiles (bk, G * bq): keys
     down the rows, the group's stacked query rows along lanes, where
     lse_ref and delta_ref (1, G * bq) broadcast.  dq of a row block
     accumulates over its key blocks; dk and dv of the whole (row, key
-    head) accumulate in float32 scratch over every step and leave once,
-    with the last."""
+    head) accumulate in float32 scratch over every step — under a
+    ``window`` still over every row block that sees the keys — and leave
+    once, with the last."""
     from jax.experimental import pallas as pl
     bq, bk = q_ref.shape[0], k_ref.shape[0]
     step = pl.program_id(2)
-    i, j, last, crossed = _gqa_step(qi_ref, kj_ref, bq, bk)
+    i, j, last, crossed = _gqa_step(qi_ref, kj_ref, bq, bk, window)
     if stacked:
         qs_ref, dos_ref = stacked
     else:
@@ -581,7 +648,7 @@ def _gqa_bwd_kernel(G, scale, qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(j == 0)
+    @pl.when(_gqa_first(i, j, bq, bk, window))
     def _():
         if stacked:
             _stack(qs_ref, q_ref, G)
@@ -592,7 +659,8 @@ def _gqa_bwd_kernel(G, scale, qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref,
         q, do, k = qs_ref[...], dos_ref[...], k_ref[...]
         s = _nt(k, q) * scale                               # (bk, M)
         if masked:
-            s = jnp.where(_gqa_causal(s.shape, i, j, bq, bk), s, _MASKED)
+            s = jnp.where(_gqa_causal(s.shape, i, j, bq, bk, window), s,
+                          _MASKED)
         p = jnp.exp(s - lse_ref[...])
         dp = _nt(v_ref[...], do)
         ds = (p * (dp - delta_ref[...]) * scale).astype(q.dtype)
@@ -615,10 +683,11 @@ def _gqa_bwd_kernel(G, scale, qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 @functools.lru_cache(maxsize=None)
-def _pallas_gqa(scale, tiles, interpret):
+def _pallas_gqa(scale, tiles, interpret, window=None):
     """The attention as one ``custom_vjp`` over q (B, T, Hq, D), k (B, T,
     Hkv, D) and v (B, T, Hkv, Dv), D and Dv whole lane tiles.  The kernels
-    read them row-major, (B, T, heads * width), a head's lanes a block."""
+    read them row-major, (B, T, heads * width), a head's lanes a block.
+    ``window``: None, or the keys a row sees (fewer than the positions)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     bq, bk = tiles
@@ -650,9 +719,9 @@ def _pallas_gqa(scale, tiles, interpret):
             dv=whole(Dv), row=pl.BlockSpec(
                 (None, None, None, 1, M),
                 lambda b, h, s, qi, kj: (b, h, qi[s], 0, 0)))
-        qi, kj = _gqa_steps(T, bq, bk)
+        qi, kj = _gqa_steps(T, bq, bk, window)
         return functools.partial(pl.pallas_call(
-            functools.partial(kernel, G, scale),
+            functools.partial(kernel, G, scale, window),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2, grid=(B, Hkv, len(qi)),
                 in_specs=[specs[n] for n in ins],
@@ -713,7 +782,8 @@ def _pallas_gqa(scale, tiles, interpret):
     return attend
 
 
-def gqa_attention_pallas(q, k, v, scale=None, tiles=None, interpret=False):
+def gqa_attention_pallas(q, k, v, scale=None, tiles=None, interpret=False,
+                         window=None):
     """The compiled tier of :func:`gqa_attention` (same operands, same
     result): :func:`_gqa_lax_reason` says which operands it takes.  Query
     / key heads that are not whole lane tiles (latent attention's 192) are
@@ -725,7 +795,8 @@ def gqa_attention_pallas(q, k, v, scale=None, tiles=None, interpret=False):
     if -D % 128:
         q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, -D % 128),)) for x in (q, k))
     tiles = tiles or _gqa_tiles(T, q.shape[2] // k.shape[2])
-    return _pallas_gqa(scale, tuple(tiles), bool(interpret))(q, k, v)
+    return _pallas_gqa(scale, tuple(tiles), bool(interpret),
+                       _gqa_window(window, T))(q, k, v)
 
 
 def _gqa_lax_reason(q, v):
@@ -737,7 +808,8 @@ def _gqa_lax_reason(q, v):
     # whole blocks of positions; value heads of whole lane tiles, query /
     # key heads that pad to one by a third of their width at most; a (row,
     # key head)'s dk and dv in VMEM, float32 accumulators and the blocks
-    # they leave through
+    # they leave through — whole under a window too, which changes the
+    # tiles a schedule visits and not what a step holds
     pad = -D % 128
     if _gqa_tiles(T, Hq // Hkv) is None or Dv % 128 or 3 * pad > D \
             or (4 + 2 * q.dtype.itemsize) * T * (D + pad + Dv) \
@@ -746,15 +818,15 @@ def _gqa_lax_reason(q, v):
     return None
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_q"))
-def _gqa_branch(q, k, v, scale, block_q):
+@functools.partial(jax.jit, static_argnames=("scale", "block_q", "window"))
+def _gqa_branch(q, k, v, scale, block_q, window=None):
     """The lax tier as the other platforms' branch of a program that takes
     the kernels: jitted, so that a model's layers of one shape trace its
     unrolled blocks, forward and backward, once and not once a layer."""
-    return _gqa(q, k, v, scale, block_q)
+    return _gqa(q, k, v, scale, block_q, window)
 
 
-def gqa_attention(q, k, v, scale=None, block_q=512):
+def gqa_attention(q, k, v, scale=None, block_q=512, window=None):
     """Causal grouped-query attention.  q (B, T, Hq, D); k (B, T, Hkv, D)
     and v (B, T, Hkv, Dv) with ``Hq % Hkv == 0``: key/value head ``h``
     serves query heads ``h*G .. h*G+G-1``.  The value heads may be
@@ -770,6 +842,18 @@ def gqa_attention(q, k, v, scale=None, block_q=512):
     tier, the reason: ``aligned``, ``shapes``, ``mesh``) and counts
     ``kernel.gqa_attention.<tier>``.
 
+    ``window``: position ``p`` sees the ``window`` keys ``p - window < j
+    <= p``, itself included (sliding-window attention); None, 0, or a
+    window of all the positions or more is plain causal attention and the
+    very program it was.  Under a window the compiled schedule leaves out
+    the key blocks wholly before a row block's first visible key and masks
+    only the tiles that the diagonal or the window's lower edge crosses;
+    the lax tier slices a row block's keys from the first its first row
+    sees.  The event of a windowed call carries three more ids: ``window``,
+    ``steps`` (grid steps a (row, key head) of the schedule it built; on
+    the lax tier the (row block, tile of ``block_q`` keys) pairs its slices
+    touch) and ``steps_causal`` (the same without the window).
+
     On the lax tier query rows go in blocks of ``block_q``; block ``i``
     meets only its causal prefix of keys (a static slice), so the work is
     the lower triangle plus half a block, and the largest tile either pass
@@ -783,16 +867,28 @@ def gqa_attention(q, k, v, scale=None, block_q=512):
         raise ValueError("gqa_attention: %d query heads over %d key/value "
                          "heads" % (q.shape[2], k.shape[2]))
     scale = float(scale or 1.0 / np.sqrt(D))
+    T = q.shape[1]
+    window = _gqa_window(window, T)
     reason = _gqa_lax_reason(q, v)
     tier = "lax" if reason else "pallas"
+    ids = {}
+    if window is not None:
+        if reason:
+            bq = _gqa_blocks(T, block_q)[0]
+            steps = [_gqa_lax_steps(T, bq, w) for w in (window, None)]
+        else:
+            tiles = _gqa_tiles(T, q.shape[2] // k.shape[2])
+            steps = [len(_gqa_steps(T, *tiles, w)[0]) for w in (window, None)]
+        ids = dict(window=window, steps=steps[0], steps_causal=steps[1])
     now = time.perf_counter_ns()
     profiler.event("kernel.route", now, now, kernel="gqa_attention",
-                   tier=tier, reason=reason or "aligned")
+                   tier=tier, reason=reason or "aligned", **ids)
     profiler.count("kernel.gqa_attention." + tier)
 
     if reason:
-        return _gqa(q, k, v, scale, int(block_q))
+        return _gqa(q, k, v, scale, int(block_q), window)
     return by_platform(
-        functools.partial(gqa_attention_pallas, scale=scale),
-        functools.partial(_gqa_branch, scale=scale, block_q=int(block_q)),
+        functools.partial(gqa_attention_pallas, scale=scale, window=window),
+        functools.partial(_gqa_branch, scale=scale, block_q=int(block_q),
+                          window=window),
         q, k, v)

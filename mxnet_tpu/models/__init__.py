@@ -11,6 +11,8 @@ from . import qwen3_next
 from . import kimi_linear
 from . import zaya
 from .zaya import zaya_sym
+from . import trinity
+from .trinity import trinity_sym
 
 _BUILDERS = {
     "lenet": lenet.get_symbol,

@@ -559,7 +559,7 @@ def _gq_attention_infer(attrs, in_shapes):
           if attrs.get("gated", False) else ("query", "key", "value"),
           infer_shape=_gq_attention_infer)
 def gq_attention(query, key, value, gate=None, scale=-1.0, block_q=512,
-                 gated=False):
+                 gated=False, window=0):
     """Causal grouped-query softmax attention that never holds a
     (positions x positions) matrix.  query (batch, positions, query_heads,
     head_dim); key (batch, positions, kv_heads, head_dim) and value (batch,
@@ -568,11 +568,14 @@ def gq_attention(query, key, value, gate=None, scale=-1.0, block_q=512,
     not be ``head_dim`` (latent attention: 192-wide keys, 128-wide values);
     the output is (batch, positions, query_heads, value_dim).  ``scale``
     <= 0 means head_dim^-0.5.  With ``gated`` the result is multiplied by
-    ``sigmoid(gate)`` (gate shaped like the output)."""
+    ``sigmoid(gate)`` (gate shaped like the output).  ``window`` > 0 is
+    sliding-window attention: position ``p`` sees the keys ``p - window <
+    j <= p``, ``window`` of them with its own; 0 (or all the positions and
+    more) is none, the whole causal prefix."""
     from ..kernels.flash_attention import gqa_attention
     out = gqa_attention(query, key, value,
                         scale=None if float(scale) <= 0 else float(scale),
-                        block_q=int(block_q))
+                        block_q=int(block_q), window=int(window))
     if gate is not None:
         out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
     return out

@@ -55,16 +55,21 @@ def test_delta_rule_kernels_compile_for_the_v5e(one_chip, rows, t, hk, hv, dk,
     assert compiled_kernels(text) == {stem + "fwd": 1, stem + "bwd": 1}
 
 
-@pytest.mark.parametrize("hq,hkv,d,dv", [
-    (32, 32, 192, 128),     # Kimi Linear's latent attention: keys padded
-    (16, 2, 256, 256),      # Qwen3-Next's: eight query heads a key head
-    (8, 2, 128, 128),       # ZAYA1's latent: four
-], ids=["kimi_linear_8k", "qwen3_next_8k", "zaya1_8k"])
-def test_gqa_attention_kernels_compile_for_the_v5e(one_chip, hq, hkv, d, dv):
+@pytest.mark.parametrize("hq,hkv,d,dv,window", [
+    (32, 32, 192, 128, None),   # Kimi Linear's latent attention: keys padded
+    (16, 2, 256, 256, None),    # Qwen3-Next's: eight query heads a key head
+    (8, 2, 128, 128, None),     # ZAYA1's latent: four
+    (32, 4, 128, 128, 2048),    # Trinity-Mini's sliding layers: eight
+    (32, 4, 128, 128, None),    # and its full layer
+], ids=["kimi_linear_8k", "qwen3_next_8k", "zaya1_8k", "trinity_mini_8k_swa",
+        "trinity_mini_8k_full"])
+def test_gqa_attention_kernels_compile_for_the_v5e(one_chip, hq, hkv, d, dv,
+                                                   window):
     """Forward and gradient of the compiled causal grouped-query attention
-    at the three language-model cells' shapes (2 x 8,192 positions,
-    bfloat16): one forward kernel, one backward kernel for dq, dk and dv,
-    within the VMEM they ask for."""
+    at the four language-model cells' shapes (2 x 8,192 positions,
+    bfloat16), under a window of 2,048 keys where the cell has one: one
+    forward kernel, one backward kernel for dq, dk and dv, within the VMEM
+    they ask for."""
     from mxnet_tpu.kernels import compiled_kernels
     from mxnet_tpu.kernels.flash_attention import (_gqa_lax_reason,
                                                    gqa_attention_pallas)
@@ -74,8 +79,8 @@ def test_gqa_attention_kernels_compile_for_the_v5e(one_chip, hq, hkv, d, dv):
     assert _gqa_lax_reason(specs[0], specs[2]) is None
 
     def grads(*a):
-        return jax.grad(lambda *x: jnp.sum(gqa_attention_pallas(*x).astype(
-            jnp.float32)), argnums=(0, 1, 2))(*a)
+        return jax.grad(lambda *x: jnp.sum(gqa_attention_pallas(
+            *x, window=window).astype(jnp.float32)), argnums=(0, 1, 2))(*a)
     text = jax.jit(grads).lower(*specs).compile().as_text()
     assert compiled_kernels(text) == {"mxtpu_gqa_attention_fwd": 1,
                                       "mxtpu_gqa_attention_bwd": 1}
@@ -90,6 +95,12 @@ def _attention_stage(model, x, seq_len):
             hidden_size=64, num_attention_heads=2, kv_lora_rank=32,
             qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
             rms_norm_eps=1e-5))
+    if model.startswith("trinity"):     # eight, 128 wide; a window or none
+        from mxnet_tpu.models.trinity import _attention
+        return _attention(x, "l0_swa", seq_len, dict(
+            hidden_size=64, num_attention_heads=8, num_key_value_heads=1,
+            head_dim=128, rope_theta=10000, sliding_window=128,
+            rms_norm_eps=1e-5), model == "trinity_swa")
     rope = dict(partial_rotary_factor=0.25, rope_theta=1e7,
                 rope_parameters={"hybrid": {"rope_theta": 5e6}})
     if model == "qwen3_next":           # eight query heads a key head
@@ -137,18 +148,22 @@ def _lower_stage(stage):
         tr.close()
 
 
-@pytest.mark.parametrize("model", ["kimi_linear", "qwen3_next", "zaya"])
+@pytest.mark.parametrize("model", ["kimi_linear", "qwen3_next", "zaya",
+                                   "trinity_swa", "trinity_full"])
 def test_a_tpu_lowering_of_each_attention_stage_takes_the_kernels(model):
     """The models' own attention stages in a bfloat16 training step:
     lowered for a TPU the step holds the attention's two kernels, lowered
     for the CPU none; the one ``GQAttention`` lowering says so in the
-    recorder."""
+    recorder — a sliding-window stage's with its window and the steps of
+    its schedule (256 positions in tiles of (256, 256): nothing to skip)."""
     from mxnet_tpu.kernels import compiled_kernels
     routes, tpu, cpu = _lower_stage(
         lambda x, seq_len: _attention_stage(model, x, seq_len))
     routes = [r for r in routes if r["kernel"] == "gqa_attention"]
-    assert routes and all(r == {"kernel": "gqa_attention", "tier": "pallas",
-                                "reason": "aligned"} for r in routes), routes
+    want = {"kernel": "gqa_attention", "tier": "pallas", "reason": "aligned"}
+    if model == "trinity_swa":
+        want.update(window=128, steps=1, steps_causal=1)
+    assert routes and all(r == want for r in routes), routes
     assert {k for k in compiled_kernels(tpu) if "gqa" in k} == {
         "mxtpu_gqa_attention_fwd", "mxtpu_gqa_attention_bwd"}
     assert compiled_kernels(cpu) == {}
