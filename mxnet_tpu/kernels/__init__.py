@@ -117,6 +117,24 @@ kernel that wins its cell is unconditional, ``ROADMAP.md`` Design 2):
   ms, kernels 0.50 / 1.40; 8,192 channels, 4.97 / 14.92 and 0.96 / 2.72;
   1,280 channels at 2 taps, plain, 0.24 / 0.80 and 0.22 / 0.48 (PERF.md,
   PR 36).
+- ``head_norm`` — ``RMSNorm`` over the heads of a (batch, positions, heads,
+  width) tensor, with its gate and the rotary turn after it
+  (:mod:`.head_norm`): ``mxtpu_head_norm_fwd`` / ``mxtpu_head_norm_bwd``, a
+  block of whole rows of every head a grid step read from the projection's
+  (batch, positions, heads x width) layout and walked one head and 256
+  positions at a time, norm, weight, gate and turn in float32 and one store
+  each way; ``dgamma`` added up in a float32 block that never leaves VMEM;
+  residuals the inputs.  Compiled in a
+  program lowered for a TPU when the width is 128, 256 or 512, the
+  positions whole blocks of 16 whose streamed blocks fit the VMEM budget
+  and the data bfloat16 or float32 (reason ``aligned``); the lax tier otherwise (``shapes``) and under
+  :func:`auto_partitioned` (``mesh``).  A norm of rank 2 or 3 stays inline
+  and records nothing.  Each lowering of rank 4 records a ``kernel.route``
+  event (kernel ``head_norm``); the benchmark's ``head_norm_kernel_share``
+  reads them.  On the v5e at 2 x 8,192 positions (bfloat16; forward /
+  backward): 32 heads of 128 turned over all 128, 0.59 / 0.94 ms; gated by
+  ``silu``, 0.65 / 1.08; 16 heads of 256 turned over 64, 0.48 / 0.91
+  (PERF.md).
 
 The plan-level passes live in :mod:`mxnet_tpu.mxfuse` (the
 match-and-rewrite framework over the executor's node plan); this

@@ -32,13 +32,16 @@ MOE_COUNTERS = ("moe.assignments", "moe.assignments_here", "moe.load_max",
 
 
 def _norm(x, name, width, zero_centered=True, gate=None, eps=1e-6,
-          gate_act="silu"):
+          gate_act="silu", rotary_dim=0, rope_base=10000.0):
+    """RMSNorm; ``rotary_dim`` turns a query's or key's heads after it."""
     gamma = sym.Variable(name + "_gamma", shape=(width,),
                          init=initializer.Zero() if zero_centered
                          else initializer.One())
     kw = {"gate": gate, "gated": True} if gate is not None else {}
     if gate_act != "silu":
         kw["gate_act"] = gate_act
+    if rotary_dim:
+        kw.update(rotary_dim=rotary_dim, rope_base=rope_base)
     return sym.RMSNorm(data=x, gamma=gamma, eps=eps,
                        zero_centered=zero_centered, name=name, **kw)
 
@@ -103,10 +106,10 @@ def _gated_attention(x, p, seq_len, c):
                     shape=(-1, seq_len, hkv, d))
     v = sym.Reshape(_linear(x, p + "_v_proj", hkv * d),
                     shape=(-1, seq_len, hkv, d))
-    q = sym.RotaryEmbedding(_norm(q, p + "_q_norm", d, eps=eps),
-                            rotary_dim=rotary, base=c["rope_theta"])
-    k = sym.RotaryEmbedding(_norm(k, p + "_k_norm", d, eps=eps),
-                            rotary_dim=rotary, base=c["rope_theta"])
+    q = _norm(q, p + "_q_norm", d, eps=eps, rotary_dim=rotary,
+              rope_base=c["rope_theta"])
+    k = _norm(k, p + "_k_norm", d, eps=eps, rotary_dim=rotary,
+              rope_base=c["rope_theta"])
     o = sym.GQAttention(query=q, key=k, value=v, gate=gate, gated=True,
                         name=p + "_core")
     return _linear(sym.Reshape(o, shape=(-1, hq * d)), p + "_o_proj",

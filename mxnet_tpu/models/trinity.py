@@ -55,12 +55,10 @@ def _attention(x, p, seq_len, c, sliding):
     def heads(name, n, normed):
         y = sym.Reshape(_linear(x, "%s_%s_proj" % (p, name), n * d),
                         shape=(-1, seq_len, n, d))
-        if normed:
+        if normed:      # with the rotary embedding where the layer has one
             y = _norm(y, "%s_%s_norm" % (p, name), d, zero_centered=False,
-                      eps=c["rms_norm_eps"])
-            if sliding:
-                y = sym.RotaryEmbedding(y, base=c["rope_theta"],
-                                        name="%s_%s_rope" % (p, name))
+                      eps=c["rms_norm_eps"], rotary_dim=d if sliding else 0,
+                      rope_base=c["rope_theta"])
         return y
     o = sym.GQAttention(
         query=heads("q", hq, True), key=heads("k", hkv, True),

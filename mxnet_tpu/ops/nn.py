@@ -452,17 +452,10 @@ def _rmsnorm_infer(attrs, in_shapes):
     return shapes, [tuple(data)], []
 
 
-@register("RMSNorm", input_names=_rmsnorm_inputs, infer_shape=_rmsnorm_infer)
-def rms_norm(data, gamma, gate=None, eps=1e-6, zero_centered=False,
-             gated=False, gate_act="silu"):
-    """Root-mean-square norm over the last axis, computed in float32:
-    ``x * rsqrt(mean(x^2) + eps) * w``, with ``w = 1 + gamma`` when
-    ``zero_centered`` (gamma initialised 0) and ``w = gamma`` otherwise.
-    With ``gated`` the result is multiplied by ``gate_act(gate)``, ``silu``
-    or ``sigmoid`` (the output norm of a gated linear-attention mixer)."""
-    if gate_act not in ("silu", "sigmoid"):
-        raise MXNetError("RMSNorm: gate_act %r is neither silu nor sigmoid"
-                         % (gate_act,))
+def rms_math(data, gamma, gate=None, eps=1e-6, zero_centered=False,
+             gate_act="silu"):
+    """``RMSNorm``'s arithmetic, inline: float32, the result in the data's
+    dtype."""
     x = data.astype(jnp.float32)
     w = gamma.astype(jnp.float32)
     y = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + float(eps))
@@ -471,6 +464,38 @@ def rms_norm(data, gamma, gate=None, eps=1e-6, zero_centered=False,
         act = jax.nn.silu if gate_act == "silu" else jax.nn.sigmoid
         y = y * act(gate.astype(jnp.float32))
     return y.astype(data.dtype)
+
+
+@register("RMSNorm", input_names=_rmsnorm_inputs, infer_shape=_rmsnorm_infer)
+def rms_norm(data, gamma, gate=None, eps=1e-6, zero_centered=False,
+             gated=False, gate_act="silu", rotary_dim=0, rope_base=10000.0):
+    """Root-mean-square norm over the last axis, computed in float32:
+    ``x * rsqrt(mean(x^2) + eps) * w``, with ``w = 1 + gamma`` when
+    ``zero_centered`` (gamma initialised 0) and ``w = gamma`` otherwise.
+    With ``gated`` the result is multiplied by ``gate_act(gate)``, ``silu``
+    or ``sigmoid`` (the output norm of a gated linear-attention mixer).
+    With ``rotary_dim`` (data of rank 4, (batch, positions, heads, width))
+    the result in the data's dtype is then turned by
+    ``RotaryEmbedding(rotary_dim=rotary_dim, base=rope_base)``: a query's or
+    a key's norm and the rotary embedding after it in one node.
+
+    Data of rank 4 goes through
+    :func:`mxnet_tpu.kernels.head_norm.head_norm` (in a program lowered for
+    a TPU the compiled kernels, for widths of 128, 256 or 512 and positions
+    in multiples of 16; the lax tier on other platforms, for other operands
+    and under a mesh the partitioner splits — the same arithmetic either
+    way); other ranks are the inline arithmetic of :func:`rms_math`."""
+    if gate_act not in ("silu", "sigmoid"):
+        raise MXNetError("RMSNorm: gate_act %r is neither silu nor sigmoid"
+                         % (gate_act,))
+    if data.ndim == 4:
+        from ..kernels.head_norm import head_norm
+        return head_norm(data, gamma, gate, eps, zero_centered, gate_act,
+                         int(rotary_dim), float(rope_base))
+    if int(rotary_dim):
+        raise MXNetError("RMSNorm: rotary_dim needs data of rank 4, not %d"
+                         % data.ndim)
+    return rms_math(data, gamma, gate, eps, zero_centered, gate_act)
 
 
 @register("_contrib_SwiGLU", aliases=("SwiGLU",))
@@ -487,7 +512,12 @@ def rotary_embedding(data, rotary_dim=0, base=10000.0):
     ``rotary_dim`` features of every head (all of them when 0); the rest
     pass through.  data (batch, positions, heads, head_dim); position p
     turns the pair (x[i], x[i + rotary_dim/2]) by p * base^(-2i/rotary_dim).
-    Angles are float32."""
+    Angles are float32.
+
+    For heads that are not normed (ZAYA1's).  A turn that follows a heads'
+    ``RMSNorm`` belongs in that norm's ``rotary_dim`` / ``rope_base``: the
+    same result, and on a TPU one pass of the compiled heads' norm where
+    this node is a pass of its own."""
     d = int(rotary_dim) or data.shape[-1]
     half = d // 2
     inv_freq = float(base) ** (-jnp.arange(half, dtype=jnp.float32)

@@ -537,6 +537,261 @@ def test_mxlint_finds_the_causal_conv_kernels_behind_their_vjp():
 
 
 # ---------------------------------------------------------------------------
+# the heads' RMSNorm (gate, rotary turn): the compiled tier against the lax
+# tier
+# ---------------------------------------------------------------------------
+
+#: (width, zero_centered, gate_act, rotary_dim): the cells' variants
+_HEAD_NORMS = {
+    "plain": (128, False, None, 0),             # Trinity-Mini's full layer
+    "zero_centered": (256, True, None, 0),
+    "silu": (128, False, "silu", 0),            # a DeltaNet's output norm
+    "sigmoid": (128, False, "sigmoid", 0),      # a KDA stage's
+    "rotary": (128, False, None, 128),          # Trinity-Mini's sliding q, k
+    "partial_rotary": (256, True, None, 64),    # Qwen3-Next's q, k
+}
+
+
+def _head_norm_operands(t, h, d, dtype, gated, rows=2, seed=9):
+    rs = np.random.RandomState(seed)
+
+    def draw(*shape, scale=1.0):
+        return jnp.asarray(scale * rs.randn(*shape).astype("f")).astype(dtype)
+    x, dy = draw(rows, t, h, d, scale=3.0), draw(rows, t, h, d)
+    gamma = jnp.asarray(1.0 + 0.2 * rs.randn(d).astype("f")).astype(dtype)
+    return (x, gamma) + ((draw(rows, t, h, d),) if gated else ()), dy
+
+
+def _head_norm_both(fn, args, dy):
+    """y and the cotangents of every operand under ``dy``."""
+    y, vjp = jax.vjp(fn, *args)
+    return (y,) + vjp(dy)
+
+
+def _head_norm_attrs(zc, act, rotary):
+    return dict(eps=1e-6, zero_centered=zc, gate_act=act or "silu",
+                rotary_dim=rotary, base=1e4)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("variant", sorted(_HEAD_NORMS))
+def test_head_norm_compiled_tier_matches_the_lax_tier(variant, dtype):
+    """The kernels in the interpreter against the lax tier: y, dx, dgamma
+    and the gate's cotangent, over two position blocks of two rows and
+    three heads, two register chunks a block: float32 to 1e-5 of the
+    largest entry, bfloat16 within the rounding of one store."""
+    from mxnet_tpu.kernels import head_norm as HN
+    d, zc, act, rotary = _HEAD_NORMS[variant]
+    args, dy = _head_norm_operands(64, 3, d, dtype, act is not None)
+    kw = _head_norm_attrs(zc, act, rotary)
+    want = _head_norm_both(lambda *a: HN.head_norm_lax(*a, **kw), args, dy)
+    got = _head_norm_both(lambda *a: HN.head_norm_pallas(
+        *a, **kw, tiles=(32, 16), interpret=True), args, dy)
+    assert len(got) == len(want) == len(args) + 1
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        a, b = (np.asarray(v.astype(jnp.float32)) for v in (a, b))
+        tol = 1e-5 if dtype == "float32" else 2.0 ** -7
+        np.testing.assert_allclose(a, b, rtol=tol,
+                                   atol=tol * np.abs(b).max())
+
+
+def test_head_norm_turns_each_position_by_its_own_angle():
+    """The rotary table follows the position across blocks: a row's
+    positions from 32 on read as the lax tier's at those positions, and a
+    head of the second row as the same head of the first."""
+    from mxnet_tpu.kernels import head_norm as HN
+    (x, gamma), _ = _head_norm_operands(64, 2, 256, "float32", False)
+    x = x.at[1].set(x[0])
+    kw = _head_norm_attrs(True, None, 64)
+    got = np.asarray(HN.head_norm_pallas(x, gamma, **kw, tiles=(32, 16),
+                                         interpret=True))
+    np.testing.assert_array_equal(got[1], got[0])
+    want = np.asarray(HN.head_norm_lax(x, gamma, **kw))
+    np.testing.assert_allclose(got[:, 32:], want[:, 32:], rtol=1e-5,
+                               atol=1e-5)
+    # past the rotary width the features are the norm's alone
+    plain = np.asarray(HN.head_norm_lax(x, gamma, **_head_norm_attrs(
+        True, None, 0)))
+    np.testing.assert_allclose(got[..., 64:], plain[..., 64:], rtol=1e-6)
+
+
+def _head_norm_routes(since):
+    return _gqa_routes(since, kernel="head_norm")
+
+
+def test_head_norm_takes_the_compiled_tier_for_aligned_operands(
+        compiled_tier):
+    """Through the op as a program lowered for a TPU resolves it: the
+    kernels' result, one ``kernel.route`` event and the counter."""
+    import time
+    from mxnet_tpu import profiler
+    from mxnet_tpu.kernels import head_norm as HN
+    (x, gamma, gate), _ = _head_norm_operands(64, 2, 128, "float32", True)
+    before = profiler.counters().get("kernel.head_norm.pallas", 0)
+    since = time.perf_counter()
+    out = NN.rms_norm(x, gamma, gate, gated=True, gate_act="sigmoid")
+    assert _head_norm_routes(since) == [{"kernel": "head_norm",
+                                         "tier": "pallas",
+                                         "reason": "aligned"}]
+    assert profiler.counters()["kernel.head_norm.pallas"] == before + 1
+    _close(out, HN.head_norm_lax(x, gamma, gate, gate_act="sigmoid"),
+           "float32")
+    assert "mxtpu_head_norm_fwd" in str(jax.make_jaxpr(
+        lambda *a: NN.rms_norm(*a, rotary_dim=128))(x, gamma))
+
+
+@pytest.mark.parametrize("t,d,rotary,dtype,mesh,reason", [
+    (64, 64, 0, "float32", False, "shapes"),    # width: no whole lane tile
+    (24, 128, 0, "float32", False, "shapes"),   # no whole block of 16
+    (64, 384, 0, "float32", False, "shapes"),   # the register chunk splits
+    (64, 128, 128, "float16", False, "shapes"),  # a dtype not the kernels'
+    (64, 128, 128, "float32", True, "mesh"),
+], ids=["width", "ragged", "wide", "float16", "mesh"])
+def test_head_norm_falls_back_to_the_lax_tier(compiled_tier, t, d, rotary,
+                                              dtype, mesh, reason):
+    """What the kernels do not take keeps to the lax tier even where the
+    platform would take them, bit for bit, and says why."""
+    import contextlib
+    import time
+    from mxnet_tpu import profiler
+    from mxnet_tpu.kernels import auto_partitioned
+    from mxnet_tpu.kernels import head_norm as HN
+    args, dy = _head_norm_operands(t, 2, d, dtype, False)
+
+    def op(x, gamma):
+        return NN.rms_norm(x, gamma, rotary_dim=rotary)
+    before = profiler.counters().get("kernel.head_norm.lax", 0)
+    since = time.perf_counter()
+    with auto_partitioned() if mesh else contextlib.nullcontext():
+        got = _head_norm_both(op, args, dy)
+        # (a fresh function: a trace is cached by the function traced)
+        text = str(jax.make_jaxpr(lambda *a: op(*a))(*args))
+    assert "pallas_call" not in text
+    assert _head_norm_routes(since) == [{"kernel": "head_norm", "tier": "lax",
+                                         "reason": reason}] * 2
+    assert profiler.counters()["kernel.head_norm.lax"] == before + 2
+    want = _head_norm_both(lambda *a: HN.head_norm_lax(
+        *a, rotary_dim=rotary), args, dy)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("variant", ["silu", "partial_rotary"])
+def test_head_norm_on_the_cpu_is_the_lax_tier_bit_for_bit(variant):
+    """Routed to the kernels, lowered for the CPU: ``platform_dependent``
+    takes the lax branch, which is the two ops as they were."""
+    from mxnet_tpu.kernels import head_norm as HN
+    d, zc, act, rotary = _HEAD_NORMS[variant]
+    args, dy = _head_norm_operands(64, 2, d, "bfloat16", act is not None)
+    attrs = dict(zero_centered=zc, rotary_dim=rotary, rope_base=1e4)
+    if act:
+        attrs.update(gated=True, gate_act=act)
+    kw = _head_norm_attrs(zc, act, rotary)
+    # (each side one program: op by op, XLA:CPU contracts other products)
+    got = jax.jit(lambda *a: _head_norm_both(
+        lambda *b: NN.rms_norm(*b, **attrs), a, dy))(*args)
+    want = jax.jit(lambda *a: _head_norm_both(
+        lambda *b: HN.head_norm_lax(*b, **kw), a, dy))(*args)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
+def test_head_norm_blocks_are_sized_in_bytes(dtype, gated):
+    """A grid step's streamed blocks — the backward's x, dy and dx, and a
+    gated norm's gate and its cotangent — fit the kernels' VMEM budget
+    double-buffered at the cells' head shapes, float32 in blocks of half
+    bfloat16's positions; heads too wide for 16 positions of them stay on
+    the lax tier."""
+    from mxnet_tpu.kernels import head_norm as HN
+    size = jnp.dtype(dtype).itemsize
+    streams = 5 if gated else 3
+    for h, d in ((32, 128), (4, 128), (16, 256), (2, 256)):
+        bt, rows = HN._tiles(8192, h, d, size, gated)
+        assert 2 * streams * bt * h * d * size <= HN._STREAMED
+        assert 8192 % bt == 0 and bt % rows == 0
+        if dtype == "float32" and h * d == 4096:
+            assert bt == HN._tiles(8192, h, d, 2, gated)[0] // 2
+    wide = jax.ShapeDtypeStruct((2, 8192, 1024, 512), jnp.dtype(dtype))
+    assert HN._lax_reason(wide, "silu" if gated else None, 0) == "shapes"
+
+
+def _equations(jaxpr):
+    """Equations of a jaxpr and of every jaxpr inside it (a kernel's body,
+    a loop's)."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        count += 1
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    count += _equations(inner)
+    return count
+
+
+@pytest.mark.parametrize("variant", sorted(_HEAD_NORMS))
+def test_head_norm_kernels_do_not_unroll_heads_or_positions(variant):
+    """The kernels' traced size is the same at 4 heads of 256 positions as
+    at 32 heads of 2,048: the grid and a loop walk them, so a model's
+    set-up does not grow with its widths."""
+    from mxnet_tpu.kernels import head_norm as HN
+    d, zc, act, rotary = _HEAD_NORMS[variant]
+    kw = _head_norm_attrs(zc, act, rotary)
+    counts = []
+    for t, h in ((256, 4), (2048, 32)):
+        x = jax.ShapeDtypeStruct((2, t, h, d), jnp.bfloat16)
+        args = (x, jax.ShapeDtypeStruct((d,), jnp.bfloat16)) + (
+            (x,) * (act is not None))
+
+        def both(*a):
+            y, vjp = jax.vjp(lambda *b: HN.head_norm_pallas(
+                *b, **kw, tiles=(256, 16)), *a)
+            return (y,) + vjp(y)
+        counts.append(_equations(jax.make_jaxpr(both)(*args).jaxpr))
+    assert counts[0] == counts[1]
+
+
+def test_a_norm_of_rank_two_or_three_is_the_inline_arithmetic():
+    """A stream's norm keeps the parent's graph: no kernel, no event, the
+    arithmetic inline; ``rotary_dim`` on such a norm is an error."""
+    import time
+    rs = np.random.RandomState(3)
+    gamma = jnp.asarray(rs.randn(96).astype("f"))
+    for shape in ((8, 96), (2, 8, 96)):
+        x = jnp.asarray(rs.randn(*shape).astype("f"))
+        since = time.perf_counter()
+        got = str(jax.make_jaxpr(lambda *a: NN.rms_norm(*a, eps=1e-5))(
+            x, gamma))
+        assert got == str(jax.make_jaxpr(lambda *a: NN.rms_math(
+            *a, eps=1e-5))(x, gamma))
+        assert "pallas_call" not in got and "pjit" not in got
+        assert _head_norm_routes(since) == []
+    with pytest.raises(mx.base.MXNetError):
+        NN.rms_norm(x, gamma, rotary_dim=32)
+
+
+def test_mxlint_finds_the_head_norm_kernels_behind_their_vjp():
+    """``graph-pallas-no-vjp`` on a graph that holds the op with both tiers
+    traced: the kernels are there, and they are behind their
+    ``custom_vjp``."""
+    from mxnet_tpu.analysis import graph_lint
+    (x, gamma, gate), _ = _head_norm_operands(64, 2, 128, "float32", True)
+
+    def graph(*a):
+        return NN.rms_norm(*a, gated=True)
+    report = graph_lint.lint_jit(graph, x, gamma, gate,
+                                 expect_allgather=False, min_donate_bytes=0)
+    assert "pallas_call" in str(jax.make_jaxpr(graph)(x, gamma, gate))
+    assert "graph-pallas-no-vjp" not in {f.rule for f in report.findings}, \
+        report.format_text()
+
+
+# ---------------------------------------------------------------------------
 # routing / registry
 # ---------------------------------------------------------------------------
 

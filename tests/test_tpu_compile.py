@@ -229,3 +229,106 @@ def test_a_tpu_lowering_of_each_mixer_stage_takes_the_convolution_kernels(
     assert {"mxtpu_causal_conv_fwd", "mxtpu_causal_conv_bwd"} <= set(
         compiled_kernels(tpu))
     assert compiled_kernels(cpu) == {}
+
+
+@pytest.mark.parametrize("h,d,zc,act,rotary,dtype", [
+    (32, 128, False, None, 128, "bfloat16"),    # Trinity-Mini's sliding q
+    (4, 128, False, None, 128, "bfloat16"),     # and keys
+    (32, 128, False, None, 0, "bfloat16"),      # its full layer's queries
+    (32, 128, False, "silu", 0, "bfloat16"),    # a DeltaNet's output norm
+    (16, 256, True, None, 64, "bfloat16"),      # Qwen3-Next's attention q
+    (2, 256, True, None, 64, "bfloat16"),       # and keys
+    (32, 128, False, "sigmoid", 0, "bfloat16"),  # a KDA stage's output norm
+    # a step left in float32 (no compute dtype): blocks of half the positions
+    (32, 128, False, None, 128, "float32"),
+    (32, 128, False, "silu", 0, "float32"),
+    (16, 256, True, None, 64, "float32"),
+    (32, 128, False, "sigmoid", 0, "float32"),
+], ids=["trinity_mini_8k_q", "trinity_mini_8k_k", "trinity_mini_8k_full_q",
+        "qwen3_next_8k_gdn", "qwen3_next_8k_q", "qwen3_next_8k_k",
+        "kimi_linear_8k_kda", "trinity_mini_8k_q_f32", "qwen3_next_8k_gdn_f32",
+        "qwen3_next_8k_q_f32", "kimi_linear_8k_kda_f32"])
+def test_head_norm_kernels_compile_for_the_v5e(one_chip, h, d, zc, act,
+                                               rotary, dtype):
+    """Forward and every gradient of the compiled heads' RMSNorm at the
+    three cells' shapes (2 x 8,192 positions, bfloat16, and float32 as a
+    step without a compute dtype has them): one kernel each way, within the
+    VMEM they ask for."""
+    from mxnet_tpu.kernels import compiled_kernels
+    from mxnet_tpu.kernels.head_norm import _lax_reason, head_norm_pallas
+    x = jax.ShapeDtypeStruct((2, 8192, h, d), jnp.dtype(dtype),
+                             sharding=one_chip)
+    gamma = jax.ShapeDtypeStruct((d,), jnp.dtype(dtype), sharding=one_chip)
+    assert _lax_reason(x, act, rotary) is None
+    args = (x, gamma) + ((x,) if act else ())
+
+    def both(*a):
+        y, vjp = jax.vjp(lambda *b: head_norm_pallas(
+            *b, zero_centered=zc, gate_act=act or "silu", rotary_dim=rotary,
+            base=1e4), *a[:-1])
+        return (y,) + vjp(a[-1])
+    text = jax.jit(both).lower(*args, x).compile().as_text()
+    assert compiled_kernels(text) == {"mxtpu_head_norm_fwd": 1,
+                                      "mxtpu_head_norm_bwd": 1}
+
+
+def _stages(n, stage):
+    """``n`` stages of one kind, each its own ``mirror_stage``, in turn."""
+    import mxnet_tpu as mx
+
+    def build(x, seq_len):
+        for i in range(n):
+            with mx.AttrScope(mirror_stage="s%d" % i):
+                x = stage(x, seq_len) + x
+        return x
+    return build
+
+
+@pytest.mark.parametrize("model,variants", [("trinity_swa", 2),
+                                            ("qwen3_next", 2),
+                                            ("kimi_linear", 1)])
+def test_a_step_of_three_stages_lowers_each_head_norm_kernel_once(model,
+                                                                  variants):
+    """The sharing that bounds set-up: three identical stages in a
+    bfloat16 training step, lowered for a TPU (the text before the
+    compiler sees it), hold the heads' norm kernels of one stage — a
+    ``mxtpu_head_norm_bwd`` a variant (a query's and a key's norm: two
+    shapes; a gated output norm: one) and a ``mxtpu_head_norm_fwd`` a
+    variant for the forward pass and one for its rematerialised copy —
+    while every stage's every norm says ``pallas`` in the recorder."""
+    from mxnet_tpu.kernels import compiled_kernels
+
+    def stage(x, seq_len):
+        if model == "kimi_linear":
+            return _mixer_stage(model, x, seq_len)
+        return _attention_stage(model, x, seq_len)
+    found = []
+    for n in (1, 3):
+        routes, tpu, _ = _lower_stage(_stages(n, stage))
+        routes = [r for r in routes if r["kernel"] == "head_norm"]
+        assert len(routes) == n * variants and all(
+            r == {"kernel": "head_norm", "tier": "pallas",
+                  "reason": "aligned"} for r in routes), routes
+        found.append({k: v for k, v in compiled_kernels(tpu).items()
+                      if "head_norm" in k})
+    assert found[0] == found[1] == {"mxtpu_head_norm_fwd": 2 * variants,
+                                    "mxtpu_head_norm_bwd": variants}
+
+
+def test_a_stream_norm_and_a_lone_rotary_embedding_lower_to_no_kernel():
+    """An RMSNorm of rank 3 (a stream's) and a ``RotaryEmbedding`` of its
+    own (ZAYA1's) stay in XLA in a program lowered for a TPU, as they
+    were."""
+    from mxnet_tpu.ops.nn import rms_norm, rotary_embedding
+
+    def graph(x, gamma, q):
+        return (rms_norm(x, gamma, zero_centered=True),
+                rotary_embedding(q, rotary_dim=64, base=5e6))
+    x = jax.ShapeDtypeStruct((2, 256, 1024), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((2, 256, 8, 128), jnp.bfloat16)
+    gamma = jax.ShapeDtypeStruct((1024,), jnp.bfloat16)
+    text = jax.jit(jax.grad(lambda *a: sum(
+        jnp.sum(o.astype(jnp.float32)) for o in graph(*a)),
+        argnums=(0, 1, 2))).trace(x, gamma, q).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in text

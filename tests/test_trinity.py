@@ -268,7 +268,7 @@ def test_symbol_has_the_reference_leaves_and_named_stages():
     names = {n.name: n.attrs.get("mirror_stage") for n in nodes}
     for name in ("l0_swa_core", "l0_swa_q_proj", "l0_swa_gate_proj",
                  "l0_swa_norm", "l0_swa_post_norm", "l0_swa_q_norm",
-                 "l0_swa_q_rope", "l0_swa_k_rope"):
+                 "l0_swa_k_norm"):
         assert names[name] == "l0_swa", name
     assert names["l3_attn_core"] == names["l3_attn_post_norm"] == "l3_attn"
     assert names["l1_mlp_post_norm"] == "l1_mlp"
@@ -278,9 +278,14 @@ def test_symbol_has_the_reference_leaves_and_named_stages():
     assert {k: int(v.get("window", 0)) for k, v in cores.items()} == {
         "l0_swa_core": 24, "l1_swa_core": 24, "l2_swa_core": 24,
         "l3_attn_core": 0, "l4_swa_core": 24, "l5_swa_core": 24}
-    rotary = {n.attrs.get("mirror_stage") for n in nodes
-              if "RotaryEmbedding" in n.op.name}
-    assert rotary == {"l0_swa", "l1_swa", "l2_swa", "l4_swa", "l5_swa"}
+    # (a query's or key's norm turns its heads itself: no node of its own)
+    rotary = {n.name: int(n.attrs.get("rotary_dim", 0)) for n in nodes
+              if n.name.endswith(("_q_norm", "_k_norm"))}
+    assert rotary == {"l%d_%s_%s_norm" % (i, kind, h): 8 * (kind == "swa")
+                      for i, kind in enumerate(["swa"] * 3 + ["attn"]
+                                               + ["swa"] * 2)
+                      for h in "qk"}
+    assert not any("RotaryEmbedding" in n.op.name for n in nodes)
     # listed layers past num_hidden_layers are ignored; an unknown type is
     # an error
     with pytest.raises(ValueError):
